@@ -20,6 +20,7 @@ from benchmarks import (async_admission, block_attn, cache_modes,
                         fused_step, kernels_bench, observability, paged_kv,
                         prefix_cache, quantized_decode, scheduler_bench,
                         sharded_serving, spec_decode, table1_compare)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "fig1": fig1_confidence.run,
@@ -107,6 +108,7 @@ def _bench_json(exp_dir: Path, name: str, rows: List[str],
 
 
 def main() -> None:
+    enable_compile_cache()
     which = sys.argv[1:] or list(BENCHES)
     rows: List[str] = []
     exp_dir = Path(__file__).resolve().parents[1] / "experiments"

@@ -54,8 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 Array = jax.Array
 
 NEG_INF = -1.0e30
@@ -88,24 +86,32 @@ def _acc_init(m_scr, l_scr, acc_scr, n_scr):
 
 
 def _make_accumulate(q_ref, m_scr, l_scr, acc_scr, n_scr):
-    """One online-softmax update over a kv tile — THE one definition of
-    the flash-accumulator math, shared by every kernel body (dense,
-    paged, per-row are all the same body now). ``valid`` is [1, tile] —
-    kv-side only: "full" mode attention has no q-side mask."""
-    q = q_ref[0, 0].astype(jnp.float32)  # [qt, D]
+    """One online-softmax update over a kv tile for ALL kv heads at once —
+    THE one definition of the flash-accumulator math, shared by the dense
+    and paged bodies. ``k``/``v`` are [tile, Kh, D] in the cache's stored
+    layout; the head axis is a batch dim of both contractions, so the
+    tile is DMA'd whole and never transposed in HBM. ``valid`` is
+    [1, tile] — kv-side only: "full" mode attention has no q-side mask.
+    Contractions take their operands in the stored dtype and accumulate
+    in float32."""
+    q = q_ref[0]                                      # [Kh, qt, D]
     scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
 
     def accumulate(k, v, valid):
-        v = jnp.where(valid[0][:, None], v, 0.0)  # don't let pad NaNs leak
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        s = jnp.einsum("hqd,khd->hqk", q, k.astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        valid = valid[None]                           # [1, 1, tile]
         s = jnp.where(valid, s, NEG_INF)
-        m_old = m_scr[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_old = m_scr[...]                            # [Kh, qt, 1]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        # masked columns contribute exactly 0, so a row that no tile
+        # validates keeps l == acc == 0 and outputs 0
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_old - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jnp.einsum(
+            "hqk,khd->hqd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_new
         if n_scr is not None:
             n_scr[0] += 1
@@ -116,9 +122,9 @@ def _make_accumulate(q_ref, m_scr, l_scr, acc_scr, n_scr):
 def _acc_finish(o_ref, cnt_ref, m_scr, l_scr, acc_scr, n_scr):
     """Normalise and write the output tile (guarding fully-masked rows)."""
     l = jnp.maximum(l_scr[...], 1e-30)
-    o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
     if cnt_ref is not None:
-        cnt_ref[0, 0, 0] = n_scr[0]
+        cnt_ref[0, 0] = jnp.full(cnt_ref.shape[2:], n_scr[0], jnp.int32)
 
 
 def _attn_kernel(s_ref, *args, paged: bool, nk: int, nkk: int, kt: int,
@@ -131,7 +137,9 @@ def _attn_kernel(s_ref, *args, paged: bool, nk: int, nkk: int, kt: int,
     mixed-cursor batches resolve their own geometry. The paged variant
     adds the page table (second prefetch operand) whose index maps routed
     the kv tile to this row's pool page, and gates tile liveness on the
-    page being mapped.
+    page being mapped. Each grid step covers every kv head of one
+    (row, q tile, kv tile): the kv blocks span the whole ``Kh`` dim, which
+    is what the TPU's (8, 128) tiling of the last two dims requires.
     """
     if paged:
         pt_ref, q_ref, ck_ref, cv_ref, bk_ref, bv_ref, pos_ref = args[:7]
@@ -145,7 +153,7 @@ def _attn_kernel(s_ref, *args, paged: bool, nk: int, nkk: int, kt: int,
         o_ref, m_scr, l_scr, acc_scr = refs
         cnt_ref = n_scr = None
     b = pl.program_id(0)
-    j = pl.program_id(3)
+    j = pl.program_id(2)
     slot = s_ref[SLOT, b]
     exc0 = s_ref[EXC0, b]
     exc1 = s_ref[EXC1, b]
@@ -165,9 +173,14 @@ def _attn_kernel(s_ref, *args, paged: bool, nk: int, nkk: int, kt: int,
 
     @pl.when(tile_live)
     def _cache_tile():
-        k = ck_ref[0, :, 0, :].astype(jnp.float32)  # [kt, D]
-        v = cv_ref[0, :, 0, :].astype(jnp.float32)
-        pos = pos_ref[...]                          # [1, kt] int32
+        k = ck_ref[0]                               # [kt, Kh, D]
+        v = cv_ref[0]
+        if T % kt:
+            # the last dense tile overhangs the buffer: zero the rows
+            # past T so no out-of-bounds padding reaches the p @ v sum
+            rid = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) + j * kt
+            v = jnp.where(rid < T, v, jnp.zeros_like(v))
+        pos = pos_ref[0]                            # [1, kt] int32
         ids = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1) + j * kt
         valid = (pos >= 0) & (ids < kv_limit) & (ids < T)
         # slots the fresh block virtually overwrites: stale, served by the
@@ -183,8 +196,8 @@ def _attn_kernel(s_ref, *args, paged: bool, nk: int, nkk: int, kt: int,
     @pl.when(~is_cache)
     def _block_tile():
         jb = j - nk
-        k = bk_ref[0, :, 0, :].astype(jnp.float32)  # [bt, D]
-        v = bv_ref[0, :, 0, :].astype(jnp.float32)
+        k = bk_ref[0]                               # [bt, Kh, D]
+        v = bv_ref[0]
         r = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1) + jb * bt
         # sentinel write slot >= T (sliced loop, finished rows): the fresh
         # block is invisible, matching the rows path's empty in-block window
@@ -213,6 +226,100 @@ def _row_scalars(B: int, slot, block_start, exclude_start, kv_limit,
     exc0 = as_row(exclude_start)
     return jnp.stack([as_row(slot), as_row(block_start), exc0,
                       exc0 + exclude_len, as_row(kv_limit)])
+
+
+def _fold_q(q: Array, Kh: int, q_tile: int):
+    """GQA layout: fold the q-group into rows so one kv head serves
+    [G*bs, D]. Returns ``(qf [B, Kh, Rp, D], R, qt)``."""
+    B, bs, H, D = q.shape
+    G = H // Kh
+    R = G * bs
+    qt = min(q_tile, _round_up(R, 8))
+    Rp = _round_up(R, qt)
+    qf = q.reshape(B, bs, Kh, G, D).transpose(0, 2, 3, 1, 4)
+    qf = qf.reshape(B, Kh, R, D)
+    if Rp != R:
+        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
+    return qf, R, qt
+
+
+def _pad_block(block_k: Array, block_v: Array, bt: int):
+    """Zero-pad the fresh block's K/V to whole ``bt``-row tiles."""
+    bs = block_k.shape[1]
+    bsp = _round_up(bs, bt)
+    if bsp != bs:
+        pad = ((0, 0), (0, bsp - bs), (0, 0), (0, 0))
+        block_k = jnp.pad(block_k, pad)
+        block_v = jnp.pad(block_v, pad)
+    return block_k, block_v, bsp // bt
+
+
+def _attn_call(kernel, prefetch, qf, kv_k, kv_v, block_k, block_v, pos3, *,
+               name: str, kv_index, live_m1, kt: int, bt: int, nk: int,
+               qt: int, debug_tile_counts: bool, interpret: bool):
+    """The ``pallas_call`` both layouts share. Grid ``(B, q tiles, kv
+    tiles)``; every block spans all ``Kh`` heads and all ``D``, so the
+    last two dims of each block are whole array dims. ``kv_index(b, j,
+    *prefetch)`` gives the leading index of cache tile ``j`` (dense: the
+    row, paged: the pool page) and ``live_m1`` the row's last live tile,
+    where dead tiles clamp (no new DMA)."""
+    B, Kh, Rp, D = qf.shape
+    nq = Rp // qt
+    nkk = pos3.shape[0] + block_k.shape[1] // bt
+    out_shape = [jax.ShapeDtypeStruct((B, Kh, Rp, D), qf.dtype)]
+    out_specs = [pl.BlockSpec((1, Kh, qt, D),
+                              lambda b, i, j, *_: (b, 0, i, 0))]
+    scratch = [pltpu.VMEM((Kh, qt, 1), jnp.float32),
+               pltpu.VMEM((Kh, qt, 1), jnp.float32),
+               pltpu.VMEM((Kh, qt, D), jnp.float32)]
+    # the tile-count output exists only in debug mode — production calls
+    # pay for exactly one output buffer
+    if debug_tile_counts:
+        out_shape.append(jax.ShapeDtypeStruct((B, nq, 1, 1), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, 1),
+                                      lambda b, i, j, *_: (b, i, 0, 0)))
+        scratch.append(pltpu.SMEM((1,), jnp.int32))
+    kv_spec = pl.BlockSpec(
+        (1, kt, Kh, D),
+        lambda b, i, j, *pf: kv_index(b, jnp.minimum(j, live_m1(b, *pf)),
+                                      *pf))
+    blk_spec = pl.BlockSpec(
+        (1, bt, Kh, D),
+        lambda b, i, j, *_: (b, jnp.maximum(j - nk, 0), 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, nq, nkk),
+        in_specs=[
+            pl.BlockSpec((1, Kh, qt, D), lambda b, i, j, *_: (b, 0, i, 0)),
+            kv_spec, kv_spec, blk_spec, blk_spec,
+            pl.BlockSpec((1, 1, kt),
+                         lambda b, i, j, *pf: (
+                             jnp.minimum(j, live_m1(b, *pf)), 0, 0)),
+        ],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, qf, kv_k, kv_v, block_k, block_v, pos3)
+
+
+def _unfold_out(res, q_shape, R: int, debug_tile_counts: bool):
+    """Undo ``_fold_q`` on the kernel output; in debug mode also return
+    the per-(row, q tile) count of kv tiles processed, [B, nq]."""
+    B, bs, H, D = q_shape
+    Kh = res[0].shape[1]
+    out = res[0][:, :, :R].reshape(B, Kh, H // Kh, bs, D)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(B, bs, H, D)
+    if debug_tile_counts:
+        return out, res[1][:, :, 0, 0]
+    return out
 
 
 def cached_block_attention_pallas(
@@ -251,40 +358,27 @@ def cached_block_attention_pallas(
     Semantics match ``model.block_step``'s attention exactly: the result
     equals writing the block at ``slot`` and attending the whole buffer with
     ``kv_valid`` masking. Returns [B, bs, H, D]; with
-    ``debug_tile_counts=True`` also returns per-(B,Kh,q_tile) counts of kv
+    ``debug_tile_counts=True`` also returns per-(B, q_tile) counts of kv
     tiles actually processed — the benchmark's HBM-traffic proxy.
     """
     B, bs, H, D = q.shape
     T, Kh = cache_k.shape[1], cache_k.shape[2]
-    G = H // Kh
     if kv_limit is None:
         kv_limit = kv_limit_from_pos(kv_pos)
     if exclude_start is None:
         exclude_start = jnp.zeros((), jnp.int32)
         exclude_len = 0
 
-    # GQA layout: fold the q-group into rows so one kv head serves [G*bs, D]
-    R = G * bs
-    qt = min(q_tile, _round_up(R, 8))
-    Rp = _round_up(R, qt)
-    qf = q.reshape(B, bs, Kh, G, D).transpose(0, 2, 3, 1, 4)
-    qf = qf.reshape(B, Kh, R, D)
-    if Rp != R:
-        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
-    nq = Rp // qt
-
+    qf, R, qt = _fold_q(q, Kh, q_tile)
     kt = min(kv_tile, _round_up(T, 8))
     nk = -(-T // kt)
     bt = min(kt, _round_up(bs, 8))
-    bsp = _round_up(bs, bt)
-    nbk = bsp // bt
-    if bsp != bs:
-        pad = ((0, 0), (0, bsp - bs), (0, 0), (0, 0))
-        block_k = jnp.pad(block_k, pad)
-        block_v = jnp.pad(block_v, pad)
-    nkk = nk + nbk
+    block_k, block_v, nbk = _pad_block(block_k, block_v, bt)
 
-    pos2d = kv_pos.reshape(1, T).astype(jnp.int32)
+    # positions as [nk, 1, kt] (padded with -1): one whole-dim block per
+    # kv tile
+    pos3 = jnp.pad(kv_pos.astype(jnp.int32), (0, nk * kt - T),
+                   constant_values=-1).reshape(nk, 1, kt)
     scalars = _row_scalars(B, slot, block_start, exclude_start, kv_limit,
                            exclude_len)
 
@@ -294,64 +388,15 @@ def cached_block_attention_pallas(
         return jnp.maximum(pl.cdiv(s[KVLIM, b], kt) - 1, 0)
 
     kernel = functools.partial(
-        _attn_kernel, paged=False, nk=nk, nkk=nkk, kt=kt, bt=bt, bs=bs,
-        T=T, exclude=bool(exclude_len), window=window,
+        _attn_kernel, paged=False, nk=nk, nkk=nk + nbk, kt=kt, bt=bt,
+        bs=bs, T=T, exclude=bool(exclude_len), window=window,
         count_tiles=debug_tile_counts)
-
-    # the tile-count output exists only in debug mode — production calls
-    # pay for exactly one output buffer
-    out_shape = [jax.ShapeDtypeStruct((B, Kh, Rp, D), q.dtype)]
-    out_specs = [
-        pl.BlockSpec((1, 1, qt, D), lambda b, h, i, j, s: (b, h, i, 0)),
-    ]
-    scratch = [pltpu.VMEM((qt,), jnp.float32),
-               pltpu.VMEM((qt,), jnp.float32),
-               pltpu.VMEM((qt, D), jnp.float32)]
-    if debug_tile_counts:
-        out_shape.append(jax.ShapeDtypeStruct((B, Kh, nq), jnp.int32))
-        out_specs.append(
-            pl.BlockSpec((1, 1, 1), lambda b, h, i, j, s: (b, h, i)))
-        scratch.append(pltpu.SMEM((1,), jnp.int32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, Kh, nq, nkk),
-        in_specs=[
-            pl.BlockSpec((1, 1, qt, D), lambda b, h, i, j, s: (b, h, i, 0)),
-            pl.BlockSpec((1, kt, 1, D),
-                         lambda b, h, i, j, s: (
-                             b, jnp.minimum(j, live_m1(b, s)), h, 0)),
-            pl.BlockSpec((1, kt, 1, D),
-                         lambda b, h, i, j, s: (
-                             b, jnp.minimum(j, live_m1(b, s)), h, 0)),
-            pl.BlockSpec((1, bt, 1, D),
-                         lambda b, h, i, j, s: (
-                             b, jnp.maximum(j - nk, 0), h, 0)),
-            pl.BlockSpec((1, bt, 1, D),
-                         lambda b, h, i, j, s: (
-                             b, jnp.maximum(j - nk, 0), h, 0)),
-            pl.BlockSpec((1, kt),
-                         lambda b, h, i, j, s: (
-                             0, jnp.minimum(j, live_m1(b, s)))),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    res = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(scalars, qf, cache_k, cache_v, block_k, block_v, pos2d)
-
-    out = res[0]  # out_shape is a list, so the result is too
-    out = out[:, :, :R].reshape(B, Kh, G, bs, D)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(B, bs, H, D)
-    if debug_tile_counts:
-        return out, res[1]
-    return out
+    res = _attn_call(
+        kernel, (scalars,), qf, cache_k, cache_v, block_k, block_v, pos3,
+        name="block_attention", kv_index=lambda b, jc, s: (b, jc, 0, 0), live_m1=live_m1, kt=kt,
+        bt=bt, nk=nk, qt=qt, debug_tile_counts=debug_tile_counts,
+        interpret=interpret)
+    return _unfold_out(res, q.shape, R, debug_tile_counts)
 
 
 # ---------------------------------------------------------------------------
@@ -393,112 +438,44 @@ def paged_block_attention_pallas(
     tiling).
     """
     B, bs, H, D = q.shape
-    Pg, ps = pool_k.shape[0], pool_k.shape[1]
-    Kh = pool_k.shape[2]
+    ps, Kh = pool_k.shape[1], pool_k.shape[2]
     T = kv_pos.shape[0]
     n_log = page_table.shape[1]
     assert n_log * ps >= T, (n_log, ps, T)
     assert ps % 8 == 0, f"page_size {ps} must be a multiple of 8"
-    G = H // Kh
     if kv_limit is None:
         kv_limit = kv_limit_from_pos(kv_pos)
     if exclude_start is None:
         exclude_start = jnp.zeros((), jnp.int32)
         exclude_len = 0
 
-    R = G * bs
-    qt = min(128, _round_up(R, 8))
-    Rp = _round_up(R, qt)
-    qf = q.reshape(B, bs, Kh, G, D).transpose(0, 2, 3, 1, 4)
-    qf = qf.reshape(B, Kh, R, D)
-    if Rp != R:
-        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
-    nq = Rp // qt
-
+    qf, R, qt = _fold_q(q, Kh, 128)
     bt = min(ps, _round_up(bs, 8))
-    bsp = _round_up(bs, bt)
-    nbk = bsp // bt
-    if bsp != bs:
-        pad = ((0, 0), (0, bsp - bs), (0, 0), (0, 0))
-        block_k = jnp.pad(block_k, pad)
-        block_v = jnp.pad(block_v, pad)
-    nkk = n_log + nbk
+    block_k, block_v, nbk = _pad_block(block_k, block_v, bt)
 
-    Tp = n_log * ps
-    pos2d = kv_pos.astype(jnp.int32)
-    if Tp != T:
-        pos2d = jnp.pad(pos2d, (0, Tp - T), constant_values=-1)
-    pos2d = pos2d.reshape(1, Tp)
+    # positions as [n_log, 1, ps] (padded with -1): one block per page
+    pos3 = jnp.pad(kv_pos.astype(jnp.int32), (0, n_log * ps - T),
+                   constant_values=-1).reshape(n_log, 1, ps)
     scalars = _row_scalars(B, slot, block_start, exclude_start, kv_limit,
                            exclude_len)
     pt = page_table.astype(jnp.int32)
 
-    def live_m1(b, s):
+    def live_m1(b, s, pt):
         # last live tile of ROW b (per-row kv_limit)
         return jnp.maximum(pl.cdiv(s[KVLIM, b], ps) - 1, 0)
 
-    def page_for(b, j, s, pt):
-        # route tile j of row b to its pool page; dead/unmapped tiles
-        # clamp to the row's last live mapped page so the revisited block
-        # index issues no new DMA (compute is skipped by tile_live)
-        jm = jnp.minimum(j, live_m1(b, s))
-        return jnp.maximum(pt[b, jm], 0)
+    def page_for(b, jc, s, pt):
+        # route (clamped) tile jc of row b to its pool page; dead or
+        # unmapped tiles land on a page already fetched, so the revisited
+        # block index issues no new DMA (compute is skipped by tile_live)
+        return (jnp.maximum(pt[b, jc], 0), 0, 0, 0)
 
     kernel = functools.partial(
-        _attn_kernel, paged=True, nk=n_log, nkk=nkk, kt=ps, bt=bt, bs=bs,
-        T=T, exclude=bool(exclude_len), window=window,
+        _attn_kernel, paged=True, nk=n_log, nkk=n_log + nbk, kt=ps, bt=bt,
+        bs=bs, T=T, exclude=bool(exclude_len), window=window,
         count_tiles=debug_tile_counts)
-
-    out_shape = [jax.ShapeDtypeStruct((B, Kh, Rp, D), q.dtype)]
-    out_specs = [
-        pl.BlockSpec((1, 1, qt, D), lambda b, h, i, j, s, pt: (b, h, i, 0)),
-    ]
-    scratch = [pltpu.VMEM((qt,), jnp.float32),
-               pltpu.VMEM((qt,), jnp.float32),
-               pltpu.VMEM((qt, D), jnp.float32)]
-    if debug_tile_counts:
-        out_shape.append(jax.ShapeDtypeStruct((B, Kh, nq), jnp.int32))
-        out_specs.append(
-            pl.BlockSpec((1, 1, 1), lambda b, h, i, j, s, pt: (b, h, i)))
-        scratch.append(pltpu.SMEM((1,), jnp.int32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Kh, nq, nkk),
-        in_specs=[
-            pl.BlockSpec((1, 1, qt, D),
-                         lambda b, h, i, j, s, pt: (b, h, i, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, i, j, s, pt: (
-                             page_for(b, j, s, pt), 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, i, j, s, pt: (
-                             page_for(b, j, s, pt), 0, h, 0)),
-            pl.BlockSpec((1, bt, 1, D),
-                         lambda b, h, i, j, s, pt: (
-                             b, jnp.maximum(j - n_log, 0), h, 0)),
-            pl.BlockSpec((1, bt, 1, D),
-                         lambda b, h, i, j, s, pt: (
-                             b, jnp.maximum(j - n_log, 0), h, 0)),
-            pl.BlockSpec((1, ps),
-                         lambda b, h, i, j, s, pt: (
-                             0, jnp.minimum(j, live_m1(b, s)))),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    res = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(scalars, pt, qf, pool_k, pool_v, block_k, block_v, pos2d)
-
-    out = res[0]
-    out = out[:, :, :R].reshape(B, Kh, G, bs, D)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(B, bs, H, D)
-    if debug_tile_counts:
-        return out, res[1]
-    return out
+    res = _attn_call(
+        kernel, (scalars, pt), qf, pool_k, pool_v, block_k, block_v, pos3,
+        name="paged_block_attention", kv_index=page_for, live_m1=live_m1, kt=ps, bt=bt, nk=n_log, qt=qt,
+        debug_tile_counts=debug_tile_counts, interpret=interpret)
+    return _unfold_out(res, q.shape, R, debug_tile_counts)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 Array = jax.Array
 
 
@@ -37,23 +35,26 @@ def softmax_acc_update(x, col, m_scr, s_scr, i_scr) -> None:
     """One vocab-tile update of the running (max, sum-exp, argmax).
 
     ``x`` [rt, vt] float32 logits (padding already -inf), ``col`` [rt, vt]
-    int32 global column ids. Tie-break is EXACTLY ``jnp.argmax``
-    (first occurrence), including across vocab tiles: within the tile the
-    min column id among the tile maxima wins, and the strict
-    ``tile_max > m_old`` compare rejects a later tile whose maximum only
-    EQUALS the running max, keeping the earlier tile's index. (Verified
+    int32 global column ids; the accumulators are [rt, 1] (rows on
+    sublanes, the layout the TPU tiles a per-row column in). Tie-break
+    is EXACTLY ``jnp.argmax`` (first occurrence), including across
+    vocab tiles: within the tile the min column id among the tile maxima
+    wins, and the strict ``tile_max > m_old`` compare rejects a later
+    tile whose maximum only EQUALS the running max, keeping the earlier
+    tile's index. (Verified
     against a crafted cross-tile-tie regression suite and an
     integer-logit fuzz sweep vs ``jnp.argmax`` — do not weaken either
     compare to ``>=``.)
     """
-    tile_max = jnp.max(x, axis=-1)
+    tile_max = jnp.max(x, axis=-1, keepdims=True)
     # first-occurrence argmax within the tile
-    hit = x == tile_max[:, None]
-    tile_arg = jnp.min(jnp.where(hit, col, jnp.iinfo(jnp.int32).max), axis=-1)
+    hit = x == tile_max
+    tile_arg = jnp.min(jnp.where(hit, col, jnp.iinfo(jnp.int32).max),
+                       axis=-1, keepdims=True)
     m_old = m_scr[...]
     m_new = jnp.maximum(m_old, tile_max)
     s_scr[...] = s_scr[...] * jnp.exp(m_old - m_new) + jnp.sum(
-        jnp.exp(x - m_new[:, None]), axis=-1)
+        jnp.exp(x - m_new), axis=-1, keepdims=True)
     i_scr[...] = jnp.where(tile_max > m_old, tile_arg, i_scr[...])
     m_scr[...] = m_new
 
@@ -98,17 +99,18 @@ def fused_confidence_pallas(logits: Array, *, row_tile: int = 8,
     kernel = functools.partial(_kernel, nv=nv, vt=vt, vocab=V)
     conf, tok = pl.pallas_call(
         kernel,
+        name="fused_confidence",
         grid=(nr, nv),
         in_specs=[pl.BlockSpec((rt, vt), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((rt,), lambda i, j: (i,)),
-                   pl.BlockSpec((rt,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((Rp,), jnp.float32),
-                   jax.ShapeDtypeStruct((Rp,), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((rt,), jnp.float32),
-                        pltpu.VMEM((rt,), jnp.float32),
-                        pltpu.VMEM((rt,), jnp.int32)],
-        compiler_params=compiler_params(
+        out_specs=[pl.BlockSpec((rt, 1), lambda i, j: (i, 0)),
+                   pl.BlockSpec((rt, 1), lambda i, j: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((Rp, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((rt, 1), jnp.float32),
+                        pltpu.VMEM((rt, 1), jnp.float32),
+                        pltpu.VMEM((rt, 1), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits)
-    return conf[:R], tok[:R]
+    return conf[:R, 0], tok[:R, 0]

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
 
 Array = jax.Array
 
@@ -94,6 +93,7 @@ def flash_attention_pallas(q: Array, k: Array, v: Array, *,
                                q_offset=q_offset, t_real=T)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, qt, D), lambda b, i, j: (b, i, 0)),
@@ -105,7 +105,7 @@ def flash_attention_pallas(q: Array, k: Array, v: Array, *,
         scratch_shapes=[pltpu.VMEM((qt,), jnp.float32),
                         pltpu.VMEM((qt,), jnp.float32),
                         pltpu.VMEM((qt, D), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

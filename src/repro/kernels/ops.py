@@ -178,15 +178,14 @@ def fused_step(x: Array, w, tau: Array, masked: Array, *,
     return _fused_step_ref(x, w, tau, masked, tied, quota)
 
 
-def _fused_pallas(x2d, w, tau1d, mask1d, *, tied: bool, row_tile: int = 8,
-                  quota: int = 0, interpret: bool = False):
+def _fused_pallas(x2d, w, tau1d, mask1d, *, tied: bool, quota: int = 0,
+                  interpret: bool = False, **tiles):
     if isinstance(w, QuantizedTensor):
         return quantized_fused_step_pallas(
-            x2d, w.q, w.scale, tau1d, mask1d, tied=tied,
-            row_tile=row_tile, quota=quota, interpret=interpret)
-    return fused_step_pallas(x2d, w, tau1d, mask1d, tied=tied,
-                             row_tile=row_tile, quota=quota,
-                             interpret=interpret)
+            x2d, w.q, w.scale, tau1d, mask1d, tied=tied, quota=quota,
+            interpret=interpret, **tiles)
+    return fused_step_pallas(x2d, w, tau1d, mask1d, tied=tied, quota=quota,
+                             interpret=interpret, **tiles)
 
 
 @partial(jax.jit, static_argnames=("tied", "quota"))

@@ -39,8 +39,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
 
 Array = jax.Array
 
@@ -86,13 +86,14 @@ def quantized_matmul_pallas(x: Array, q: Array, scale: Array, *,
         else pl.BlockSpec((Kp, nt), lambda i, j: (0, j))
     out = pl.pallas_call(
         functools.partial(_kernel, transpose=transpose),
+        name="quantized_matmul",
         grid=(nr, nn),
         in_specs=[pl.BlockSpec((rt, Kp), lambda i, j: (i, 0)),
                   w_spec,
                   pl.BlockSpec((1, nt), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((rt, nt), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Rp, Np), x.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, q, svec)

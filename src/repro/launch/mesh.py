@@ -8,12 +8,22 @@ use.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: sharding propagates from the
+    ``NamedSharding``s and constraints the program places, as the rules
+    in ``repro.sharding`` assume (JAX's default is ``Explicit``, which
+    types every array by its sharding and rejects those constraints)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int = 2, model: int = 2):
@@ -21,7 +31,7 @@ def make_host_mesh(*, data: int = 2, model: int = 2):
     used by sharding unit tests."""
     n = len(jax.devices())
     assert n >= data * model, (n, data, model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(*, data: int = 1, model: int = 1):
@@ -38,7 +48,7 @@ def make_serving_mesh(*, data: int = 1, model: int = 1):
     assert n >= data * model, \
         f"serving mesh {data}x{model} needs {data * model} devices, " \
         f"have {n}"
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 # v5e hardware constants for the roofline (per chip)

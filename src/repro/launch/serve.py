@@ -1,26 +1,60 @@
-"""Serving launcher: batched OSDT diffusion serving of a checkpoint.
+"""Serving launcher: batched OSDT diffusion serving.
+
+Serves either the trained bench checkpoint or, with ``--arch``, a
+registered configuration at its published widths with seeded random
+weights (``--layers`` cuts the depth to fit the device):
 
   PYTHONPATH=src python -m repro.launch.serve --ckpt experiments/bench_model.msgpack \\
       --policy osdt --n 16
+  PYTHONPATH=src python -m repro.launch.serve --arch llada-8b --layers 16 \\
+      --prompt-len 256 --max-new 128 --block 32 --slice-len 1 \\
+      --cache-layout paged --attn-impl kernel --step-fusion fused
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import numpy as np
 
 from repro.checkpoint.checkpoint import restore
 from repro.config.base import DecodeConfig, EngineConfig
+from repro.config.registry import get_config, list_archs
 from repro.data import tokenizer as tok
 from repro.data.tasks import TASKS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.engine import DiffusionEngine, Request
+
+
+def random_model(arch: str, *, layers: int = 0, seed: int = 0):
+    """``(cfg, params)``: the registered ``arch`` at its published widths,
+    its depth cut to ``layers`` when non-zero (widths never change), with
+    seeded random weights. The draw runs under ``jax.jit`` on the default
+    device, where XLA fuses the float32 normal draw into the cast to the
+    parameter dtype, so no float32 copy of a stacked weight is held."""
+    cfg = get_config(arch)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    cfg = dataclasses.replace(cfg, mask_token_id=tok.MASK_ID)
+    params = jax.jit(lambda s: M.init_params(jax.random.key(s), cfg))(seed)
+    return cfg, params
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--ckpt", default="experiments/bench_model.msgpack")
+    ap.add_argument("--arch", default="", choices=[""] + list_archs(),
+                    help="serve this registered configuration at its "
+                         "published widths with seeded random weights "
+                         "instead of the bench checkpoint")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="with --arch: cut the depth to this many layers "
+                         "(0 = the configuration's own depth)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="with --arch: random-weight seed")
+    ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--policy", default="osdt",
                     choices=["static", "factor", "osdt"])
     ap.add_argument("--task", default="gsm8k-syn", choices=list(TASKS))
@@ -34,6 +68,14 @@ def main() -> None:
                     choices=["dense", "paged"],
                     help="paged: page-pool KV with per-slot page tables — "
                          "dead slots pin zero pages (SERVING.md)")
+    ap.add_argument("--attn-impl", default="auto",
+                    choices=["auto", "dense", "flash", "kernel"],
+                    help="block-step attention path; kernel = the Pallas "
+                         "block-attention kernels on TPU (KERNELS.md)")
+    ap.add_argument("--step-fusion", default="unfused",
+                    choices=["unfused", "fused"],
+                    help="fused: one kernel for lm head + confidence + "
+                         "threshold select (KERNELS.md 'fused step')")
     ap.add_argument("--page-size", type=int, default=16,
                     help="cache slots per page (paged layout)")
     ap.add_argument("--num-pages", type=int, default=0,
@@ -97,18 +139,28 @@ def main() -> None:
                          "calibration profile and flag staleness")
     args = ap.parse_args()
 
-    from benchmarks.common import bench_config
-    cfg = bench_config()
-    shape = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
-    params, meta = restore(args.ckpt, shape)
-    print(f"# loaded {args.ckpt} (meta={meta})")
+    print(f"# compile cache: {enable_compile_cache()}")
+    if args.arch:
+        cfg, params = random_model(args.arch, layers=args.layers,
+                                   seed=args.seed)
+        print(f"# {cfg.name}: {cfg.num_layers} layers, d_model "
+              f"{cfg.d_model}, random weights (seed {args.seed})")
+    else:
+        from benchmarks.common import bench_config
+        cfg = bench_config()
+        shape = jax.eval_shape(lambda: M.init_params(jax.random.key(0),
+                                                     cfg))
+        params, meta = restore(args.ckpt, shape)
+        print(f"# loaded {args.ckpt} (meta={meta})")
 
     dcfg = DecodeConfig(max_new_tokens=args.max_new, block_size=args.block,
                         policy=args.policy, threshold=0.9, mode="block",
                         metric="q1", cap=0.9, slack=0.1,
+                        attn_impl=args.attn_impl,
                         cache_layout=args.cache_layout,
-                        page_size=args.page_size)
-    ecfg = EngineConfig(batch_size=args.batch, prompt_len=64,
+                        page_size=args.page_size,
+                        step_fusion=args.step_fusion)
+    ecfg = EngineConfig(batch_size=args.batch, prompt_len=args.prompt_len,
                         cache_mode=args.cache_mode, store_path=args.store,
                         num_pages=args.num_pages,
                         shared_prefix=args.shared_prefix,

@@ -72,10 +72,10 @@ import numpy as np
 
 from repro.config.base import DecodeConfig, EngineConfig, ModelConfig
 from repro.core.calibrate import CalibrationProfile
-from repro.core.decoder import (admit_carry_rows, init_decode_carry,
-                                make_admit_fn, make_generate_fn,
-                                make_slice_fn, result_profile,
-                                retire_carry_rows)
+from repro.core.decoder import (DecodeCarry, admit_carry_rows,
+                                init_decode_carry, make_admit_fn,
+                                make_generate_fn, make_slice_fn,
+                                result_profile, retire_carry_rows)
 from repro.core.osdt import CalibrationStore
 from repro.data import tokenizer as tok
 from repro.launch.mesh import make_serving_mesh
@@ -128,6 +128,9 @@ class Response:
     # pages replaced
     prefix_hit_pages: int = 0
     prefill_tokens_saved: int = 0
+    # the delivered token ids ``text`` decodes (EOS-truncated, masks
+    # dropped); ids outside the byte tokenizer's range have no text
+    token_ids: Tuple[int, ...] = ()
 
 
 @dataclass
@@ -456,6 +459,18 @@ class Scheduler:
             assert not self.prefix_cache or self.dp == 1, \
                 "radix prefix cache is single-shard (tree pages live " \
                 "on one data shard); use model_parallel only"
+            pallas = [name for name, on in (
+                ("attn_impl=kernel",
+                 (self.ecfg.attn_impl or dcfg.attn_impl) == "kernel"),
+                ("step_fusion=fused", dcfg.step_fusion == "fused"),
+                ("weight_dtype=int8", self.weight_dtype == "int8")) if on]
+            if pallas and jax.default_backend() == "tpu":
+                # the TPU compiler cannot partition a Mosaic kernel over
+                # a mesh; that takes a shard_map per kernel
+                raise ValueError(
+                    f"sharded serving on TPU runs the XLA paths only; "
+                    f"{', '.join(pallas)} would put Pallas kernels in a "
+                    f"mesh-sharded program")
             self.params = place_serving_params(self.params, cfg,
                                                self.mesh)
         # the shared system prompt is prepended to every row's prompt
@@ -518,6 +533,13 @@ class Scheduler:
                 weight_dtype=self.weight_dtype, **kw)
             self._admit_fn = make_admit_fn(cfg, dcfg, **kw) \
                 if mode != "none" else None
+
+    @property
+    def carry(self) -> Optional[DecodeCarry]:
+        """The live step-sliced decode carry between slices (``None``
+        when idle or not sliced). Read it between ``slice_step`` calls
+        only: the next slice may donate its buffers."""
+        return self._carry
 
     def _count_nfe(self, n: int) -> None:
         """Every counted forward streams the decode weight set once —
@@ -785,7 +807,7 @@ class Scheduler:
                     blocks_accepted=int(accepted[j]),
                     # batch granularity: the first block is only
                     # observable when the whole batch returns
-                    ttfb_s=queue_s + decode_s))
+                    ttfb_s=queue_s + decode_s, token_ids=tuple(row)))
                 self.stats.tokens += len(row)
                 self.stats.tokens_dropped += tokens.shape[1] - len(row)
                 self.stats.queue_s += queue_s
@@ -1402,7 +1424,7 @@ class Scheduler:
                 blocks_accepted=int(accepted[j]), ttfb_s=slot.ttfb_s,
                 prefix_hit_pages=slot.prefix_hit_pages,
                 prefill_tokens_saved=slot.prefix_hit_pages
-                * self.dcfg.page_size))
+                * self.dcfg.page_size, token_ids=tuple(row)))
             self.stats.tokens += len(row)
             self.stats.tokens_dropped += tokens.shape[1] - len(row)
             self.stats.queue_s += queue_s

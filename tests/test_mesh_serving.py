@@ -283,7 +283,8 @@ _PRELUDE = textwrap.dedent("""
 """)
 
 _CHILD_DECODE = _PRELUDE + textwrap.dedent("""
-    mesh = jax.make_mesh((2, 1), ("data", "model"))
+    from repro.launch.mesh import make_serving_mesh
+    mesh = make_serving_mesh(data=2, model=1)
     out = {}
     for layout, fusion, sl in [("", "unfused", 1), ("", "fused", NB),
                                ("paged", "unfused", NB),

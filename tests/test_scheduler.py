@@ -176,6 +176,8 @@ def test_engine_stats_accounting(small_model, calibrated_store):
     assert st.tokens + st.tokens_dropped == 6 * DCFG.max_new_tokens
     for r in out:
         assert r.tokens_out + r.tokens_dropped == DCFG.max_new_tokens
+        assert len(r.token_ids) == r.tokens_out
+        assert tok.decode(r.token_ids) == r.text
         assert r.wall_s == pytest.approx(r.queue_s + r.decode_s)
         assert r.decode_s < st.wall_s + 1e-9  # one batch, not the whole run
         assert r.nfe <= DCFG.num_blocks * DCFG.steps_cap

@@ -20,7 +20,8 @@ _CHILD = textwrap.dedent("""
     from repro.config.registry import get_config
     from repro.launch import specs as specs_lib
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(data=2, model=4)
     results = {}
     for arch in ["smollm-135m", "qwen3-moe-235b-a22b", "mamba2-130m",
                  "zamba2-1.2b"]:

@@ -357,6 +357,8 @@ def test_sliced_latency_accounting(small_model, calibrated_store):
     total = s.stats.wall_s
     for r in got.values():
         assert r.ttfb_s > 0.0
+        assert len(r.token_ids) == r.tokens_out
+        assert tok.decode(r.token_ids) == r.text
         assert r.wall_s == pytest.approx(r.queue_s + r.decode_s)
         assert r.decode_s <= total + 1e-9
     # the late request was admitted after alpha's first slice: its decode
