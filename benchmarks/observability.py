@@ -19,8 +19,6 @@ the "always compiled, off by default" contract:
 Artifacts: ``experiments/obs_trace.json`` (Chrome/Perfetto
 trace_event JSON, schema-validated here) and
 ``experiments/obs_metrics.prom`` (Prometheus text exposition).
-Emits ``roofline/step_us_measured/*`` rows — the measured column next to
-the analytic µs/step model in ``repro.roofline.report --section step``.
 
   REPRO_OBS_BENCH_REQS=8 PYTHONPATH=src:. python -m benchmarks.run obs
 """
@@ -134,9 +132,6 @@ def run(csv_rows: List[str], verbose: bool = True) -> None:
                     f"obs={d['observations']};"
                     f"fallback={d['fallback_frac']:.3f};"
                     f"margin={d['margin_mean']:.3f}")
-    for kind, (us, fwd, disp) in sorted(obs.timer.rows().items()):
-        rows.append(f"roofline/step_us_measured/{kind},{us:.2f},"
-                    f"f{fwd}_d{disp}")
     for row in rows:
         csv_rows.append(row)
         if verbose:

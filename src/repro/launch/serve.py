@@ -131,8 +131,8 @@ def main() -> None:
                     help="write a Prometheus text-exposition snapshot of "
                          "the engine's metrics registry here ('-' = stdout)")
     ap.add_argument("--metrics-json", default="",
-                    help="write the full JSON snapshot (metrics + drift + "
-                         "measured dispatch timing) here")
+                    help="write the full JSON snapshot (metrics + drift) "
+                         "here")
     ap.add_argument("--drift", action="store_true",
                     help="confidence-drift telemetry: score each retiring "
                          "row's live trajectory against the task's stored "

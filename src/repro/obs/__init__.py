@@ -1,16 +1,18 @@
 """Observability for the serving runtime (SERVING.md "Observability").
 
 Always compiled, off by default: the :class:`Observability` bundle is
-constructed unconditionally by the scheduler, but the tracer is falsy
-and the drift monitor is ``None`` unless ``EngineConfig`` opts in —
-hot-path call sites guard with ``if obs.tracer:`` / ``if obs.drift:``
+constructed unconditionally by the scheduler, but the tracer's ring is
+off (the tracer is falsy) and the drift monitor is ``None`` unless
+``EngineConfig`` opts in — ring-only call sites guard with
+``if obs.tracer:`` / ``if obs.drift:``
 so the disabled cost is a branch, and decode output + ``EngineStats``
 stay bit-identical to an engine built without the subsystem.
 
 Pieces (each usable standalone):
-  * :mod:`repro.obs.trace`   — ring-buffer tracer, Perfetto export
+  * :mod:`repro.obs.trace`   — host spans on the profiler's clock,
+    ring-buffer tracer, Perfetto export
   * :mod:`repro.obs.metrics` — counter/gauge/histogram registry,
-    Prometheus + JSON exposition, measured dispatch timing
+    Prometheus + JSON exposition
   * :mod:`repro.obs.drift`   — per-task confidence-drift scoring vs the
     stored calibration profile
 """
@@ -19,18 +21,17 @@ from __future__ import annotations
 from typing import Optional
 
 from repro.obs.drift import DriftMonitor
-from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               StepTimer)
+from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.trace import Tracer, validate_trace
 
 __all__ = ["Observability", "Tracer", "validate_trace", "MetricsRegistry",
-           "Counter", "Gauge", "Histogram", "StepTimer", "DriftMonitor"]
+           "Counter", "Gauge", "Histogram", "DriftMonitor"]
 
 
 class Observability:
-    """The scheduler-owned bundle: one registry, one tracer, one step
-    timer, and (opt-in) one drift monitor sharing the engine's
-    calibration store."""
+    """The scheduler-owned bundle: one registry, one tracer, and
+    (opt-in) one drift monitor sharing the engine's calibration
+    store."""
 
     #: fixed track ids for the tracer's duration spans; per-slot serve
     #: tracks are ``TID_SLOT0 + slot_index``
@@ -43,7 +44,6 @@ class Observability:
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer(enabled=False)
         self.drift = drift
-        self.timer = StepTimer()
         if self.tracer:
             self.tracer.track(self.TID_ENGINE, "engine")
 
@@ -76,7 +76,6 @@ class Observability:
     def _publish(self) -> None:
         if self.drift is not None:
             self.drift.publish(self.registry)
-        self.timer.publish(self.registry)
         if self.tracer.enabled:
             self.registry.gauge(
                 "trace_events_dropped",
@@ -84,8 +83,8 @@ class Observability:
             ).set(self.tracer.dropped)
 
     def prometheus(self) -> str:
-        """Prometheus text exposition of everything (drift + timing
-        gauges refreshed first)."""
+        """Prometheus text exposition of everything (drift gauges
+        refreshed first)."""
         self._publish()
         return self.registry.prometheus()
 
@@ -95,9 +94,6 @@ class Observability:
         out = {"metrics": self.registry.snapshot()}
         if self.drift is not None:
             out["drift"] = self.drift.snapshot()
-        out["dispatch"] = {k: {"us_per_forward": us, "forwards": fwd,
-                               "dispatches": d}
-                           for k, (us, fwd, d) in self.timer.rows().items()}
         return out
 
     def save_trace(self, path) -> None:

@@ -10,19 +10,13 @@ Three instrument kinds, each label-aware:
     contract would make that an error.
   * ``Histogram`` — cumulative fixed buckets (+Inf implicit), sum and
     count; Prometheus ``_bucket``/``_sum``/``_count`` exposition.
-
-``StepTimer`` accumulates wall-clock dispatch timings per compiled
-program kind and renders them as µs/forward — the measured column next
-to ``repro.roofline.step_time_model``'s analytic µs/step
-(``roofline/report.py --section step``).
 """
 from __future__ import annotations
 
 import json
 from typing import Dict, Iterable, List, Optional, Tuple
 
-__all__ = ["MetricsRegistry", "Counter", "Gauge", "Histogram",
-           "StepTimer"]
+__all__ = ["MetricsRegistry", "Counter", "Gauge", "Histogram"]
 
 LabelKey = Tuple[Tuple[str, str], ...]
 
@@ -215,46 +209,3 @@ class MetricsRegistry:
 
     def snapshot_json(self, **json_kw) -> str:
         return json.dumps(self.snapshot(), **json_kw)
-
-
-class StepTimer:
-    """Wall-clock dispatch accumulator per compiled-program kind.
-
-    The scheduler calls ``add(kind, wall_s, forwards)`` once per
-    dispatch with the host-observed wall (block-until-ready) and the
-    number of model forwards the dispatch executed (nfe delta — prefill,
-    denoise and commit forwards all count). ``us_per_forward`` is then
-    directly comparable to the roofline model's analytic µs/step;
-    :func:`repro.roofline.report.step_table` renders both when the
-    measured rows are present in ``bench_results.csv``.
-    """
-
-    def __init__(self):
-        # kind -> [wall_s, forwards, dispatches]
-        self._acc: Dict[str, list] = {}
-
-    def add(self, kind: str, wall_s: float, forwards: int) -> None:
-        st = self._acc.setdefault(kind, [0.0, 0, 0])
-        st[0] += wall_s
-        st[1] += int(forwards)
-        st[2] += 1
-
-    def us_per_forward(self, kind: str) -> float:
-        st = self._acc.get(kind)
-        if not st or not st[1]:
-            return 0.0
-        return st[0] * 1e6 / st[1]
-
-    def rows(self) -> Dict[str, Tuple[float, int, int]]:
-        """kind -> (us_per_forward, forwards, dispatches)."""
-        return {k: (self.us_per_forward(k), st[1], st[2])
-                for k, st in sorted(self._acc.items())}
-
-    def publish(self, registry: MetricsRegistry) -> None:
-        g = registry.gauge("dispatch_us_per_forward",
-                           "measured wall-clock per model forward")
-        n = registry.gauge("dispatch_forwards",
-                           "model forwards timed per program kind")
-        for kind, (us, fwd, _) in self.rows().items():
-            g.set(us, kind=kind)
-            n.set(fwd, kind=kind)

@@ -1,18 +1,29 @@
-"""Ring-buffer tracer with Chrome/Perfetto ``trace_event`` export.
+"""Host spans on the profiler's clock, plus a ring buffer with
+Chrome/Perfetto ``trace_event`` export.
 
-Low-overhead by construction: a disabled tracer is falsy, so call sites
-guard with ``if tr:`` and pay one attribute load + branch; an enabled
-tracer appends a plain tuple into a preallocated ring (no dict build, no
-timestamp formatting) and serializes only at :meth:`export`. The ring
-keeps the most recent ``capacity`` events — long runs drop the oldest
-events, never block, and :meth:`export` reports how many were dropped.
+:meth:`Tracer.span` is the one way to time a synchronous host phase. It
+always enters a ``jax.profiler.TraceAnnotation``, so an active profiler
+session records the span on the same clock as the device's operations
+(that is what lets a device-idle gap be put down to the host phase that
+caused it); with no session the annotation costs about a microsecond.
+When the tracer is enabled, the same call also writes the span's B/E
+pair into the ring.
 
-Event model (maps 1:1 onto the Chrome ``trace_event`` JSON schema that
-Perfetto / ``chrome://tracing`` load directly):
+The ring is low-overhead by construction: a disabled tracer is falsy,
+so ring-only call sites guard with ``if tr:`` and pay one attribute
+load + branch; an enabled tracer appends a plain tuple into a
+preallocated ring (no dict build, no timestamp formatting) and
+serializes only at :meth:`export`. The ring keeps the most recent
+``capacity`` events — long runs drop the oldest events, never block,
+and :meth:`export` reports how many were dropped.
 
-  * **duration spans** (``ph`` B/E) on named *tracks* — synchronous host
-    phases: admission prefill, seed forwards, slice dispatch, per-slot
-    serve spans. Strictly nested per track.
+Event model of the ring (maps 1:1 onto the Chrome ``trace_event`` JSON
+schema that Perfetto / ``chrome://tracing`` load directly):
+
+  * **duration spans** (``ph`` B/E) on named *tracks* — the
+    :meth:`Tracer.span` phases on the engine track, and the per-slot
+    ``serve`` spans (:meth:`Tracer.begin` / :meth:`Tracer.end`, which
+    open and close in different calls). Strictly nested per track.
   * **async spans** (``ph`` b/e, keyed by ``id`` + ``cat``) — request
     lifecycle phases that overlap arbitrarily: ``request`` (submit →
     response) and ``queued`` (submit/requeue → admit).
@@ -29,17 +40,59 @@ import json
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "validate_trace"]
 
 _REQUIRED_KEYS = ("name", "ph", "ts", "pid", "tid")
 
 
+class _Span:
+    """One :meth:`Tracer.span` of an enabled tracer: a profiler
+    annotation and a B/E pair in the ring. A span left by an exception
+    closes with ``error=True`` in the ring."""
+
+    __slots__ = ("_tr", "_name", "_tid", "_args", "_ann", "_end")
+
+    def __init__(self, tr: "Tracer", name: str, tid: int, args: dict):
+        self._tr, self._name, self._tid, self._args = tr, name, tid, args
+        self._ann = TraceAnnotation(name, **args)
+        self._end: Optional[dict] = None
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        tr = self._tr
+        tr._emit("B", self._name, self._tid, tr._ts(None),
+                 self._args or None, None, "")
+        return self
+
+    def set_metadata(self, **args) -> None:
+        """Arguments known only as the span closes (host values): they
+        join the profiler event's and go on the ring's E (the same call
+        as ``TraceAnnotation.set_metadata``)."""
+        self._ann.set_metadata(**args)
+        if self._end is None:
+            self._end = {}
+        self._end.update(args)
+
+    def __exit__(self, etype, value, tb) -> None:
+        args = self._end
+        if etype is not None:
+            args = dict(args or {}, error=True)
+        tr = self._tr
+        tr._emit("E", self._name, self._tid, tr._ts(None), args or None,
+                 None, "")
+        self._ann.__exit__(etype, value, tb)
+
+
 class Tracer:
-    """Bounded in-memory trace sink (Chrome ``trace_event`` exporter).
+    """Profiler spans plus a bounded in-memory trace sink (Chrome
+    ``trace_event`` exporter).
 
     ``enabled=False`` (the default posture in the engine) makes the
-    tracer falsy and every emit a no-op; the scheduler's hot paths guard
-    with ``if tracer:`` so the disabled cost is one branch.
+    tracer falsy and every ring emit a no-op; :meth:`span` still reaches
+    an active profiler session. Ring-only call sites guard with
+    ``if tracer:`` so the disabled cost is one branch.
 
     Timestamps are microseconds relative to the tracer's construction,
     taken from ``clock`` (``time.perf_counter``); emit methods accept an
@@ -77,9 +130,22 @@ class Tracer:
                                               eid, cat)
         self._n += 1
 
+    def span(self, name: str, *, tid: int = 0, **args):
+        """``with tracer.span(name, **args) as sp:`` — a synchronous host
+        phase. Always recorded by an active profiler session; also in
+        the ring when enabled. ``args`` must be values the host already
+        holds (never a device array: reading one would sync); more may
+        follow with ``sp.set_metadata(...)`` before the span closes.
+        With the ring off this is the bare profiler annotation."""
+        if not self.enabled:
+            return TraceAnnotation(name, **args)
+        return _Span(self, name, tid, args)
+
     def begin(self, name: str, *, tid: int = 0, t: Optional[float] = None,
               **args) -> None:
-        """Open a duration span on ``tid`` (close with :meth:`end`)."""
+        """Open a ring-only duration span on ``tid`` (close with
+        :meth:`end`) — for spans that open and close in different calls,
+        such as a slot's ``serve``."""
         if self.enabled:
             self._emit("B", name, tid, self._ts(t), args or None, None, "")
 

@@ -177,19 +177,6 @@ def step_table() -> str:
         base = base or v["us"]
         lines.append(f"| {tp} | {v['us']:.1f} | {v['ici_us']:.1f} | "
                      f"{v['bound']} | {base / v['us']:.2f}x |")
-    mprefix = "roofline/step_us_measured/"
-    mnames = [n for n in sorted(rows) if n.startswith(mprefix)]
-    if mnames:
-        lines += ["", "measured dispatch wall (`obs.StepTimer` via "
-                  "`benchmarks/observability.py`; this container — model "
-                  "column above assumes tpu-v5e):", "",
-                  "| program | measured µs/forward | forwards | "
-                  "dispatches |", "|---|---|---|---|"]
-        for name in mnames:
-            us, derived = rows[name]
-            fwd, _, disp = derived.partition("_d")
-            lines.append(f"| {name[len(mprefix):]} | {us} | "
-                         f"{fwd.lstrip('f')} | {disp} |")
     lines += ["", "measured epilogue (CPU container; real kernel timing "
               "needs a TPU):", ""]
     for key in ("fused_step/unfused_epilogue", "fused_step/fused_epilogue",

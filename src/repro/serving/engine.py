@@ -93,7 +93,7 @@ class DiffusionEngine:
     @property
     def obs(self):
         """The scheduler's :class:`repro.obs.Observability` bundle
-        (tracer, metrics registry, drift monitor, dispatch timer)."""
+        (tracer, metrics registry, drift monitor)."""
         return self.scheduler.obs
 
     @property

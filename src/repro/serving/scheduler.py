@@ -62,7 +62,7 @@ from __future__ import annotations
 import os
 import time
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import lru_cache
 from typing import Deque, Dict, List, Optional, Tuple
 
@@ -121,8 +121,13 @@ class Response:
     # submit -> the row's FIRST decoded block is available. Sliced decode
     # measures it at the first slice boundary the row participated in;
     # the batch-granular runtime can only observe the batch end, so there
-    # it equals wall_s (stats glossary).
+    # it equals wall_s (stats glossary). Always ``block_s[0]``.
     ttfb_s: float = 0.0
+    # submit -> each decoded block, as the host observes it: the end of
+    # the wait for the slice that completed the block (blocks completed
+    # by one slice share its time). The batch runtime observes only the
+    # batch end, so it holds that one entry.
+    block_s: Tuple[float, ...] = ()
     # radix prefix cache (0 with prefix_cache off): tree pages this
     # row's admission reused, and the prompt tokens whose prefill those
     # pages replaced
@@ -148,14 +153,14 @@ class Slot:
     freed — and shared-prefix references dropped — at retirement.
     Sliced decode additionally accumulates the slot's per-request
     latency split (``decode_s`` over the slices it was live in,
-    ``ttfb_s`` at its first slice boundary) and remembers which task it
-    is calibrating, if any."""
+    ``block_t``: the host time each block completed) and remembers which
+    task it is calibrating, if any."""
     index: int
     state: str = "free"
     rs: Optional[RequestState] = None
     pages: Optional[List[int]] = None
     decode_s: float = 0.0
-    ttfb_s: float = 0.0
+    block_t: List[float] = field(default_factory=list)
     calib_task: str = ""
     was_mid: bool = False  # admitted while the batch was mid-generation
     # radix prefix cache: tree pages share()d into this row (freed at
@@ -172,7 +177,7 @@ class Slot:
         self.pages = pages
         self.state = "active" if rs is not None else "dead"
         self.decode_s = 0.0
-        self.ttfb_s = 0.0
+        self.block_t = []
         self.calib_task = ""
         self.was_mid = False
         self.prefix_pages = None
@@ -502,14 +507,6 @@ class Scheduler:
         self.drafter = Drafter(self.store, dcfg,
                                max_steps=self.ecfg.draft_max_steps) \
             if self.spec else None
-        # StepTimer key for measured dispatch walls — mirrors the
-        # roofline model's layout x runtime x epilogue axes
-        fusion = dcfg.step_fusion or "unfused"
-        if self.weight_dtype != "bf16":
-            fusion += f"-{self.weight_dtype}"
-        self._prog_kind = "/".join((
-            "paged" if self.paged else "dense",
-            "sliced" if self.ecfg.slice_len else "batch", fusion))
         self._gen = make_generate_fn(
             cfg, dcfg, cache_mode=mode, attn_impl=self.ecfg.attn_impl,
             cache_layout="paged" if self.paged else "dense",
@@ -734,34 +731,25 @@ class Scheduler:
                                         self.allocator.in_use)
 
         served: set = set()   # slots whose serve span closed (trace)
-        batch_open = False
         try:
             t0 = time.perf_counter()
-            if tr:
-                tr.begin("batch", tid=0, t=t0, rows_live=len(picked),
-                         dead=n_dead,
-                         pages_in_use=self.allocator.in_use
-                         if self.paged else 0,
+            with tr.span("sched.batch", live=len(picked), dead=n_dead,
                          draft_blocks=int(draft_mask.sum())
-                         if draft_mask is not None else 0)
-                batch_open = True
-            args = (self.params, jnp.asarray(prompt), jnp.asarray(tables),
-                    self._mask_arr, jnp.asarray(live),
-                    self.eos_id if self.ecfg.eos_early_exit else None)
-            kwargs = {}
-            if self.paged:
-                args += (self._pool_k, self._pool_v,
-                         jnp.asarray(page_tables))
-            if draft_mask is not None:
-                kwargs["draft_mask"] = jnp.asarray(draft_mask)
-            res = self._gen(*args, **kwargs)
-            tokens = np.asarray(res.tokens)  # blocks until ready
-            t_end = time.perf_counter()
+                         if draft_mask is not None else 0):
+                args = (self.params, jnp.asarray(prompt),
+                        jnp.asarray(tables), self._mask_arr,
+                        jnp.asarray(live),
+                        self.eos_id if self.ecfg.eos_early_exit else None)
+                kwargs = {}
+                if self.paged:
+                    args += (self._pool_k, self._pool_v,
+                             jnp.asarray(page_tables))
+                if draft_mask is not None:
+                    kwargs["draft_mask"] = jnp.asarray(draft_mask)
+                res = self._gen(*args, **kwargs)
+                tokens = np.asarray(res.tokens)  # blocks until ready
+                t_end = time.perf_counter()
             decode_s = t_end - t0
-            if tr:
-                tr.end("batch", tid=0, t=t_end, nfe=int(res.nfe))
-                batch_open = False
-            self.obs.timer.add(self._prog_kind, decode_s, int(res.nfe))
             self._h_dispatch.observe(decode_s, kind="batch")
 
             for task, row in calib_rows.items():
@@ -770,7 +758,7 @@ class Scheduler:
                 # rows of other tasks determine)
                 self.store.ingest(task, result_profile(res, row=row))
                 if tr:
-                    tr.instant("calibrate", t=t_end, task=task, row=row)
+                    tr.instant("calibrate", task=task, row=row)
                 if self.drafter is not None:
                     self.drafter.invalidate(task)
             if calib_rows and self.ecfg.store_path:
@@ -807,7 +795,8 @@ class Scheduler:
                     blocks_accepted=int(accepted[j]),
                     # batch granularity: the first block is only
                     # observable when the whole batch returns
-                    ttfb_s=queue_s + decode_s, token_ids=tuple(row)))
+                    ttfb_s=queue_s + decode_s,
+                    block_s=(queue_s + decode_s,), token_ids=tuple(row)))
                 self.stats.tokens += len(row)
                 self.stats.tokens_dropped += tokens.shape[1] - len(row)
                 self.stats.queue_s += queue_s
@@ -824,7 +813,7 @@ class Scheduler:
                 if tr:
                     tr.end("serve", tid=self.obs.slot_track(j), t=t_end,
                            tokens=len(row), nfe=steps)
-                    tr.aend("request", rs.req.uid, t=t_end)
+                    tr.aend("request", rs.req.uid)
                     served.add(j)
             if draft_mask is not None and int(drafted.sum()) > 0:
                 self.stats.blocks_drafted += int(drafted.sum())
@@ -861,8 +850,6 @@ class Scheduler:
             # retried run() can still serve every uid
             if tr:
                 tr.instant("batch_failed", tid=0)
-                if batch_open:
-                    tr.end("batch", tid=0, error=True)
                 for slot in self.slots:
                     if slot.rs is None:
                         continue
@@ -1040,24 +1027,16 @@ class Scheduler:
             tokens = jnp.asarray(ids[:end], jnp.int32)[None]
             prog = _seed_prefill_prog(self.cfg, self.max_len, ps, end,
                                       bool(start))
-            tr = self.obs.tracer
-            if tr:
-                tr.begin("seed_prefill", tid=0, start=start, end=end,
-                         pages=len(pages))
-            try:
-                if start:
-                    wpt = spt.copy()
-                    wpt[0, :start // ps] = -1  # chain pages stay immutable
-                    kp, vp = prog(self.params, tokens, kv["kp"], kv["vp"],
-                                  jnp.asarray(spt),
-                                  jnp.asarray([start], jnp.int32),
-                                  jnp.asarray(wpt))
-                else:
-                    kp, vp = prog(self.params, tokens, kv["kp"], kv["vp"],
-                                  jnp.asarray(spt))
-            finally:
-                if tr:
-                    tr.end("seed_prefill", tid=0)
+            if start:
+                wpt = spt.copy()
+                wpt[0, :start // ps] = -1  # chain pages stay immutable
+                kp, vp = prog(self.params, tokens, kv["kp"], kv["vp"],
+                              jnp.asarray(spt),
+                              jnp.asarray([start], jnp.int32),
+                              jnp.asarray(wpt))
+            else:
+                kp, vp = prog(self.params, tokens, kv["kp"], kv["vp"],
+                              jnp.asarray(spt))
             self._put_kv(kp, vp)
             self._count_nfe(1)
             self.stats.prefill_nfe += 1
@@ -1066,7 +1045,7 @@ class Scheduler:
             self.allocator.free(pages)
             raise
 
-    def _batch_seed_pending(self, n_slots: int) -> None:
+    def _batch_seed_pending(self, n_slots: int) -> int:
         """Seed the radix segments the next ``n_slots`` queued requests
         are missing, batching concurrent segments into ONE padded donor
         forward per dependency round (SERVING.md "Radix prefix cache",
@@ -1076,9 +1055,10 @@ class Scheduler:
         the segments that chain on round 0's. Segments are deduplicated
         by ``(tokens, start)`` — a burst of same-tenant cold requests
         seeds its template once. Page pressure aborts quietly: the
-        per-request claim re-seeds (and sheds load) exactly as before."""
+        per-request claim re-seeds (and sheds load) exactly as before.
+        Returns the number of segments seeded."""
         if n_slots <= 0 or not self.queue:
-            return
+            return 0
         ps = self.dcfg.page_size
         plans = []
         owned: set = set()  # segments already attributed this boundary
@@ -1101,6 +1081,7 @@ class Scheduler:
                     pos = b
             self._preseed_hits[rs.req.uid] = hits
             plans.append((row, m0, len(pfx_ids)))
+        seeded = 0
         for _round in range(2):
             segs: Dict[tuple, tuple] = {}
             for row, m0, L in plans:
@@ -1113,11 +1094,13 @@ class Scheduler:
                 segs.setdefault((tuple(row[:end]), matched),
                                 (row, matched, end, list(mpages)))
             if not segs:
-                return
+                break
             try:
                 self._seed_segments(list(segs.values()))
             except MemoryError:
-                return
+                break
+            seeded += len(segs)
+        return seeded
 
     def _seed_segments(self, segs: List[tuple]) -> None:
         """Seed a round of independent segments and insert each into the
@@ -1166,18 +1149,10 @@ class Scheduler:
             kv = self._live_kv()
             prog = _seed_prefill_batched_prog(self.cfg, self.max_len, ps,
                                               S, bucket)
-            tr = self.obs.tracer
-            if tr:
-                tr.begin("seed_prefill_batched", tid=0, segments=n,
-                         bucket=bucket, tokens=S)
-            try:
-                kp, vp = prog(self.params, jnp.asarray(tokens),
-                              kv["kp"], kv["vp"], jnp.asarray(spt),
-                              jnp.asarray(plen), jnp.asarray(vlen),
-                              jnp.asarray(wpt))
-            finally:
-                if tr:
-                    tr.end("seed_prefill_batched", tid=0)
+            kp, vp = prog(self.params, jnp.asarray(tokens),
+                          kv["kp"], kv["vp"], jnp.asarray(spt),
+                          jnp.asarray(plen), jnp.asarray(vlen),
+                          jnp.asarray(wpt))
             self._put_kv(kp, vp)
             self._count_nfe(1)
             self.stats.prefill_nfe += 1
@@ -1212,20 +1187,27 @@ class Scheduler:
         # pre-pass) are cost, not reuse — report the pre-seed depth
         hit_pages = self._preseed_hits.pop(req.uid, len(mpages))
         if matched < L:
-            try:
-                for b in (m0, L):
-                    if matched < b:
-                        self._evict_pages((b - matched)
-                                          // self.dcfg.page_size)
-                        new = self._seed_segment(row, matched, b, mpages)
-                        if self.prefix_tree.insert(row, matched, new):
-                            self.stats.prefix_inserts += 1
-                        else:  # cannot happen single-threaded (the walk
-                            # just missed); keep the ledger honest anyway
-                            self.allocator.free(new)
-                        matched, mpages, _ = self.prefix_tree.match(row)
-            except MemoryError:
-                return None
+            with self.obs.tracer.span("sched.seed") as sp:
+                seeded = 0
+                try:
+                    for b in (m0, L):
+                        if matched < b:
+                            self._evict_pages((b - matched)
+                                              // self.dcfg.page_size)
+                            new = self._seed_segment(row, matched, b,
+                                                     mpages)
+                            seeded += 1
+                            if self.prefix_tree.insert(row, matched, new):
+                                self.stats.prefix_inserts += 1
+                            else:  # cannot happen single-threaded (the
+                                # walk just missed); keep the ledger
+                                # honest anyway
+                                self.allocator.free(new)
+                            matched, mpages, _ = self.prefix_tree.match(row)
+                except MemoryError:
+                    return None
+                finally:
+                    sp.set_metadata(segments=seeded)
         need = self.n_log - len(mpages)
         self._evict_pages(need)
         if self.allocator.available < need:
@@ -1240,9 +1222,10 @@ class Scheduler:
 
     def _admit_sliced(self) -> List[Slot]:
         """Pop admissible requests into free slots (FIFO; paged admission
-        gates on PAGE availability), update the carry's rows, and run the
-        one batched admission prefill. Returns the slots admitted at this
-        boundary."""
+        gates on PAGE availability) and write their rows into the carry.
+        Returns the slots admitted at this boundary; their admission
+        prefill is ``_admit_prefill``'s."""
+        tr = self.obs.tracer
         free = [s for s in self.slots if s.state == "free"]
         admitted: List[Slot] = []
         now = time.perf_counter()
@@ -1253,7 +1236,8 @@ class Scheduler:
             # admissions will need in batched donor forwards BEFORE the
             # per-request claims walk the tree (each then finds its
             # chain resident)
-            self._batch_seed_pending(len(free))
+            with tr.span("sched.seed") as sp:
+                sp.set_metadata(segments=self._batch_seed_pending(len(free)))
         for slot in free:
             if not self.queue:
                 break
@@ -1299,9 +1283,8 @@ class Scheduler:
             if not self.store.calibrated(t) and t not in self._calibrating:
                 self._calibrating[t] = slot.index
                 slot.calib_task = t
-            if self.obs.tracer:
-                tr = self.obs.tracer
-                tr.aend("queued", rs.req.uid, t=now)
+            if tr:
+                tr.aend("queued", rs.req.uid)
                 tr.begin("serve", tid=self.obs.slot_track(slot.index),
                          t=now, uid=rs.req.uid, task=t, mid=mid_gen,
                          prefix_len=slot.prefix_len)
@@ -1335,85 +1318,90 @@ class Scheduler:
                                        np.asarray(tables), self.mask_id,
                                        page_rows=page_rows,
                                        mark_prompt_pos=self.prefix_cache)
-        if self._admit_fn is not None:
-            admit_mask = np.zeros((self.ecfg.batch_size,), bool)
-            admit_mask[rows] = True
-            tr = self.obs.tracer
-            if self.prefix_cache:
-                P = self.ecfg.prompt_len
-                if all(s.prefix_len == P for s in admitted):
-                    # zero-prefill admission: every prompt position of
-                    # every admitted row is already resident in tree
-                    # pages (admit_carry_rows marked pos/length) — the
-                    # composed forward would compute nothing fresh
-                    if tr:
-                        tr.instant("zero_prefill_admit", tid=0,
-                                   rows=len(admitted))
-                    return admitted
-            if tr:
-                tr.begin("admit_prefill", tid=0, rows=len(admitted))
-            try:
-                if self.prefix_cache:
-                    pfx = np.zeros((self.ecfg.batch_size,), np.int32)
-                    for s in admitted:
-                        pfx[s.index] = s.prefix_len
-                    self._carry = self._admit_fn(self.params, self._carry,
-                                                 jnp.asarray(admit_mask),
-                                                 jnp.asarray(pfx))
-                else:
-                    self._carry = self._admit_fn(self.params, self._carry,
-                                                 jnp.asarray(admit_mask))
-            finally:
-                if tr:
-                    tr.end("admit_prefill", tid=0)
-            self.stats.prefill_nfe += 1
         return admitted
+
+    def _admit_prefill(self, admitted: List[Slot]) -> None:
+        """Dispatch the one batched admission prefill over the rows
+        ``_admit_sliced`` just wrote (nothing to dispatch without a
+        cache, or when every prompt position is already resident)."""
+        if not admitted or self._admit_fn is None:
+            return
+        tr = self.obs.tracer
+        if self.prefix_cache:
+            P = self.ecfg.prompt_len
+            if all(s.prefix_len == P for s in admitted):
+                # zero-prefill admission: every prompt position of every
+                # admitted row is already resident in tree pages
+                # (admit_carry_rows marked pos/length) — the composed
+                # forward would compute nothing fresh
+                if tr:
+                    tr.instant("zero_prefill_admit", tid=0,
+                               rows=len(admitted))
+                return
+        admit_mask = np.zeros((self.ecfg.batch_size,), bool)
+        admit_mask[[s.index for s in admitted]] = True
+        with tr.span("sched.admit_prefill", rows=len(admitted)):
+            if self.prefix_cache:
+                pfx = np.zeros((self.ecfg.batch_size,), np.int32)
+                for s in admitted:
+                    pfx[s.index] = s.prefix_len
+                self._carry = self._admit_fn(self.params, self._carry,
+                                             jnp.asarray(admit_mask),
+                                             jnp.asarray(pfx))
+            else:
+                self._carry = self._admit_fn(self.params, self._carry,
+                                             jnp.asarray(admit_mask))
+        self.stats.prefill_nfe += 1
 
     def _retire_sliced(self) -> List[Response]:
         """Emit responses for rows whose cursor ran out or that
         EOS-retired, reclaim their pages immediately (the next
         ``_admit_sliced`` can hand them out), and ingest any finished
         calibration row."""
-        carry = self._carry
-        cursor = np.asarray(carry.cursor)
-        live = np.asarray(carry.live)
-        nb = self.dcfg.num_blocks
-        done = [s for s in self.slots if s.state == "active"
-                and (cursor[s.index] >= nb or not live[s.index])]
-        if not done:
-            return []
-        tokens = np.asarray(carry.resp)
-        seq_steps = np.asarray(carry.seq_steps)
-        drafted = np.asarray(carry.blocks_drafted)
-        accepted = np.asarray(carry.blocks_accepted)
-        res = carry.result()
         tr = self.obs.tracer
+        carry = self._carry
+        nb = self.dcfg.num_blocks
         drift = self.obs.drift
-        if drift is not None:
-            thr = np.asarray(carry.thr_steps)
-            msum = np.asarray(carry.margin_sum)
-            mn = np.asarray(carry.margin_n)
-            # convert the batch recording ONCE — ``result_profile`` per
-            # row would re-pull the full device arrays per retirement
-            conf_rec = np.asarray(res.conf)
-            val_rec = np.asarray(res.conf_valid)
+        with tr.span("sched.pull"):
+            cursor = np.asarray(carry.cursor)
+            live = np.asarray(carry.live)
+            done = [s for s in self.slots if s.state == "active"
+                    and (cursor[s.index] >= nb or not live[s.index])]
+            if not done:
+                return []
+            tokens = np.asarray(carry.resp)
+            seq_steps = np.asarray(carry.seq_steps)
+            drafted = np.asarray(carry.blocks_drafted)
+            accepted = np.asarray(carry.blocks_accepted)
+            if drift is not None or any(s.calib_task for s in done):
+                # the batch recording ONCE, not one full pull per row
+                conf_rec = np.asarray(carry.conf)
+                val_rec = np.asarray(carry.conf_valid)
+            if drift is not None:
+                thr = np.asarray(carry.thr_steps)
+                msum = np.asarray(carry.margin_sum)
+                mn = np.asarray(carry.margin_n)
         out: List[Response] = []
         for slot in done:
             j, rs = slot.index, slot.rs
             if slot.calib_task:
-                self.store.ingest(slot.calib_task,
-                                  result_profile(res, row=j))
-                if self.drafter is not None:
-                    self.drafter.invalidate(slot.calib_task)
-                self._calibrating.pop(slot.calib_task, None)
-                if self.ecfg.store_path:
-                    self.store.save(self.ecfg.store_path)
+                with tr.span("sched.ingest", task=slot.calib_task):
+                    self.store.ingest(slot.calib_task, CalibrationProfile(
+                        conf=conf_rec[j], valid=val_rec[j],
+                        steps=seq_steps[j]))
+                    if self.drafter is not None:
+                        self.drafter.invalidate(slot.calib_task)
+                    self._calibrating.pop(slot.calib_task, None)
+                    if self.ecfg.store_path:
+                        self.store.save(self.ecfg.store_path)
             row = tokens[j].tolist()
             if self.eos_id in row:
                 row = row[:row.index(self.eos_id)]
             row = [t for t in row if t != self.mask_id]
             queue_s = rs.t_admit - rs.t_submit
             steps = int(seq_steps[j].sum())
+            block_s = tuple(t - rs.t_submit for t in slot.block_t)
+            ttfb_s = block_s[0] if block_s else 0.0
             out.append(Response(
                 rs.req.uid, rs.req.task, tok.decode(row),
                 nfe=steps, wall_s=queue_s + slot.decode_s,
@@ -1421,14 +1409,14 @@ class Scheduler:
                 tokens_out=len(row),
                 tokens_dropped=tokens.shape[1] - len(row),
                 blocks_drafted=int(drafted[j]),
-                blocks_accepted=int(accepted[j]), ttfb_s=slot.ttfb_s,
-                prefix_hit_pages=slot.prefix_hit_pages,
+                blocks_accepted=int(accepted[j]), ttfb_s=ttfb_s,
+                block_s=block_s, prefix_hit_pages=slot.prefix_hit_pages,
                 prefill_tokens_saved=slot.prefix_hit_pages
                 * self.dcfg.page_size, token_ids=tuple(row)))
             self.stats.tokens += len(row)
             self.stats.tokens_dropped += tokens.shape[1] - len(row)
             self.stats.queue_s += queue_s
-            self.stats.ttfb_s += slot.ttfb_s
+            self.stats.ttfb_s += ttfb_s
             self.stats.seq_steps += steps
             self._h_queue.observe(queue_s)
             if drift is not None:
@@ -1446,80 +1434,102 @@ class Scheduler:
             # accumulate over the row's lifetime: bank them here
             self.stats.blocks_drafted += int(drafted[j])
             self.stats.blocks_accepted += int(accepted[j])
-            if self.paged and slot.pages is not None:
-                pages = slot.pages
-                if self.prefix_cache:
-                    # promote the row's now-immutable prompt pages into
-                    # the tree by refcount TRANSFER (no copy): the next
-                    # identical submission becomes a near-zero-prefill
-                    # full hit. Only whole pages strictly inside the
-                    # prompt qualify — the page straddling prompt/
-                    # generation was decode-written and stays private.
-                    ps = self.dcfg.page_size
-                    n_promo = (self.ecfg.prompt_len - slot.prefix_len) \
-                        // ps
-                    promo = pages[:n_promo]
-                    if promo and self.prefix_tree.insert(
-                            self._prompt_row(slot.rs),
-                            slot.prefix_len, promo):
-                        self.stats.prefix_inserts += 1
-                        if tr:
-                            tr.instant("promote", tid=0, uid=rs.req.uid,
-                                       pages=len(promo))
-                        pages = pages[n_promo:]
-                        n, _ = self.prefix_tree.trim()
-                        self.stats.prefix_evictions += n
-                    self.allocator.free(pages)
-                    self.allocator.free(slot.prefix_pages or [])
-                else:
-                    self.allocator.free(pages)
-                    self.allocator.free(self._shared_for(slot))
-                self.stats.pages_freed += len(pages)
-            slot.retire()
-        self._carry = retire_carry_rows(carry, [s.index for s in done], nb)
+        with tr.span("sched.release") as sp:
+            freed = 0
+            for slot in done:
+                if self.paged and slot.pages is not None:
+                    freed += self._release_pages(slot)
+                slot.retire()
+            self._carry = retire_carry_rows(carry, [s.index for s in done],
+                                            nb)
+            sp.set_metadata(pages=freed)
         return out
+
+    def _release_pages(self, slot: Slot) -> int:
+        """Return a retiring slot's pages to the pool (promoting its
+        prompt pages into the radix tree first); the count freed."""
+        pages = slot.pages
+        if self.prefix_cache:
+            # promote the row's now-immutable prompt pages into the tree
+            # by refcount TRANSFER (no copy): the next identical
+            # submission becomes a near-zero-prefill full hit. Only
+            # whole pages strictly inside the prompt qualify — the page
+            # straddling prompt/generation was decode-written and stays
+            # private.
+            ps = self.dcfg.page_size
+            n_promo = (self.ecfg.prompt_len - slot.prefix_len) // ps
+            promo = pages[:n_promo]
+            if promo and self.prefix_tree.insert(
+                    self._prompt_row(slot.rs), slot.prefix_len, promo):
+                self.stats.prefix_inserts += 1
+                if self.obs.tracer:
+                    self.obs.tracer.instant("promote", tid=0,
+                                            uid=slot.rs.req.uid,
+                                            pages=len(promo))
+                pages = pages[n_promo:]
+                n, _ = self.prefix_tree.trim()
+                self.stats.prefix_evictions += n
+            self.allocator.free(pages)
+            self.allocator.free(slot.prefix_pages or [])
+        else:
+            self.allocator.free(pages)
+            self.allocator.free(self._shared_for(slot))
+        self.stats.pages_freed += len(pages)
+        return len(pages)
 
     def slice_step(self) -> List[Response]:
         """One slice boundary: admit into free slots, dispatch ONE
         compiled ``slice_len``-block slice, retire finished rows, and
         return their responses. A no-op (returning ``[]``) when nothing
-        is queued or active."""
+        is queued or active. Each phase is a ``sched.*`` span
+        (SERVING.md "Observability")."""
         assert self.slice_len, "slice_step() needs EngineConfig.slice_len"
-        admitted = self._admit_sliced()
-        active = [s for s in self.slots if s.state == "active"]
-        if not active:
-            self._teardown_carry()
-            return []
+        tr = self.obs.tracer
+        with tr.span("sched.boundary", slice=int(self.stats.slices)) as bd:
+            with tr.span("sched.admit") as sp:
+                admitted = self._admit_sliced()
+                sp.set_metadata(rows=len(admitted))
+            self._admit_prefill(admitted)
+            active = [s for s in self.slots if s.state == "active"]
+            bd.set_metadata(live=len(active), admitted=len(admitted))
+            if not active:
+                self._teardown_carry()
+                return []
+            out = self._slice_and_retire(admitted, active)
+            bd.set_metadata(retired=len(out))
+            return out
+
+    def _slice_and_retire(self, admitted: List[Slot],
+                          active: List[Slot]) -> List[Response]:
+        """The boundary after admission: plan drafts, dispatch and wait
+        for the slice, stamp completed blocks, retire finished rows."""
+        tr = self.obs.tracer
         draft_mask = None
         if self.spec and admitted:
             # slice-boundary draft (re-)planning: ONLY the rows admitted
             # at this boundary get a plan — rows mid-decode already
             # drafted at their own admission
-            fresh = {s.index for s in admitted}
-            plan = [s.rs.req.task if s.index in fresh and s.rs is not None
-                    else None for s in self.slots]
-            dm = self.drafter.plan_remaining(
-                plan, np.asarray(self._carry.cursor))
-            if dm.any():
-                draft_mask = jnp.asarray(dm)
-                self.stats.draft_batches += 1
-        tr = self.obs.tracer
-        slice_open = False
+            with tr.span("sched.plan") as sp:
+                fresh = {s.index for s in admitted}
+                plan = [s.rs.req.task if s.index in fresh
+                        and s.rs is not None else None for s in self.slots]
+                dm = self.drafter.plan_remaining(
+                    plan, np.asarray(self._carry.cursor))
+                sp.set_metadata(draft_blocks=int(dm.sum()))
+                if dm.any():
+                    draft_mask = jnp.asarray(dm)
+                    self.stats.draft_batches += 1
         try:
             t0 = time.perf_counter()
-            if tr:
-                tr.begin("slice", tid=0, t=t0, rows_live=len(active),
-                         pages_in_use=self.allocator.in_use
-                         if self.paged else 0,
-                         draft_blocks=int(draft_mask.sum())
-                         if draft_mask is not None else 0)
-                slice_open = True
-            self._carry = self._slice_fn(
-                self.params, self._carry, self._mask_arr,
-                self.eos_id if self.ecfg.eos_early_exit else None,
-                draft_mask)
-            cursor = np.asarray(self._carry.cursor)  # blocks until ready
-            t_end = time.perf_counter()
+            with tr.span("sched.slice", live=len(active)):
+                self._carry = self._slice_fn(
+                    self.params, self._carry, self._mask_arr,
+                    self.eos_id if self.ecfg.eos_early_exit else None,
+                    draft_mask)
+            with tr.span("sched.wait"):
+                cursor = np.asarray(self._carry.cursor)  # blocks until ready
+                t_end = time.perf_counter()
+                nfe_now = int(np.asarray(self._carry.nfe))
         except BaseException:
             # a failed slice must not swallow in-flight requests or leak
             # their pages: requeue FIFO (by submit time) and reclaim.
@@ -1527,8 +1537,6 @@ class Scheduler:
             # re-claim its calibration row, so back out both here.
             if tr:
                 tr.instant("slice_failed", tid=0)
-                if slice_open:
-                    tr.end("slice", tid=0, error=True)
             for slot in sorted(active, key=lambda s: s.rs.t_submit,
                                reverse=True):
                 if tr:
@@ -1563,21 +1571,20 @@ class Scheduler:
         wall = t_end - t0
         self.stats.wall_s += wall
         self.stats.slices += 1
-        nfe_now = int(np.asarray(self._carry.nfe))
         nfe_delta = nfe_now - self._nfe_seen
         self._count_nfe(nfe_delta)
         self._nfe_seen = nfe_now
-        self.obs.timer.add(self._prog_kind, wall, nfe_delta)
         self._h_dispatch.observe(wall, kind="slice")
-        if tr:
-            tr.end("slice", tid=0, t=t_end, nfe=nfe_delta)
-            if self.paged:
-                tr.counter("pages_in_use", self.allocator.in_use, t=t_end)
+        if tr and self.paged:
+            tr.counter("pages_in_use", self.allocator.in_use)
         for slot in active:
             slot.decode_s += wall
-            if not slot.ttfb_s and cursor[slot.index] > 0:
-                slot.ttfb_s = t_end - slot.rs.t_submit
-        out = self._retire_sliced()
+            # every block this slice completed becomes observable now
+            n = int(cursor[slot.index]) - len(slot.block_t)
+            slot.block_t.extend([t_end] * n)
+        with tr.span("sched.retire") as sp:
+            out = self._retire_sliced()
+            sp.set_metadata(rows=len(out))
         if not self.queue and \
                 not any(s.state == "active" for s in self.slots):
             self._teardown_carry()

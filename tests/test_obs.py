@@ -16,9 +16,14 @@ The contracts enforced here:
 * **Trace integrity** — one balanced span tree per submitted request,
   across mid-generation admission AND failed-slice/failed-batch
   requeues.
+* **Profiler spans** — every slice boundary's ``sched.*`` phases reach
+  an active ``jax.profiler`` session, nested and in order, whether or
+  not the ring is on; the ring, when on, holds the same spans.
 """
 import dataclasses
+import glob
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +37,7 @@ from repro.core.decoder import make_generate_fn, result_profile
 from repro.core.osdt import CalibrationStore
 from repro.data import tokenizer as tok
 from repro.obs.drift import DriftMonitor
-from repro.obs.metrics import MetricsRegistry, StepTimer
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer, validate_trace
 from repro.serving.scheduler import Request, Scheduler
 
@@ -114,6 +119,8 @@ def test_disabled_tracer_is_falsy_and_silent():
     assert not t
     t.begin("a")
     t.end("a")
+    with t.span("sched.x", rows=1) as sp:
+        sp.set_metadata(pages=2)
     t.instant("x")
     t.abegin("r", 1)
     t.aend("r", 1)
@@ -188,21 +195,6 @@ def test_registry_rejects_kind_and_sign_errors():
         r.gauge("x", "now a gauge?")
     with pytest.raises(AssertionError):
         r.counter("x", "").inc(-1)
-
-
-def test_step_timer_rows_and_publish():
-    t = StepTimer()
-    t.add("dense/sliced/unfused", 0.002, 4)
-    t.add("dense/sliced/unfused", 0.004, 8)
-    t.add("paged/batch/fused", 0.001, 2)
-    rows = t.rows()
-    us, fwd, disp = rows["dense/sliced/unfused"]
-    assert fwd == 12 and disp == 2
-    assert us == pytest.approx(0.006 / 12 * 1e6)
-    r = MetricsRegistry()
-    t.publish(r)
-    text = r.prometheus()
-    assert 'repro_dispatch_forwards{kind="paged/batch/fused"} 2' in text
 
 
 def test_engine_stats_is_registry_view(small_model, calibrated_store):
@@ -354,8 +346,9 @@ def test_trace_covers_request_lifecycle(small_model, calibrated_store):
     counts = validate_trace(doc)
     assert counts["spans"] > 0 and counts["async"] > 0
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"request", "queued", "serve", "slice",
-            "admit_prefill"} <= names
+    assert {"request", "queued", "serve", "sched.boundary", "sched.admit",
+            "sched.admit_prefill", "sched.slice", "sched.wait",
+            "sched.retire", "sched.pull", "sched.release"} <= names
     bal = _async_balance(s.obs.tracer)
     for uid in (0, 50):
         assert bal.get(("request", uid, "request"), 0) == 0, uid
@@ -397,6 +390,10 @@ def test_trace_balanced_across_failed_slice(small_model, calibrated_store):
         assert bal.get(("request", uid, "queued"), 0) == 0
     names = [e["name"] for e in doc["traceEvents"]]
     assert "slice_failed" in names
+    # the failed slice's own span closes as an error
+    assert any(e["name"] == "sched.slice" and e["ph"] == "E"
+               and (e.get("args") or {}).get("error")
+               for e in doc["traceEvents"])
     # the failed slice's serve spans carry the requeue marker
     assert any(e["name"] == "serve" and e["ph"] == "E"
                and (e.get("args") or {}).get("requeued")
@@ -427,7 +424,8 @@ def test_trace_balanced_across_failed_batch(small_model, calibrated_store):
     for uid in (0, 1):
         assert bal.get(("request", uid, "request"), 0) == 0
         assert bal.get(("request", uid, "queued"), 0) == 0
-    assert "batch_failed" in [e["name"] for e in doc["traceEvents"]]
+    names = [e["name"] for e in doc["traceEvents"]]
+    assert "batch_failed" in names and "sched.batch" in names
 
 
 def test_trace_save_roundtrip(tmp_path, small_model, calibrated_store):
@@ -442,15 +440,148 @@ def test_trace_save_roundtrip(tmp_path, small_model, calibrated_store):
     assert doc["otherData"]["dropped"] == 0
 
 
-def test_measured_dispatch_timing(small_model, calibrated_store):
-    """Every dispatch lands in the StepTimer under its program kind, and
-    the us/forward column is finite and positive."""
+# ---------------------------------------------------------------------------
+# profiler spans (the sched.* taxonomy on the device trace's clock)
+# ---------------------------------------------------------------------------
+
+# the benchmark harness's own span names (bench/devtrace.py): its traced
+# window and idle-gap labels are built from them, so no program span may
+# take one
+HARNESS_SPANS = ("generator", "slice_step", "responses", "idle_wait")
+
+
+def _profiled(tmp_path, s, reqs, later=()):
+    """Drive ``s`` under a profiler session (``later`` is submitted after
+    the first boundary, so it admits mid-generation); returns the
+    responses and the host ``sched.*`` events as (start, end, name,
+    args), sorted by start."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        s.submit(list(reqs))
+        out = s.slice_step()
+        s.submit(list(later))
+        out += _drain(s)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    evs, names = [], set()
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                names.add(e.name)
+                if e.name.startswith("sched."):
+                    evs.append((e.start_ns, e.end_ns, e.name,
+                                {k: v for k, v in e.stats}))
+    evs.sort(key=lambda e: (e[0], -e[1]))
+    return out, evs, names
+
+
+def _within(evs, outer):
+    s0, e0 = outer[0], outer[1]
+    return [e for e in evs if e is not outer and s0 <= e[0] and e[1] <= e0]
+
+
+def _ring_spans(tracer):
+    """(name, count) of the ring's closed engine-track spans."""
+    got = {}
+    for ph, name, tid, ts, args, eid, cat in tracer.events():
+        if ph == "E" and name.startswith("sched."):
+            got[name] = got.get(name, 0) + 1
+    return got
+
+
+@pytest.mark.parametrize("ring", [False, True])
+def test_profiler_records_boundary_phases(tmp_path, small_model,
+                                          calibrated_store, ring):
+    """Every ``sched.boundary`` holds its admission (when it admitted),
+    the slice dispatch, the wait and the retirement, nested and in that
+    order; one boundary per slice; the calibrating row's ingest shows;
+    no program span takes a harness span's name. With the ring on it
+    holds the same spans (and validates); with it off it stays empty
+    while the profiler still records."""
     cfg, params = small_model
-    s = _sched(cfg, params, calibrated_store)
-    s.submit(_requests("alpha", 2))
-    s.run()
-    rows = s.obs.timer.rows()
-    assert list(rows) == ["dense/sliced/unfused"]
-    us, fwd, disp = rows["dense/sliced/unfused"]
-    assert fwd == s.stats.nfe and disp == s.stats.slices
-    assert us > 0
+    s = _sched(cfg, params, calibrated_store, trace=ring,
+               dcfg_kw=dict(cache_layout="paged"))
+    out, evs, names = _profiled(
+        tmp_path, s, _requests("alpha", 1) + _requests("gamma", 1, 20),
+        later=_requests("beta", 1, 50))
+    assert sorted(r.uid for r in out) == [0, 20, 50]
+    assert not names & set(HARNESS_SPANS)
+    bounds = [e for e in evs if e[2] == "sched.boundary"]
+    assert len(bounds) == s.stats.slices == sum(
+        e[2] == "sched.slice" for e in evs)
+    admitted = retired = 0
+    for b in bounds:
+        inner = _within(evs, b)
+        order = [e[2] for e in inner
+                 if e[2] in ("sched.admit", "sched.slice", "sched.wait",
+                             "sched.retire")]
+        assert order == ["sched.admit", "sched.slice", "sched.wait",
+                         "sched.retire"], order
+        args = b[3]
+        assert args["live"] >= 1 and args["slice"] == bounds.index(b)
+        admitted += args["admitted"]
+        retired += args["retired"]
+        if args["admitted"]:
+            assert "sched.admit_prefill" in [e[2] for e in inner]
+        for r in (e for e in inner if e[2] == "sched.retire"):
+            phases = [e[2] for e in _within(evs, r)]
+            assert phases[0] == "sched.pull"
+            assert ("sched.release" in phases) == (r[3]["rows"] > 0)
+    assert admitted == retired == 3
+    ingest = [e for e in evs if e[2] == "sched.ingest"]
+    assert [e[3]["task"] for e in ingest] == ["gamma"]
+    counts = {}
+    for e in evs:
+        counts[e[2]] = counts.get(e[2], 0) + 1
+    if ring:
+        validate_trace(s.obs.tracer.export())
+        assert _ring_spans(s.obs.tracer) == counts
+    else:
+        assert s.obs.tracer.events() == []
+
+
+def test_profiler_records_seed_and_plan(tmp_path, small_model,
+                                        calibrated_store):
+    """The radix cache's seeding and the drafter's planning get their
+    own spans, with host-held counts as arguments."""
+    cfg, params = small_model
+    s = _sched(cfg, params, calibrated_store, prefix_cache=True,
+               spec_decode=True, shared_prefix="a shared system prompt.",
+               prompt_len=32, dcfg_kw=dict(cache_layout="paged"))
+    _, evs, _ = _profiled(tmp_path, s, _requests("alpha", 2))
+    seeds = [e for e in evs if e[2] == "sched.seed"]
+    assert seeds and sum(e[3]["segments"] for e in seeds) >= 1
+    plans = [e for e in evs if e[2] == "sched.plan"]
+    assert plans and all(e[3]["draft_blocks"] >= 0 for e in plans)
+    bounds = [e for e in evs if e[2] == "sched.boundary"]
+    for e in seeds + plans:
+        assert any(b[0] <= e[0] and e[1] <= b[1] for b in bounds)
+
+
+@pytest.mark.parametrize("slice_len", [0, 1])
+def test_response_block_times(small_model, calibrated_store, slice_len):
+    """Sliced decode stamps every block: ``num_blocks`` strictly
+    increasing times from submit, the first of them the ttfb. The batch
+    runtime observes only the batch end."""
+    cfg, params = small_model
+    s = _sched(cfg, params, calibrated_store, slice_len=slice_len,
+               dcfg_kw=dict(cache_layout="paged"))
+    s.submit(_requests("alpha", 2) + _requests("beta", 1, 10))
+    out = s.run()
+    assert len(out) == 3
+    for r in out:
+        assert r.ttfb_s > 0 and r.block_s[0] == r.ttfb_s
+        if slice_len:
+            assert len(r.block_s) == DCFG.num_blocks
+            assert all(b > a for a, b in zip(r.block_s, r.block_s[1:]))
+            # the slices the row decoded in lie between its admission
+            # and its last block
+            assert r.block_s[-1] >= r.queue_s + r.decode_s
+        else:
+            assert r.block_s == (r.wall_s,)
