@@ -11,22 +11,21 @@ This kernel is purpose-built for ``model.block_step``:
 
 * **Per-row scalar-prefetched block geometry** — each row's
   ``[slot, block_start, exc0, exc1, kv_limit]`` vector is scalar-prefetched
-  as a ``[5, B]`` operand, so the BlockSpec index maps and ``pl.when``
-  tile-liveness guards resolve EVERY row's own block geometry before any
-  DMA is issued. The step-sliced decode loop's mixed-cursor batches (each
-  row denoising its own cursor block) therefore stay on the fused Pallas
-  path — uniform (scalar) calls are just the broadcast special case.
+  as a ``[5, B]`` operand, so the index maps, DMA addresses and ``pl.when``
+  liveness guards resolve EVERY row's own block geometry before any DMA is
+  issued. The step-sliced decode loop's mixed-cursor batches (each row
+  denoising its own cursor block) therefore stay on the fused Pallas path
+  — uniform (scalar) calls are just the broadcast special case.
 * **Length-aware tile skipping** — kv tiles entirely beyond a row's
-  ``kv_limit`` are skipped via ``pl.when`` AND their BlockSpec index maps
-  clamp to the row's last live tile, so revisited blocks issue no new DMA:
-  zero FLOPs and zero HBM reads for the unfilled cache region. A retired
-  row (``kv_limit == 0``) touches no cache tiles at all.
+  ``kv_limit`` are skipped via ``pl.when`` and fetch nothing: zero FLOPs
+  and zero HBM reads for the unfilled cache region. A retired row
+  (``kv_limit == 0``) touches no cache tiles at all.
 * **Native GQA** — queries are laid out ``[B, Kh, G*bs, D]`` so the whole
   q-group shares one kv head; no ``jnp.repeat`` materialisation of K/V.
 * **Fresh-block operands** — the active block's K/V ride as separate
-  ``[B, bs, Kh, D]`` inputs appended as extra kv tiles, so the step needs no
-  pre-write of the cache (the generic path copies the whole cache buffer per
-  layer per step just to insert the block). A sentinel write slot
+  ``[B, bs, Kh, D]`` inputs, one extra kv tile, so the step needs no
+  pre-write of the cache (the generic path copies the whole cache buffer
+  per layer per step just to insert the block). A sentinel write slot
   ``>= T`` (the sliced loop's finished rows) hides the fresh block, exactly
   like the XLA rows path's empty in-block window.
 * **Exact ``block_step`` masking** — slot validity (``pos >= 0``), the
@@ -35,12 +34,26 @@ This kernel is purpose-built for ``model.block_step``:
 
 Because attention here is bidirectional ("full" mode) the mask depends only
 on the KV side — every query row keeps the same columns — which is what lets
-a single ``[kt]`` validity vector drive the whole tile.
+a single ``[tile]`` validity vector drive the whole tile.
 
-The dense and paged layouts share ONE kernel body (``_attn_kernel``): the
-paged variant only swaps the kv operand routing (pool pages resolved per
-row through the scalar-prefetched page table) and adds the page-mapped
-liveness term. Oracle: ``ref.cached_block_attention_ref`` /
+The two layouts differ in how a kv tile reaches VMEM. The dense kernel's
+tile is a BlockSpec slab of the row's ``[T, Kh, D]`` buffer (``kt`` = 128
+slots, dead tiles clamped to the row's last live one, so they issue no
+DMA). The paged kernel's tile is a COMPUTE BLOCK of ``ppb`` pool pages:
+the pool stays in HBM (``pl.ANY``) and the kernel DMAs each live page of
+block ``c`` (row ``b``'s pages ``pt[b, c*ppb : (c+1)*ppb]``) into one slot
+of a double-buffered ``[2, ppb*ps, Kh, D]`` VMEM buffer, starting block
+``c+1``'s copies before it computes block ``c``. Pages past the row's
+``kv_limit`` and unmapped pages (``pt < 0``) are neither fetched nor
+computed, and a block with no live page is skipped whole; the V rows of
+a live block's unfetched pages are zeroed, since stale VMEM may hold NaN
+and ``0 * NaN`` would poison ``p @ v``. ``ppb`` comes from shapes alone
+(``_pages_per_block``): 128 keys a block, at most the row's page count,
+halved until the kernel's VMEM fits — so a per-step cost (pipeline
+overhead, the online-softmax state's rescale, a 128-lane ``exp``) is paid
+once per 128 keys, not once per 16-token page. Both bodies share the
+online-softmax math (``_make_accumulate``, ``_acc_init``/``_acc_finish``)
+and the mask terms. Oracle: ``ref.cached_block_attention_ref`` /
 ``ref.paged_block_attention_ref`` (one shared core). Off-TPU the dispatch
 in ``ops.py`` routes to the length-aware ``attend_flash`` path instead.
 """
@@ -60,6 +73,14 @@ NEG_INF = -1.0e30
 
 # rows of the [5, B] scalar-prefetch operand (one column per batch row)
 SLOT, BSTART, EXC0, EXC1, KVLIM = range(5)
+
+# keys per paged compute block: one 128-lane row of scores
+BLOCK_KEYS = 128
+# VMEM the paged kernel may plan for (v5e has 128 MiB, the scoped default
+# is 16), and the limit it asks the compiler for, with room for the
+# compiler's own temporaries
+VMEM_BUDGET = 48 * 2**20
+VMEM_LIMIT = 64 * 2**20
 
 
 def _round_up(x: int, m: int) -> int:
@@ -82,10 +103,18 @@ def _acc_init(m_scr, l_scr, acc_scr, n_scr):
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
     if n_scr is not None:
-        n_scr[0] = 0
+        for i in range(n_scr.shape[0]):
+            n_scr[i] = 0
 
 
-def _make_accumulate(q_ref, m_scr, l_scr, acc_scr, n_scr):
+def _count(n_scr, *incs):
+    """Debug counters: add ``incs[i]`` to counter ``i``."""
+    if n_scr is not None:
+        for i, inc in enumerate(incs):
+            n_scr[i] += inc
+
+
+def _make_accumulate(q_ref, m_scr, l_scr, acc_scr):
     """One online-softmax update over a kv tile for ALL kv heads at once —
     THE one definition of the flash-accumulator math, shared by the dense
     and paged bodies. ``k``/``v`` are [tile, Kh, D] in the cache's stored
@@ -113,103 +142,206 @@ def _make_accumulate(q_ref, m_scr, l_scr, acc_scr, n_scr):
             "hqk,khd->hqd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
-        if n_scr is not None:
-            n_scr[0] += 1
 
     return accumulate
 
 
 def _acc_finish(o_ref, cnt_ref, m_scr, l_scr, acc_scr, n_scr):
-    """Normalise and write the output tile (guarding fully-masked rows)."""
+    """Normalise and write the output tile (guarding fully-masked rows);
+    in debug mode also write the counters."""
     l = jnp.maximum(l_scr[...], 1e-30)
     o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
     if cnt_ref is not None:
-        cnt_ref[0, 0] = jnp.full(cnt_ref.shape[2:], n_scr[0], jnp.int32)
+        col = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape[2:], 1)
+        cnt = jnp.zeros(cnt_ref.shape[2:], jnp.int32)
+        for i in range(n_scr.shape[0]):
+            cnt = jnp.where(col == i, n_scr[i], cnt)
+        cnt_ref[0, 0] = cnt
 
 
-def _attn_kernel(s_ref, *args, paged: bool, nk: int, nkk: int, kt: int,
-                 bt: int, bs: int, T: int, exclude: bool, window: int,
-                 count_tiles: bool):
-    """ONE body for the dense and paged layouts.
+def _cache_valid(s_ref, b, pos, ids, *, bs: int, T: int, exclude: bool,
+                 window: int):
+    """[1, tile] validity of cache slots ``ids`` (positions ``pos``) for
+    row ``b``: filled, below the row's ``kv_limit``, not among the slots
+    the fresh block virtually overwrites (stale, served by the block
+    operand instead), outside the exclusion and inside the window."""
+    slot = s_ref[SLOT, b]
+    valid = (pos >= 0) & (ids < s_ref[KVLIM, b]) & (ids < T)
+    valid &= ~((ids >= slot) & (ids < slot + bs))
+    if exclude:
+        valid &= ~((ids >= s_ref[EXC0, b]) & (ids < s_ref[EXC1, b]))
+    if window:
+        qmax = s_ref[BSTART, b] + bs - 1  # block's last absolute pos
+        valid &= (qmax - pos) < window
+    return valid
+
+
+def _fresh_tile(s_ref, b, bk_ref, bv_ref, jb, accumulate, *, bt: int,
+                bs: int, T: int, exclude: bool, window: int):
+    """Attend fresh-block tile ``jb`` (rows ``[jb*bt, (jb+1)*bt)``)."""
+    slot = s_ref[SLOT, b]
+    r = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1) + jb * bt
+    # sentinel write slot >= T (sliced loop, finished rows): the fresh
+    # block is invisible, matching the rows path's empty in-block window
+    valid = (r < bs) & (slot + bs <= T)
+    if exclude:
+        ids = slot + r
+        valid &= ~((ids >= s_ref[EXC0, b]) & (ids < s_ref[EXC1, b]))
+    if window:
+        valid &= (bs - 1 - r) < window
+    accumulate(bk_ref[0], bv_ref[0], valid)           # [bt, Kh, D] each
+
+
+def _split_refs(refs, count_tiles: bool):
+    """(o_ref, cnt_ref, m, l, acc, layout scratch, n_scr) from the output
+    and scratch refs; ``cnt_ref``/``n_scr`` are None outside debug mode."""
+    if count_tiles:
+        o_ref, cnt_ref, *scr = refs
+        n_scr = scr.pop()
+    else:
+        (o_ref, *scr), cnt_ref, n_scr = refs, None, None
+    return (o_ref, cnt_ref, *scr[:3], scr[3:], n_scr)
+
+
+def _dense_kernel(s_ref, q_ref, ck_ref, cv_ref, bk_ref, bv_ref, pos_ref,
+                  *refs, nk: int, nkk: int, kt: int, bt: int, bs: int,
+                  T: int, exclude: bool, window: int, count_tiles: bool):
+    """Dense layout: grid step ``j < nk`` is cache tile ``j`` (a BlockSpec
+    slab of row ``b``'s buffer), the rest are fresh-block tiles.
 
     ``s_ref`` is the [5, B] per-row scalar operand (rows SLOT..KVLIM);
-    every mask term below reads row ``b = program_id(0)``'s own column, so
-    mixed-cursor batches resolve their own geometry. The paged variant
-    adds the page table (second prefetch operand) whose index maps routed
-    the kv tile to this row's pool page, and gates tile liveness on the
-    page being mapped. Each grid step covers every kv head of one
-    (row, q tile, kv tile): the kv blocks span the whole ``Kh`` dim, which
-    is what the TPU's (8, 128) tiling of the last two dims requires.
+    every mask term reads row ``b = program_id(0)``'s own column, so
+    mixed-cursor batches resolve their own geometry. Each grid step covers
+    every kv head of one (row, q tile, kv tile): the kv blocks span the
+    whole ``Kh`` dim, which is what the TPU's (8, 128) tiling of the last
+    two dims requires.
     """
-    if paged:
-        pt_ref, q_ref, ck_ref, cv_ref, bk_ref, bv_ref, pos_ref = args[:7]
-        refs = args[7:]
-    else:
-        q_ref, ck_ref, cv_ref, bk_ref, bv_ref, pos_ref = args[:6]
-        refs = args[6:]
-    if count_tiles:
-        o_ref, cnt_ref, m_scr, l_scr, acc_scr, n_scr = refs
-    else:
-        o_ref, m_scr, l_scr, acc_scr = refs
-        cnt_ref = n_scr = None
+    o_ref, cnt_ref, m_scr, l_scr, acc_scr, _, n_scr = _split_refs(
+        refs, count_tiles)
     b = pl.program_id(0)
     j = pl.program_id(2)
-    slot = s_ref[SLOT, b]
-    exc0 = s_ref[EXC0, b]
-    exc1 = s_ref[EXC1, b]
-    kv_limit = s_ref[KVLIM, b]
+    geom = dict(bs=bs, T=T, exclude=exclude, window=window)
 
     @pl.when(j == 0)
     def _init():
         _acc_init(m_scr, l_scr, acc_scr, n_scr)
 
-    accumulate = _make_accumulate(q_ref, m_scr, l_scr, acc_scr, n_scr)
-
+    accumulate = _make_accumulate(q_ref, m_scr, l_scr, acc_scr)
     is_cache = j < nk
-    tile_live = is_cache & ((j * kt) < kv_limit)
-    if paged:
-        jm = jnp.minimum(j, nk - 1)
-        tile_live &= pt_ref[b, jm] >= 0
 
-    @pl.when(tile_live)
+    @pl.when(is_cache & ((j * kt) < s_ref[KVLIM, b]))
     def _cache_tile():
-        k = ck_ref[0]                               # [kt, Kh, D]
-        v = cv_ref[0]
+        v = cv_ref[0]                               # [kt, Kh, D]
         if T % kt:
             # the last dense tile overhangs the buffer: zero the rows
             # past T so no out-of-bounds padding reaches the p @ v sum
             rid = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) + j * kt
             v = jnp.where(rid < T, v, jnp.zeros_like(v))
-        pos = pos_ref[0]                            # [1, kt] int32
         ids = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1) + j * kt
-        valid = (pos >= 0) & (ids < kv_limit) & (ids < T)
-        # slots the fresh block virtually overwrites: stale, served by the
-        # block operand instead
-        valid &= ~((ids >= slot) & (ids < slot + bs))
-        if exclude:
-            valid &= ~((ids >= exc0) & (ids < exc1))
-        if window:
-            qmax = s_ref[BSTART, b] + bs - 1  # block's last absolute pos
-            valid &= (qmax - pos) < window
-        accumulate(k, v, valid)
+        accumulate(ck_ref[0], v,
+                   _cache_valid(s_ref, b, pos_ref[0], ids, **geom))
+        _count(n_scr, 1)
 
     @pl.when(~is_cache)
     def _block_tile():
-        jb = j - nk
-        k = bk_ref[0]                               # [bt, Kh, D]
-        v = bv_ref[0]
-        r = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1) + jb * bt
-        # sentinel write slot >= T (sliced loop, finished rows): the fresh
-        # block is invisible, matching the rows path's empty in-block window
-        valid = (r < bs) & (slot + bs <= T)
-        if exclude:
-            ids = slot + r
-            valid &= ~((ids >= exc0) & (ids < exc1))
-        if window:
-            valid &= (bs - 1 - r) < window
-        accumulate(k, v, valid)
+        _fresh_tile(s_ref, b, bk_ref, bv_ref, j - nk, accumulate, bt=bt,
+                    **geom)
+        _count(n_scr, 1)
 
     @pl.when(j == nkk - 1)
+    def _finish():
+        _acc_finish(o_ref, cnt_ref, m_scr, l_scr, acc_scr, n_scr)
+
+
+def _paged_kernel(s_ref, pt_ref, q_ref, pool_k, pool_v, bk_ref, bv_ref,
+                  pos_ref, *refs, ppb: int, ps: int, n_log: int, nkb: int,
+                  bt: int, bs: int, T: int, exclude: bool, window: int,
+                  count_tiles: bool):
+    """Paged layout: grid step 0 is the fresh block, step ``j >= 1`` is
+    compute block ``c = j - 1`` of ``ppb`` pages, fetched by hand.
+
+    ``pool_k``/``pool_v`` stay in HBM. Step 0 starts block 0's page
+    copies and attends the fresh block meanwhile; step ``j`` starts block
+    ``j``'s copies into the other buffer slot, then waits for block
+    ``c``'s and attends it. Every started copy is waited for in the
+    step of its block: both use the same liveness test, so the DMA
+    semaphores balance whatever the row's holes and limit. Debug counters:
+    live mapped pages plus the block tile, and kv grid steps that did work.
+    """
+    o_ref, cnt_ref, m_scr, l_scr, acc_scr, (kbuf, vbuf, sem), n_scr = (
+        _split_refs(refs, count_tiles))
+    b = pl.program_id(0)
+    j = pl.program_id(2)
+    bk = ppb * ps
+    kv_limit = s_ref[KVLIM, b]
+    geom = dict(bs=bs, T=T, exclude=exclude, window=window)
+    accumulate = _make_accumulate(q_ref, m_scr, l_scr, acc_scr)
+
+    def page(c, i):
+        # (live, physical page) of page i of row b's block c: mapped,
+        # inside the table and starting below the row's kv_limit
+        lp = c * ppb + i
+        phys = pt_ref[b, jnp.minimum(lp, n_log - 1)]
+        live = (phys >= 0) & (lp * ps < kv_limit)
+        if nkb * ppb > n_log:
+            live &= lp < n_log
+        return live, phys
+
+    def copies(slot, i, phys):
+        dst = pl.ds(i * ps, ps)
+        return (pltpu.make_async_copy(pool_k.at[phys], kbuf.at[slot, dst],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(pool_v.at[phys], vbuf.at[slot, dst],
+                                      sem.at[1, slot]))
+
+    def fetch(c):
+        for i in range(ppb):
+            live, phys = page(c, i)
+
+            @pl.when(live)
+            def _start():
+                for cp in copies(jax.lax.rem(c, 2), i, phys):
+                    cp.start()
+
+    @pl.when(j == 0)
+    def _fresh():
+        _acc_init(m_scr, l_scr, acc_scr, n_scr)
+        fetch(jnp.int32(0))
+        _fresh_tile(s_ref, b, bk_ref, bv_ref, 0, accumulate, bt=bt, **geom)
+        _count(n_scr, 1, 1)
+
+    @pl.when((j > 0) & (j < nkb))
+    def _prefetch():
+        fetch(j)
+
+    c = jnp.maximum(j - 1, 0)
+    pages = [page(c, i) for i in range(ppb)]
+    n_live = sum(live.astype(jnp.int32) for live, _ in pages)
+
+    @pl.when((j > 0) & (n_live > 0))
+    def _cache_block():
+        slot = jax.lax.rem(c, 2)
+        pid = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        fetched = jnp.zeros((1, bk), jnp.int32)
+        for i, (live, phys) in enumerate(pages):
+            @pl.when(live)
+            def _wait():
+                for cp in copies(slot, i, phys):
+                    cp.wait()
+
+            @pl.when(~live)
+            def _zero():
+                vbuf[slot, pl.ds(i * ps, ps)] = jnp.zeros(
+                    (ps,) + vbuf.shape[2:], vbuf.dtype)
+
+            fetched = jnp.where((pid >= i * ps) & (pid < (i + 1) * ps),
+                                live.astype(jnp.int32), fetched)
+        valid = (fetched > 0) & _cache_valid(s_ref, b, pos_ref[c], pid + c * bk,
+                                       **geom)
+        accumulate(kbuf[slot], vbuf[slot], valid)
+        _count(n_scr, n_live, 1)
+
+    @pl.when(j == nkb)
     def _finish():
         _acc_finish(o_ref, cnt_ref, m_scr, l_scr, acc_scr, n_scr)
 
@@ -255,46 +387,38 @@ def _pad_block(block_k: Array, block_v: Array, bt: int):
 
 
 def _attn_call(kernel, prefetch, qf, kv_k, kv_v, block_k, block_v, pos3, *,
-               name: str, kv_index, live_m1, kt: int, bt: int, nk: int,
-               qt: int, debug_tile_counts: bool, interpret: bool):
+               name: str, kv_spec, pos_spec, blk_index, scratch, nkk: int,
+               bt: int, qt: int, n_counts: int, vmem_limit: Optional[int],
+               interpret: bool):
     """The ``pallas_call`` both layouts share. Grid ``(B, q tiles, kv
-    tiles)``; every block spans all ``Kh`` heads and all ``D``, so the
-    last two dims of each block are whole array dims. ``kv_index(b, j,
-    *prefetch)`` gives the leading index of cache tile ``j`` (dense: the
-    row, paged: the pool page) and ``live_m1`` the row's last live tile,
-    where dead tiles clamp (no new DMA)."""
+    steps)``; every block spans all ``Kh`` heads and all ``D``, so the
+    last two dims of each block are whole array dims. The layout supplies
+    the kv and position specs, the fresh block's index map and its own
+    scratch; in debug mode an extra output carries ``n_counts`` counters
+    per (row, q tile)."""
     B, Kh, Rp, D = qf.shape
     nq = Rp // qt
-    nkk = pos3.shape[0] + block_k.shape[1] // bt
     out_shape = [jax.ShapeDtypeStruct((B, Kh, Rp, D), qf.dtype)]
     out_specs = [pl.BlockSpec((1, Kh, qt, D),
                               lambda b, i, j, *_: (b, 0, i, 0))]
     scratch = [pltpu.VMEM((Kh, qt, 1), jnp.float32),
                pltpu.VMEM((Kh, qt, 1), jnp.float32),
-               pltpu.VMEM((Kh, qt, D), jnp.float32)]
-    # the tile-count output exists only in debug mode — production calls
+               pltpu.VMEM((Kh, qt, D), jnp.float32)] + scratch
+    # the counter output exists only in debug mode — production calls
     # pay for exactly one output buffer
-    if debug_tile_counts:
-        out_shape.append(jax.ShapeDtypeStruct((B, nq, 1, 1), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1, 1, 1),
+    if n_counts:
+        out_shape.append(
+            jax.ShapeDtypeStruct((B, nq, 1, n_counts), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, n_counts),
                                       lambda b, i, j, *_: (b, i, 0, 0)))
-        scratch.append(pltpu.SMEM((1,), jnp.int32))
-    kv_spec = pl.BlockSpec(
-        (1, kt, Kh, D),
-        lambda b, i, j, *pf: kv_index(b, jnp.minimum(j, live_m1(b, *pf)),
-                                      *pf))
-    blk_spec = pl.BlockSpec(
-        (1, bt, Kh, D),
-        lambda b, i, j, *_: (b, jnp.maximum(j - nk, 0), 0, 0))
+        scratch.append(pltpu.SMEM((n_counts,), jnp.int32))
+    blk_spec = pl.BlockSpec((1, bt, Kh, D), blk_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B, nq, nkk),
         in_specs=[
             pl.BlockSpec((1, Kh, qt, D), lambda b, i, j, *_: (b, 0, i, 0)),
-            kv_spec, kv_spec, blk_spec, blk_spec,
-            pl.BlockSpec((1, 1, kt),
-                         lambda b, i, j, *pf: (
-                             jnp.minimum(j, live_m1(b, *pf)), 0, 0)),
+            kv_spec, kv_spec, blk_spec, blk_spec, pos_spec,
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -305,21 +429,22 @@ def _attn_call(kernel, prefetch, qf, kv_k, kv_v, block_k, block_v, pos3, *,
         out_shape=out_shape,
         name=name,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(*prefetch, qf, kv_k, kv_v, block_k, block_v, pos3)
 
 
-def _unfold_out(res, q_shape, R: int, debug_tile_counts: bool):
+def _unfold_out(res, q_shape, R: int):
     """Undo ``_fold_q`` on the kernel output; in debug mode also return
-    the per-(row, q tile) count of kv tiles processed, [B, nq]."""
+    each per-(row, q tile) counter, [B, nq] each."""
     B, bs, H, D = q_shape
     Kh = res[0].shape[1]
     out = res[0][:, :, :R].reshape(B, Kh, H // Kh, bs, D)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, bs, H, D)
-    if debug_tile_counts:
-        return out, res[1][:, :, 0, 0]
-    return out
+    if len(res) == 1:
+        return out
+    return (out,) + tuple(res[1][:, :, 0, i] for i in range(res[1].shape[3]))
 
 
 def cached_block_attention_pallas(
@@ -382,26 +507,60 @@ def cached_block_attention_pallas(
     scalars = _row_scalars(B, slot, block_start, exclude_start, kv_limit,
                            exclude_len)
 
-    def live_m1(b, s):
-        # last live cache tile of ROW b (index maps clamp dead tiles here:
-        # revisiting the same block index issues no new DMA)
-        return jnp.maximum(pl.cdiv(s[KVLIM, b], kt) - 1, 0)
+    def tile(b, j, s):
+        # clamp dead tiles to ROW b's last live tile: revisiting the same
+        # block index issues no new DMA
+        return jnp.minimum(j, jnp.maximum(pl.cdiv(s[KVLIM, b], kt) - 1, 0))
 
     kernel = functools.partial(
-        _attn_kernel, paged=False, nk=nk, nkk=nk + nbk, kt=kt, bt=bt,
-        bs=bs, T=T, exclude=bool(exclude_len), window=window,
+        _dense_kernel, nk=nk, nkk=nk + nbk, kt=kt, bt=bt, bs=bs, T=T,
+        exclude=bool(exclude_len), window=window,
         count_tiles=debug_tile_counts)
     res = _attn_call(
         kernel, (scalars,), qf, cache_k, cache_v, block_k, block_v, pos3,
-        name="block_attention", kv_index=lambda b, jc, s: (b, jc, 0, 0), live_m1=live_m1, kt=kt,
-        bt=bt, nk=nk, qt=qt, debug_tile_counts=debug_tile_counts,
+        name="block_attention",
+        kv_spec=pl.BlockSpec((1, kt, Kh, D),
+                             lambda b, i, j, s: (b, tile(b, j, s), 0, 0)),
+        pos_spec=pl.BlockSpec((1, 1, kt),
+                              lambda b, i, j, s: (tile(b, j, s), 0, 0)),
+        blk_index=lambda b, i, j, *_: (b, jnp.maximum(j - nk, 0), 0, 0),
+        scratch=[], nkk=nk + nbk, bt=bt, qt=qt,
+        n_counts=int(debug_tile_counts), vmem_limit=None,
         interpret=interpret)
-    return _unfold_out(res, q.shape, R, debug_tile_counts)
+    return _unfold_out(res, q.shape, R)
 
 
 # ---------------------------------------------------------------------------
-# paged variant: page-table indirection via scalar prefetch
+# paged variant: multi-page compute blocks fetched by hand
 # ---------------------------------------------------------------------------
+
+def _paged_vmem_bytes(ppb: int, ps: int, n_log: int, Kh: int, qt: int,
+                      D: int, bt: int, itemsize: int) -> int:
+    """VMEM the paged kernel holds at ``ppb`` pages a block: the kv double
+    buffer, the pipeline's double-buffered q, output and fresh-block
+    tiles, the online-softmax state, the positions, and the block's
+    temporaries (scores, probabilities, the head-major copies of K/V)."""
+    bk = ppb * ps
+    khp = _round_up(Kh, 8 * (4 // itemsize))          # packed sublanes
+    kv = 2 * 2 * bk * khp * D * itemsize
+    q_out = 2 * 2 * Kh * qt * D * itemsize
+    fresh = 2 * 2 * bt * khp * D * itemsize
+    state = Kh * qt * D * 4 + 2 * Kh * qt * 128 * 4  # acc, m and l
+    pos = 2 * -(-n_log // ppb) * 8 * bk * 4
+    temps = 3 * Kh * qt * bk * 4 + 2 * Kh * bk * D * itemsize
+    return kv + q_out + fresh + state + pos + temps
+
+
+def _pages_per_block(ps: int, n_log: int, Kh: int, qt: int, D: int,
+                     bt: int, itemsize: int) -> int:
+    """Pages per compute block, from shapes alone: ``BLOCK_KEYS`` keys,
+    at most the row's ``n_log`` pages, halved until the VMEM fits."""
+    ppb = min(max(1, BLOCK_KEYS // ps), n_log)
+    while ppb > 1 and _paged_vmem_bytes(ppb, ps, n_log, Kh, qt, D, bt,
+                                        itemsize) > VMEM_BUDGET:
+        ppb //= 2
+    return ppb
+
 
 def paged_block_attention_pallas(
         q: Array, pool_k: Array, pool_v: Array, block_k: Array,
@@ -409,7 +568,8 @@ def paged_block_attention_pallas(
         block_start: Array, kv_limit: Optional[Array] = None,
         exclude_start: Optional[Array] = None, exclude_len: int = 0,
         window: int = 0, debug_tile_counts: bool = False,
-        interpret: bool = False) -> Union[Array, Tuple[Array, Array]]:
+        interpret: bool = False
+) -> Union[Array, Tuple[Array, Array, Array]]:
     """Block attention against a PAGED cache: pool pages are DMA'd
     directly — the dense [B, T] view is never materialised.
 
@@ -427,15 +587,22 @@ def paged_block_attention_pallas(
     (the fresh-block tile stays live unless the row's write slot is the
     ``>= T`` sentinel, so ride-along mask flushes keep working).
 
-    The page table rides as a second scalar-prefetch operand, so the kv
-    BlockSpec index maps resolve (row, logical page) → physical pool page
-    before the tile's DMA is issued; tiles that are beyond the row's
-    ``kv_limit`` OR unmapped clamp to the row's last live page (no new
-    DMA) and skip compute via ``pl.when`` — the paged mirror of the dense
-    kernel's ``kv_limit`` mechanism, which additionally skips *holes*
-    (dead rows, reclaimed pages), not just the tail. One kv tile == one
-    page, so ``page_size`` must be a multiple of 8 (float32 sublane
-    tiling).
+    The page table rides as a second scalar-prefetch operand, so the
+    kernel resolves (row, logical page) → physical pool page before it
+    issues a page's DMA. One kv grid step attends a compute block of
+    ``ppb`` pages (``_pages_per_block``: 8 pages of 16 tokens), fetched
+    page by page into a double-buffered VMEM slab; pages beyond the row's
+    ``kv_limit`` OR unmapped are neither fetched nor computed, and a block
+    with no live page is skipped — the paged mirror of the dense kernel's
+    ``kv_limit`` mechanism, which additionally skips *holes* (dead rows,
+    reclaimed pages), not just the tail. ``page_size`` must be a multiple
+    of 8 (float32 sublane tiling). The fresh block is one tile of
+    ``round_up(bs, 8)`` rows.
+
+    With ``debug_tile_counts=True`` also returns two per-(B, q_tile)
+    counters: live mapped pages plus the fresh-block tile (the HBM-traffic
+    proxy, as the dense kernel's tile count), and the kv grid steps that
+    did work (the fresh block plus each block with a live page).
     """
     B, bs, H, D = q.shape
     ps, Kh = pool_k.shape[1], pool_k.shape[2]
@@ -450,32 +617,32 @@ def paged_block_attention_pallas(
         exclude_len = 0
 
     qf, R, qt = _fold_q(q, Kh, 128)
-    bt = min(ps, _round_up(bs, 8))
-    block_k, block_v, nbk = _pad_block(block_k, block_v, bt)
+    bt = _round_up(bs, 8)
+    block_k, block_v, _ = _pad_block(block_k, block_v, bt)
+    ppb = _pages_per_block(ps, n_log, Kh, qt, D, bt, pool_k.dtype.itemsize)
+    bk = ppb * ps
+    nkb = -(-n_log // ppb)
 
-    # positions as [n_log, 1, ps] (padded with -1): one block per page
-    pos3 = jnp.pad(kv_pos.astype(jnp.int32), (0, n_log * ps - T),
-                   constant_values=-1).reshape(n_log, 1, ps)
+    # positions as [nkb, 1, bk] (padded with -1), resident in VMEM whole:
+    # one [1, bk] row per compute block
+    pos3 = jnp.pad(kv_pos.astype(jnp.int32), (0, nkb * bk - T),
+                   constant_values=-1).reshape(nkb, 1, bk)
     scalars = _row_scalars(B, slot, block_start, exclude_start, kv_limit,
                            exclude_len)
-    pt = page_table.astype(jnp.int32)
-
-    def live_m1(b, s, pt):
-        # last live tile of ROW b (per-row kv_limit)
-        return jnp.maximum(pl.cdiv(s[KVLIM, b], ps) - 1, 0)
-
-    def page_for(b, jc, s, pt):
-        # route (clamped) tile jc of row b to its pool page; dead or
-        # unmapped tiles land on a page already fetched, so the revisited
-        # block index issues no new DMA (compute is skipped by tile_live)
-        return (jnp.maximum(pt[b, jc], 0), 0, 0, 0)
 
     kernel = functools.partial(
-        _attn_kernel, paged=True, nk=n_log, nkk=n_log + nbk, kt=ps, bt=bt,
-        bs=bs, T=T, exclude=bool(exclude_len), window=window,
+        _paged_kernel, ppb=ppb, ps=ps, n_log=n_log, nkb=nkb, bt=bt, bs=bs,
+        T=T, exclude=bool(exclude_len), window=window,
         count_tiles=debug_tile_counts)
     res = _attn_call(
-        kernel, (scalars, pt), qf, pool_k, pool_v, block_k, block_v, pos3,
-        name="paged_block_attention", kv_index=page_for, live_m1=live_m1, kt=ps, bt=bt, nk=n_log, qt=qt,
-        debug_tile_counts=debug_tile_counts, interpret=interpret)
-    return _unfold_out(res, q.shape, R, debug_tile_counts)
+        kernel, (scalars, page_table.astype(jnp.int32)), qf, pool_k, pool_v,
+        block_k, block_v, pos3, name="paged_block_attention",
+        kv_spec=pl.BlockSpec(memory_space=pl.ANY),
+        pos_spec=pl.BlockSpec((nkb, 1, bk), lambda b, i, j, *_: (0, 0, 0)),
+        blk_index=lambda b, i, j, *_: (b, 0, 0, 0),
+        scratch=[pltpu.VMEM((2, bk, Kh, D), pool_k.dtype),
+                 pltpu.VMEM((2, bk, Kh, D), pool_v.dtype),
+                 pltpu.SemaphoreType.DMA((2, 2))],
+        nkk=nkb + 1, bt=bt, qt=qt, n_counts=2 * int(debug_tile_counts),
+        vmem_limit=VMEM_LIMIT, interpret=interpret)
+    return _unfold_out(res, q.shape, R)

@@ -12,7 +12,8 @@ from repro.config.registry import get_config
 from repro.core.decoder import make_generate_fn
 from repro.data import tokenizer as tok
 from repro.kernels import ref
-from repro.kernels.block_attention import paged_block_attention_pallas
+from repro.kernels.block_attention import (BLOCK_KEYS,
+                                           paged_block_attention_pallas)
 from repro.models import cache as cache_lib
 from repro.models import model as M
 from repro.serving.engine import DiffusionEngine
@@ -233,7 +234,7 @@ def test_paged_kernel_skips_dead_and_unmapped_pages():
     kv_pos = kv_pos.astype(jnp.int32)
     pt = np.full((B, n_log), -1, np.int32)
     pt[0, :] = np.arange(n_log)  # live row; row 1 stays dead
-    _, cnt = paged_block_attention_pallas(
+    _, cnt, _ = paged_block_attention_pallas(
         q, pool_k, pool_v, bk, bv, kv_pos, jnp.asarray(pt),
         slot=jnp.asarray(fill, jnp.int32),
         block_start=jnp.asarray(fill, jnp.int32),
@@ -266,7 +267,7 @@ def test_paged_kernel_per_row_kv_limit_skips_retired_rows():
     # BOTH rows fully mapped: only the limit distinguishes them
     pt = jnp.asarray(np.arange(B * n_log).reshape(B, n_log), np.int32)
     lim = jnp.asarray([fill, 0], jnp.int32)  # row 1 retired
-    got, cnt = paged_block_attention_pallas(
+    got, cnt, _ = paged_block_attention_pallas(
         q, pool_k, pool_v, bk, bv, kv_pos, pt,
         slot=jnp.asarray(fill, jnp.int32),
         block_start=jnp.asarray(fill, jnp.int32), kv_limit=lim,
@@ -320,7 +321,7 @@ def test_paged_kernel_per_row_mixed_cursors():
     bstart = jnp.asarray([8, 24, 40, 0], jnp.int32)
     lim = jnp.asarray([8, 24, 40, 0], jnp.int32)
     exc = jnp.asarray([0, 0, 16, 0], jnp.int32)     # row 2 excludes
-    got, cnt = paged_block_attention_pallas(
+    got, cnt, _ = paged_block_attention_pallas(
         q, pool_k, pool_v, bk, bv, kv_pos, pt, slot=slot,
         block_start=bstart, kv_limit=lim, exclude_start=exc,
         exclude_len=PS, debug_tile_counts=True, interpret=True)
@@ -337,6 +338,88 @@ def test_paged_kernel_per_row_mixed_cursors():
     assert (cnt[1] == 24 // PS - 1 + 1).all()       # hole page skipped
     assert (cnt[2] == 40 // PS + 1).all()
     assert (cnt[3] == 1).all()                      # masked block tile only
+
+
+MP_PS, MP_NLOG, MP_T, MP_BS = 16, 20, 316, 8   # 20 pages: 2.5 compute blocks
+
+
+def _multi_page_case(case):
+    """(page table, kv_limit, slot, extra kwargs) per row for a 4-row call
+    over 20 logical pages of 16 slots (T = 316: the last page is partial,
+    the last compute block holds 4 pages). Physical page 0 stays mapped
+    at row 0's first page: the oracle reads it for unmapped slots."""
+    B, T, bs = 4, MP_T, MP_BS
+    pt = np.random.default_rng(5).permutation(B * MP_NLOG).reshape(
+        B, MP_NLOG).astype(np.int32)
+    r0, c0 = np.argwhere(pt == 0)[0]
+    pt[r0, c0], pt[0, 0] = pt[0, 0], 0
+    lim = np.full(B, T)
+    slot = np.full(B, T - bs)
+    kw = {}
+    if case == "limit_mid_page_mid_block":
+        lim = np.asarray([100, 200, 37, 250])
+        slot = lim.copy()
+        # the last row's exclusion straddles the block 0 / block 1 edge
+        kw = dict(exclude_start=jnp.asarray([40, 120, 8, 120], jnp.int32),
+                  exclude_len=MP_PS)
+    elif case == "unmapped_block_edges":
+        pt[1, 8] = pt[1, 15] = -1      # first and last page of block 1
+        pt[2, 7] = pt[2, 16] = -1      # last of block 0, first of block 2
+        pt[3, 8:16] = -1               # a whole block unmapped: skipped
+        kw = dict(window=200)
+    elif case == "shared_prefix":
+        pt[1:, :12] = pt[0, :12]       # a document over 1.5 blocks
+        slot = np.asarray([T - bs, 292, 252, 300])
+        lim = slot + bs
+    elif case == "retired_and_sentinel":
+        lim = np.asarray([150, 0, 200, T])    # row 1 retired, still mapped
+        slot = np.asarray([150, 0, T, T - bs])  # row 2: sentinel slot
+    return pt, lim, slot, kw
+
+
+@pytest.mark.paged
+@pytest.mark.parametrize("case", [
+    "partial_last_block", "limit_mid_page_mid_block",
+    "unmapped_block_edges", "shared_prefix", "retired_and_sentinel"])
+def test_paged_kernel_multi_page_blocks(case):
+    """Compute blocks of several pages, fetched by hand: per-row limits
+    that end mid-page and mid-block, holes at a block's edges, a whole
+    unmapped block, shared pages, a retired row and the sentinel slot,
+    against the oracle. Every pool page that no row maps holds NaN, so a
+    page that is fetched or summed when it should not be shows; the V
+    rows a live block did not fetch must be zeroed (interpret mode fills
+    fresh VMEM with NaN). The counters read live mapped pages plus the
+    block tile, and one kv step per block with a live page plus one."""
+    pt, lim, slot, kw = _multi_page_case(case)
+    rng = np.random.default_rng(31)
+    B, bs, H, Kh, D, T = 4, MP_BS, 4, 2, 32, MP_T
+    num_pages = B * MP_NLOG + 4
+    pool_k = rng.standard_normal((num_pages, MP_PS, Kh, D))
+    pool_v = rng.standard_normal((num_pages, MP_PS, Kh, D))
+    unmapped = np.setdiff1d(np.arange(num_pages), pt[pt >= 0])
+    pool_k[unmapped] = np.nan
+    pool_v[unmapped] = np.nan
+    q, bk, bv = (jnp.asarray(rng.standard_normal(s), jnp.float32) for s in
+                 [(B, bs, H, D), (B, bs, Kh, D), (B, bs, Kh, D)])
+    args = (q, jnp.asarray(pool_k, jnp.float32),
+            jnp.asarray(pool_v, jnp.float32), bk, bv,
+            jnp.arange(T, dtype=jnp.int32), jnp.asarray(pt))
+    kw.update(slot=jnp.asarray(slot, jnp.int32),
+              block_start=jnp.asarray(slot, jnp.int32),
+              kv_limit=jnp.asarray(lim, jnp.int32))
+    got, tiles, steps = paged_block_attention_pallas(
+        *args, **kw, debug_tile_counts=True, interpret=True)
+    want = ref.paged_block_attention_ref(*args, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    ppb = BLOCK_KEYS // MP_PS
+    live = (pt >= 0) & (np.arange(MP_NLOG) * MP_PS < lim[:, None])
+    blocks = np.pad(live, ((0, 0), (0, -MP_NLOG % ppb))).reshape(B, -1, ppb)
+    np.testing.assert_array_equal(np.asarray(tiles)[:, 0],
+                                  live.sum(1) + 1)
+    np.testing.assert_array_equal(np.asarray(steps)[:, 0],
+                                  blocks.any(2).sum(1) + 1)
 
 
 @pytest.mark.paged

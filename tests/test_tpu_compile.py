@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.block_attention import (cached_block_attention_pallas,
+from repro.kernels.block_attention import (_pages_per_block,
+                                           cached_block_attention_pallas,
                                            paged_block_attention_pallas)
 from repro.kernels.confidence import fused_confidence_pallas
 from repro.kernels.fused_step import (fused_step_pallas,
@@ -92,6 +93,36 @@ def test_paged_block_attention_compiles(one_chip):
                    ((B, BS, KH, D), BF16), ((B, BS, KH, D), BF16),
                    ((T,), I32), ((B, T // PS), I32), ((B,), I32),
                    ((B,), I32), ((B,), I32))
+    assert _pallas_calls(hlo, "paged_block_attention")
+
+
+@pytest.mark.parametrize("rows,bs,heads,kv_heads,slots", [
+    (16, 32, 64, 8, 768),     # deepseek-67b decode: 256 q rows a kv head
+    (8, 32, 32, 32, 3264),    # llada-8b decode over the shared document
+    (8, 64, 32, 32, 3264),    # llada-8b shared-prefix admission, 64 rows
+], ids=["deepseek67b", "llada8b", "llada8b-admit"])
+def test_paged_block_attention_compiles_at_cell_shapes(
+        one_chip, rows, bs, heads, kv_heads, slots):
+    """The benchmark cells' paged geometries (16-token pages) compile with
+    compute blocks of 8 pages: the VMEM reckoning keeps 128 keys a block
+    and the compiler accepts what it asks for."""
+    n_log = slots // PS
+    pages = rows * n_log
+    qt = min(128, heads // kv_heads * bs)
+    assert _pages_per_block(PS, n_log, kv_heads, qt, D, bs, 2) == 8
+
+    def fn(q, pk, pv, bk, bv, pos, pt, slot, start, lim, exc):
+        return paged_block_attention_pallas(
+            q, pk, pv, bk, bv, pos, pt, slot=slot, block_start=start,
+            kv_limit=lim, exclude_start=exc, exclude_len=bs)
+
+    row = ((rows,), I32)
+    hlo = _compile(fn, one_chip, ((rows, bs, heads, D), BF16),
+                   ((pages, PS, kv_heads, D), BF16),
+                   ((pages, PS, kv_heads, D), BF16),
+                   ((rows, bs, kv_heads, D), BF16),
+                   ((rows, bs, kv_heads, D), BF16), ((slots,), I32),
+                   ((rows, n_log), I32), row, row, row, row)
     assert _pallas_calls(hlo, "paged_block_attention")
 
 
