@@ -730,6 +730,17 @@ def _admit_rows_prog(bucket: int, has_pages: bool, mark: bool):
     return jax.jit(prog)
 
 
+def bucket_rows(rows: Sequence[int], batch: int) -> np.ndarray:
+    """``rows`` padded to the power-of-two bucket ``1 << (n-1).bit_length()``
+    with the out-of-range index ``batch``: the row vector both admission
+    programs (``admit_carry_rows``'s scatter, ``make_admit_fn``'s
+    prefill) take, so each compiles once per bucket."""
+    n = len(rows)
+    r = np.full((1 << (n - 1).bit_length(),), batch, np.int32)
+    r[:n] = list(rows)
+    return r
+
+
 def admit_carry_rows(carry: DecodeCarry, rows: Sequence[int],
                      prompts: np.ndarray, tables: np.ndarray,
                      mask_id: int, *,
@@ -754,14 +765,10 @@ def admit_carry_rows(carry: DecodeCarry, rows: Sequence[int],
     needs no prefill forward at all."""
     if not len(rows):
         return carry
-    B = carry.live.shape[0]
-    rows = list(rows)
-    n = len(rows)
-    bucket = 1 << (n - 1).bit_length()
+    r = bucket_rows(rows, carry.live.shape[0])
+    n, bucket = len(rows), len(r)
     P = carry.prompt.shape[1]
     nb, sc = carry.table.shape[1], carry.table.shape[2]
-    r = np.full((bucket,), B, np.int32)  # B == out of range -> drop
-    r[:n] = rows
     pr = np.zeros((bucket, P), np.int32)
     pr[:n] = np.asarray(prompts, np.int32)
     tb = np.zeros((bucket, nb, sc), np.float32)
@@ -809,24 +816,30 @@ def make_admit_fn(cfg: ModelConfig, dcfg: DecodeConfig, *,
                   donate: Optional[bool] = None):
     """Build (or fetch) the compiled admission program.
 
-    fn(params, carry, admit [B] bool, prefix_len [B] i32 = None) -> carry
+    fn(params, carry, rows [k] i32, prefix_len [k] i32 = None) -> carry
 
-    ONE full-prompt forward prefills ``carry.prompt`` for every row and
-    merges the K/V of rows flagged in ``admit`` into the carried cache
-    (non-admitted rows keep their buffers bit-exactly: dense writes are
-    masked per row, paged writes go through an admit-masked page table
-    and drop). Costs one forward (+1 nfe) per call — the host batches
-    all of a slice boundary's admissions into one call, so an initial
-    full batch pays exactly the monolithic program's one prefill. The
-    cacheless mode has no admission program (nothing to prefill).
+    ``rows`` is the ``bucket_rows`` vector: the slots admitted at this
+    boundary, padded to a power-of-two ``k`` with the out-of-range index
+    ``B``. ONE forward prefills just those ``k`` rows of
+    ``carry.prompt`` (gathered; a pad entry prefills a dummy row that
+    maps no page) and merges their K/V into the carried cache: paged
+    writes go through the gathered page-table rows (pad rows are all
+    ``-1`` and drop), dense writes scatter back per row. Every other row
+    keeps its buffers bit-exactly. The program compiles once per bucket
+    (O(log B) programs, as ``admit_carry_rows``); with every row
+    admitted, ``k = B`` and the forward is the monolithic program's one
+    prefill. Costs one forward (+1 nfe) per call — the host batches all
+    of a slice boundary's admissions into one call. The cacheless mode
+    has no admission program (nothing to prefill).
 
     ``prefix_len`` (paged only, mutually exclusive with the static
-    ``shared_prefix_len``): per-row radix-cache hit lengths in tokens
-    (page-aligned, 0 = full miss). Hit positions read their K/V from the
-    row's already-mapped shared pages instead of the fresh projections,
-    and the write-back page table unmaps the hit pages so the shared
-    runs stay immutable. Passing a zero vector is bit-exact with
-    omitting the argument (the jit specializes on its presence).
+    ``shared_prefix_len``): per-entry radix-cache hit lengths in tokens,
+    aligned with ``rows`` (page-aligned, 0 = full miss). Hit positions
+    read their K/V from the row's already-mapped shared pages instead of
+    the fresh projections, and the write-back page table unmaps the hit
+    pages so the shared runs stay immutable. Passing a zero vector is
+    bit-exact with omitting the argument (the jit specializes on its
+    presence).
     """
     cache_mode, attn_impl, cache_layout, Sp, _, _ = _norm_slice_key(
         cfg, dcfg, True, cache_mode, attn_impl, cache_layout,
@@ -847,13 +860,17 @@ def _make_admit_fn(cfg: ModelConfig, dcfg: DecodeConfig, cache_mode: str,
     N, bs = dcfg.max_new_tokens, dcfg.block_size
     dual = cache_mode == "dual"
 
-    def admit(params, carry: DecodeCarry, admit_mask, prefix_len=None):
-        B, P = carry.prompt.shape
+    def admit(params, carry: DecodeCarry, rows, prefix_len=None):
+        P = carry.prompt.shape[1]
         max_len = P + N + (bs if dual else 0)
         kv = carry.cache["attn"]
-        admit_mask = jnp.asarray(admit_mask).astype(bool)
+        rows = jnp.asarray(rows, jnp.int32)
+        k = rows.shape[0]
+        prompt = jnp.take(carry.prompt, rows, axis=0, mode="fill",
+                          fill_value=0)
         if paged:
-            pt_admit = jnp.where(admit_mask[:, None], kv["pt"], -1)
+            pt = jnp.take(kv["pt"], rows, axis=0, mode="fill",
+                          fill_value=-1)
             if Sp:
                 assert prefix_len is None, \
                     "per-row prefix_len replaces the static shared prefix"
@@ -862,12 +879,12 @@ def _make_admit_fn(cfg: ModelConfig, dcfg: DecodeConfig, cache_mode: str,
                 # monolithic Sp prefill; write slot is explicit because
                 # the carried length tracks the batch-max extent, not Sp)
                 _, c1 = M.block_step(
-                    params, cfg, carry.prompt[:, Sp:],
+                    params, cfg, prompt[:, Sp:],
                     jnp.asarray(Sp, jnp.int32),
-                    {"attn": dict(kv, pt=pt_admit)}, write=True,
+                    {"attn": dict(kv, pt=pt)}, write=True,
                     advance=False, write_slot=jnp.asarray(Sp, jnp.int32),
                     attn_impl=attn_impl, page_size=ps,
-                    row_limit=jnp.full((B,), Sp, jnp.int32))
+                    row_limit=jnp.full((k,), Sp, jnp.int32))
                 kv1 = c1["attn"]
             elif prefix_len is not None:
                 # radix-hit admission: each row's first prefix_len[r]
@@ -883,17 +900,17 @@ def _make_admit_fn(cfg: ModelConfig, dcfg: DecodeConfig, cache_mode: str,
                 n_log = kv["pt"].shape[1]
                 drop = jnp.arange(n_log, dtype=jnp.int32)[None, :] \
                     < (pfx[:, None] // ps)
-                pt_write = jnp.where(drop, -1, pt_admit)
-                _, c1 = M.prefill(params, cfg, carry.prompt,
+                pt_write = jnp.where(drop, -1, pt)
+                _, c1 = M.prefill(params, cfg, prompt,
                                   max_len=max_len, mode="full",
-                                  cache={"attn": dict(kv, pt=pt_admit)},
+                                  cache={"attn": dict(kv, pt=pt)},
                                   page_size=ps, prefix_len=pfx,
                                   write_page_table=pt_write)
                 kv1 = c1["attn"]
             else:
-                _, c1 = M.prefill(params, cfg, carry.prompt,
+                _, c1 = M.prefill(params, cfg, prompt,
                                   max_len=max_len, mode="full",
-                                  cache={"attn": dict(kv, pt=pt_admit)},
+                                  cache={"attn": dict(kv, pt=pt)},
                                   page_size=ps)
                 kv1 = c1["attn"]
             new_kv = dict(kv, kp=kv1["kp"], vp=kv1["vp"],
@@ -903,19 +920,15 @@ def _make_admit_fn(cfg: ModelConfig, dcfg: DecodeConfig, cache_mode: str,
         else:
             assert prefix_len is None, \
                 "radix prefix hits require the paged layout"
-            _, fresh = M.prefill(params, cfg, carry.prompt,
+            _, fresh = M.prefill(params, cfg, prompt,
                                  max_len=max_len, mode="full")
             fkv = fresh["attn"]
-            sl = (jnp.arange(max_len, dtype=jnp.int32) < P)
-            pick = admit_mask[None, :, None, None, None] \
-                & sl[None, None, :, None, None]
-            new_kv = dict(kv,
-                          k=jnp.where(pick, fkv["k"].astype(kv["k"].dtype),
-                                      kv["k"]),
-                          v=jnp.where(pick, fkv["v"].astype(kv["v"].dtype),
-                                      kv["v"]),
-                          pos=jnp.maximum(kv["pos"], fkv["pos"]),
+            new_kv = dict(kv, pos=jnp.maximum(kv["pos"], fkv["pos"]),
                           length=jnp.maximum(kv["length"], fkv["length"]))
+            for name in ("k", "v"):
+                new_kv[name] = kv[name].at[:, rows, :P].set(
+                    fkv[name][:, :, :P].astype(kv[name].dtype),
+                    mode="drop")
         return carry._replace(cache=dict(carry.cache, attn=new_kv),
                               nfe=carry.nfe + 1)
 

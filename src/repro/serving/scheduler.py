@@ -72,7 +72,7 @@ import numpy as np
 
 from repro.config.base import DecodeConfig, EngineConfig, ModelConfig
 from repro.core.calibrate import CalibrationProfile
-from repro.core.decoder import (DecodeCarry, admit_carry_rows,
+from repro.core.decoder import (DecodeCarry, admit_carry_rows, bucket_rows,
                                 init_decode_carry, make_admit_fn,
                                 make_generate_fn, make_slice_fn,
                                 result_profile, retire_carry_rows)
@@ -240,6 +240,8 @@ _STATS_FIELDS: Dict[str, tuple] = {
     # admission + seeding + the one-time shared prefill; the radix
     # cache's headline reduction (a full-hit skips its forward outright)
     "prefill_nfe": (int, "prefill forwards"),
+    # rows the admission forwards computed, bucket pad rows included
+    "prefill_rows": (int, "admission prefill rows"),
 }
 
 
@@ -1338,20 +1340,21 @@ class Scheduler:
                     tr.instant("zero_prefill_admit", tid=0,
                                rows=len(admitted))
                 return
-        admit_mask = np.zeros((self.ecfg.batch_size,), bool)
-        admit_mask[[s.index for s in admitted]] = True
-        with tr.span("sched.admit_prefill", rows=len(admitted)):
+        rows = bucket_rows([s.index for s in admitted],
+                           self.ecfg.batch_size)
+        with tr.span("sched.admit_prefill", rows=len(admitted),
+                     bucket=len(rows)):
             if self.prefix_cache:
-                pfx = np.zeros((self.ecfg.batch_size,), np.int32)
-                for s in admitted:
-                    pfx[s.index] = s.prefix_len
+                pfx = np.zeros((len(rows),), np.int32)
+                pfx[:len(admitted)] = [s.prefix_len for s in admitted]
                 self._carry = self._admit_fn(self.params, self._carry,
-                                             jnp.asarray(admit_mask),
+                                             jnp.asarray(rows),
                                              jnp.asarray(pfx))
             else:
                 self._carry = self._admit_fn(self.params, self._carry,
-                                             jnp.asarray(admit_mask))
+                                             jnp.asarray(rows))
         self.stats.prefill_nfe += 1
+        self.stats.prefill_rows += len(rows)
 
     def _retire_sliced(self) -> List[Response]:
         """Emit responses for rows whose cursor ran out or that

@@ -204,7 +204,7 @@ def test_sliced_fused_bit_identity(small_model, prompts, mode, layout):
         page_rows=np.asarray(pool_kw["page_table"])
         if layout == "paged" else None)
     adm = make_admit_fn(cfg, DCFG, cache_mode=mode, cache_layout=layout)
-    carry = adm(params, carry, jnp.asarray([True, True]))
+    carry = adm(params, carry, jnp.asarray([0, 1]))
     sf = make_slice_fn(cfg, DCFG, slice_len=1, cache_mode=mode,
                        cache_layout=layout, step_fusion="fused")
     while int(np.asarray(carry.cursor).min()) < NB:

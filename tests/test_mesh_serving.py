@@ -272,7 +272,7 @@ _PRELUDE = textwrap.dedent("""
             page_rows=np.asarray(pk["page_table"])
             if layout == "paged" else None)
         adm = make_admit_fn(cfg, dcfg, cache_mode="prefix", **kw)
-        carry = adm(p or params, carry, jnp.asarray([True, True]))
+        carry = adm(p or params, carry, jnp.asarray([0, 1]))
         sf = make_slice_fn(cfg, dcfg, slice_len=slice_len,
                            cache_mode="prefix", **kw)
         mask = jnp.asarray(tok.MASK_ID, jnp.int32)

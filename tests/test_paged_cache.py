@@ -698,8 +698,8 @@ def test_failed_slice_exact_stats_backout(small_model):
     """The failed-slice requeue must back the admission ledger out
     EXACTLY: afterwards the stats equal the pre-submit snapshot except
     the fields the (real) admission prefill moved — ``nfe`` /
-    ``weight_bytes_streamed`` / ``prefill_nfe`` — and ``pages_peak``,
-    a high-water mark that is never unwound."""
+    ``weight_bytes_streamed`` / ``prefill_nfe`` / ``prefill_rows`` — and
+    ``pages_peak``, a high-water mark that is never unwound."""
     cfg, params = small_model
     ecfg = EngineConfig(batch_size=2, prompt_len=32, slice_len=1,
                         shared_prefix="SYSTEM: be terse. ")
@@ -713,7 +713,8 @@ def test_failed_slice_exact_stats_backout(small_model):
     with pytest.raises(RuntimeError):
         sch.slice_step()
     after = sch.stats.as_dict()
-    moved = {"pages_peak", "nfe", "weight_bytes_streamed", "prefill_nfe"}
+    moved = {"pages_peak", "nfe", "weight_bytes_streamed", "prefill_nfe",
+             "prefill_rows"}
     assert {k: v for k, v in after.items() if k not in moved} == \
         {k: v for k, v in before.items() if k not in moved}
     assert after["pages_peak"] >= before["pages_peak"]

@@ -231,7 +231,7 @@ def test_sliced_int8_matches_dequantized(small_model, prompts):
     carry = admit_carry_rows(carry, [0, 1], np.asarray(prompts),
                              np.asarray(table), 3)
     adm = make_admit_fn(cfg, DCFG)
-    carry = adm(qp, carry, jnp.asarray([True, True]))
+    carry = adm(qp, carry, jnp.asarray([0, 1]))
     sf = make_slice_fn(cfg, DCFG, slice_len=1, weight_dtype="int8")
     while int(np.asarray(carry.cursor).min()) < DCFG.num_blocks:
         carry = sf(qp, carry, mask, None, None)

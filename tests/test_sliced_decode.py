@@ -83,7 +83,7 @@ def _run_sliced(cfg, params, prompts, table, *, slice_len, mode, impl,
         if layout == "paged" else None)
     if mode != "none":
         adm = make_admit_fn(cfg, DCFG, **kw)
-        carry = adm(params, carry, jnp.asarray([True, True]))
+        carry = adm(params, carry, jnp.asarray([0, 1]))
     sf = make_slice_fn(cfg, DCFG, slice_len=slice_len,
                        variant="draft" if spec else "step", **kw)
     mask = jnp.asarray(tok.MASK_ID, jnp.int32)
@@ -180,7 +180,7 @@ def test_sliced_kernel_impl_dispatches_pallas(small_model, prompts,
                                   cache_mode="prefix")
         carry = admit_carry_rows(carry, [0, 1], prompts, table, tok.MASK_ID)
         adm = make_admit_fn(cfg, dcfg, cache_mode="prefix")
-        carry = adm(params, carry, jnp.asarray([True, True]))
+        carry = adm(params, carry, jnp.asarray([0, 1]))
         with monkeypatch.context() as mp:
             if patched:
                 real = ops.cached_block_attention_pallas
@@ -487,9 +487,132 @@ def test_cpu_donation_fallback(small_model, prompts):
                               mask_id=tok.MASK_ID)
     carry = admit_carry_rows(carry, [0, 1], prompts, table, tok.MASK_ID)
     adm = make_admit_fn(cfg, DCFG)
-    carry = adm(params, carry, jnp.asarray([True, True]))
+    carry = adm(params, carry, jnp.asarray([0, 1]))
     sf = make_slice_fn(cfg, DCFG, slice_len=1)
     out = sf(params, carry, jnp.asarray(tok.MASK_ID, jnp.int32), None,
              None)
     assert not carry.resp.is_deleted()       # no donation on CPU
     assert not out.resp.is_deleted()
+
+
+# ---------------------------------------------------------------------------
+# row-bucketed admission: the prefill runs over the admitted rows only
+# ---------------------------------------------------------------------------
+
+ADMIT_B = 4
+
+
+def _admit_carry(cfg, branch):
+    """A B=4 carry with every row's prompt and page row in place and the
+    cache filled with noise, so a write that should not happen shows."""
+    Sp = 8 if branch == "shared" else 0
+    layout = "dense" if branch == "dense" else "paged"
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(1, 256, (ADMIT_B, PROMPT_LEN)).astype(np.int32)
+    table = np.full((ADMIT_B, NB, DCFG.steps_cap), 0.9, np.float32)
+    pool_kw, page_rows = {}, None
+    if layout == "paged":
+        max_len = PROMPT_LEN + DCFG.max_new_tokens
+        n_log = DCFG.pages_per_seq(max_len)
+        pt = np.array(identity_page_table(ADMIT_B, max_len,
+                                           DCFG.page_size))
+        pt[:, :Sp // DCFG.page_size] = pt[0, :Sp // DCFG.page_size]
+        shape = (cfg.num_layers, ADMIT_B * n_log, DCFG.page_size,
+                 cfg.num_kv_heads, cfg.resolved_head_dim)
+        pool_kw = dict(pool_k=jnp.asarray(rng.standard_normal(shape),
+                                          M.param_dtype(cfg)),
+                       pool_v=jnp.asarray(rng.standard_normal(shape),
+                                          M.param_dtype(cfg)),
+                       page_table=jnp.asarray(pt))
+        page_rows = pt
+    carry = init_decode_carry(cfg, DCFG, batch=ADMIT_B,
+                              prompt_len=PROMPT_LEN, mask_id=tok.MASK_ID,
+                              cache_layout=layout, shared_prefix_len=Sp,
+                              **pool_kw)
+    if layout == "dense":
+        kv = dict(carry.cache["attn"])
+        for name in ("k", "v"):
+            kv[name] = jnp.asarray(rng.standard_normal(kv[name].shape),
+                                   kv[name].dtype)
+        carry = carry._replace(cache={"attn": kv})
+    carry = admit_carry_rows(carry, list(range(ADMIT_B)), prompts, table,
+                             tok.MASK_ID, page_rows=page_rows)
+    adm = make_admit_fn(cfg, DCFG, cache_layout=layout, shared_prefix_len=Sp)
+    return carry, adm
+
+
+@pytest.mark.parametrize("branch", ["paged", "shared", "radix", "dense"])
+def test_bucketed_admission_matches_whole_batch(small_model, branch):
+    """Admitting rows {1} then {0, 2} of a B=4 carry writes what the
+    whole-batch admission writes for those rows; row 3's cache stays
+    bit-exact, and a bucket of pad entries (index B) writes nothing at
+    all. The shared-prefix branch is bitwise on CPU. The full-prompt
+    prefills are not: XLA's CPU matmuls order their sums by the batch's
+    row count, so a 1- or 2-row prefill differs from the 4-row one by
+    ~2e-6 (a 4-row bucket with a pad entry is bitwise); those branches
+    hold to the paged-cache tests' tolerance."""
+    cfg, params = small_model
+    # radix: row 1 hits two pages, row 2 one, rows 0 and 3 miss
+    pfx = np.asarray([0, 8, 4, 0], np.int32) if branch == "radix" else None
+
+    def call(adm, carry, rows):
+        rows = np.asarray(rows, np.int32)
+        if pfx is None:
+            return adm(params, carry, jnp.asarray(rows))
+        return adm(params, carry, jnp.asarray(rows),
+                   jnp.asarray(np.where(rows < ADMIT_B,
+                                        pfx[np.minimum(rows, ADMIT_B - 1)],
+                                        0)))
+
+    init, adm = _admit_carry(cfg, branch)
+    whole = call(adm, init, range(ADMIT_B))
+    part, _ = _admit_carry(cfg, branch)
+    part = call(adm, part, [1])
+    part = call(adm, part, [0, 2])
+    kv0, kvw, kvp = (c.cache["attn"] for c in (init, whole, part))
+    if branch == "dense":
+        names, untouched = ("k", "v"), (slice(None), 3)
+    else:
+        names = ("kp", "vp")
+        untouched = (slice(None), np.asarray(kv0["pt"])[3])
+    for name in names:
+        got = np.asarray(kvp[name])
+        want = np.asarray(kvw[name]).copy()
+        want[untouched] = np.asarray(kv0[name])[untouched]
+        np.testing.assert_array_equal(got[untouched], want[untouched])
+        if branch == "shared":
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for name in ("pos", "length"):
+        np.testing.assert_array_equal(np.asarray(kvp[name]),
+                                      np.asarray(kvw[name]))
+    assert int(part.nfe) == int(init.nfe) + 2
+
+    pads = call(adm, part, [ADMIT_B, ADMIT_B])
+    for name, a in pads.cache["attn"].items():
+        np.testing.assert_array_equal(np.asarray(a),
+                                      np.asarray(part.cache["attn"][name]),
+                                      err_msg=name)
+
+
+def test_admission_prefill_rows_counter(small_model, calibrated_store):
+    """``prefill_rows`` counts the rows the admission forward computed:
+    one request admitted mid-generation into a B=4 batch adds 1, not 4;
+    a burst of 3 adds 4 (its power-of-two bucket)."""
+    cfg, params = small_model
+    s = _sched(cfg, params, calibrated_store, batch_size=4,
+               dcfg_kw=dict(cache_layout="paged"))
+    s.submit(_requests("alpha", 1))
+    s.slice_step()
+    assert s.stats.prefill_rows == 1
+    s.submit(_requests("beta", 1, 10))   # arrives mid-generation
+    s.slice_step()
+    assert s.stats.mid_admits == 1
+    assert s.stats.prefill_rows == 2
+    _drain(s)
+    s.submit(_requests("alpha", 3, 20))  # a burst of three
+    s.slice_step()
+    assert s.stats.prefill_rows == 6
+    assert s.stats.requests == 5
+    _drain(s)

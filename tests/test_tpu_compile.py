@@ -100,7 +100,8 @@ def test_paged_block_attention_compiles(one_chip):
     (16, 32, 64, 8, 768),     # deepseek-67b decode: 256 q rows a kv head
     (8, 32, 32, 32, 3264),    # llada-8b decode over the shared document
     (8, 64, 32, 32, 3264),    # llada-8b shared-prefix admission, 64 rows
-], ids=["deepseek67b", "llada8b", "llada8b-admit"])
+    (1, 64, 32, 32, 3264),    # the same admission of one row (its bucket)
+], ids=["deepseek67b", "llada8b", "llada8b-admit", "llada8b-admit1"])
 def test_paged_block_attention_compiles_at_cell_shapes(
         one_chip, rows, bs, heads, kv_heads, slots):
     """The benchmark cells' paged geometries (16-token pages) compile with
