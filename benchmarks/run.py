@@ -15,11 +15,9 @@ import sys
 from pathlib import Path
 from typing import List
 
-from benchmarks import (async_admission, block_attn, cache_modes,
-                        fig1_confidence, fig2_cosine, fig3_5_sweep,
-                        fused_step, kernels_bench, observability, paged_kv,
-                        prefix_cache, quantized_decode, scheduler_bench,
-                        sharded_serving, spec_decode, table1_compare)
+from benchmarks import (block_attn, cache_modes, fig1_confidence,
+                        fig2_cosine, fig3_5_sweep, fused_step, kernels_bench,
+                        quantized_decode, table1_compare)
 from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
@@ -31,14 +29,7 @@ BENCHES = {
     "kernels": kernels_bench.run,
     "block_attn": block_attn.run,
     "fused_step": fused_step.run,
-    "scheduler": scheduler_bench.run,
-    "paged_kv": paged_kv.run,
-    "spec_decode": spec_decode.run,
-    "async_admission": async_admission.run,
-    "prefix_cache": prefix_cache.run,
     "quant": quantized_decode.run,
-    "obs": observability.run,
-    "mesh": sharded_serving.run,
 }
 
 

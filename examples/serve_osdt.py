@@ -1,24 +1,24 @@
 """Continuous-batching serving with per-slot OSDT tables (SERVING.md).
 
-    PYTHONPATH=src:. python examples/serve_osdt.py [--paged] [--spec] [--sliced] [--prefix]
+    PYTHONPATH=src:. python examples/serve_osdt.py [--paged] [--spec] [--prefix]
 
 Simulates a mixed request stream across three tasks. The engine keeps ONE
-calibration store and ONE compiled decode program; every task calibrates
-on its first admitted request (all rows record profiles, so several new
-tasks calibrate inside one mixed batch) and every batch mixes tasks
-freely: the per-slot threshold table is gathered at runtime. Rows retire
-at EOS, so short answers stop costing denoising steps. With ``--paged``
-the KV cache is a page pool: a shared system prompt is prefilled once
-into refcounted pages, dead slots pin zero pages, and retirement reclaims
-pages for the next batch. With ``--spec`` the engine decodes through the
-draft-and-verify program: blocks a task's calibrated signature predicts
-easy are one-shot drafted and, when verification accepts them, skip
-their denoising steps. Prints per-task accuracy + throughput accounting,
-the per-request queue/decode split, page occupancy, and draft acceptance. With ``--sliced`` the engine decodes through the
-step-sliced loop (one block per compiled slice): requests admit into
-freed slots mid-generation and the per-request ``ttfb_s`` / queue waits
-are measured at slice boundaries (SERVING.md "Async admission"). With
-``--prefix`` (implies --paged --sliced) each task's requests carry a
+calibration store and ONE compiled slice program (one block per slice);
+every task calibrates on its first admitted request (all rows record
+profiles, so several new tasks calibrate at once) and every batch mixes
+tasks freely: the per-slot threshold table is gathered at runtime.
+Requests admit into freed slots mid-generation, and rows retire at EOS,
+so short answers stop costing denoising steps; the per-request
+``ttfb_s`` / queue waits are measured at slice boundaries (SERVING.md
+"Async admission"). With ``--paged`` the KV cache is a page pool: a
+shared system prompt is prefilled once into refcounted pages, free slots
+pin zero pages, and retirement reclaims pages at once. With ``--spec``
+the engine decodes through the draft-and-verify program: blocks a task's
+calibrated signature predicts easy are one-shot drafted and, when
+verification accepts them, skip their denoising steps. Prints per-task
+accuracy + throughput accounting, the per-request queue/decode split,
+page occupancy, and draft acceptance. With ``--prefix`` (implies
+--paged) each task's requests carry a
 per-tenant system prompt in ``Request.prefix`` and the engine runs the
 radix-tree prefix cache: repeat tenants reuse the tree's prefix pages
 and prefill only their novel remainder (SERVING.md "Radix prefix
@@ -38,7 +38,6 @@ def main() -> None:
     prefix = "--prefix" in sys.argv
     paged = "--paged" in sys.argv or prefix
     spec = "--spec" in sys.argv
-    sliced = "--sliced" in sys.argv or prefix
     cfg, params = common.get_model()
     dcfg = DecodeConfig(max_new_tokens=32, block_size=8, policy="osdt",
                         mode="block", metric="q1", cap=0.8, slack=0.15,
@@ -49,8 +48,7 @@ def main() -> None:
                         eos_early_exit=True,
                         shared_prefix="answer briefly. "
                         if paged and not prefix else "",
-                        spec_decode=spec, slice_len=1 if sliced else 0,
-                        prefix_cache=prefix)
+                        spec_decode=spec, prefix_cache=prefix)
     engine = DiffusionEngine(params, cfg, dcfg, ecfg=ecfg)
 
     rng = np.random.default_rng(3)
@@ -81,8 +79,8 @@ def main() -> None:
     q = [r.queue_s for r in responses]
     d = [r.decode_s for r in responses]
     steps = [r.nfe for r in responses]
-    print(f"TOTAL: {st.requests} reqs / {st.batches} batches "
-          f"({st.dead_slots} dead slots)  {st.tokens} tokens delivered "
+    print(f"TOTAL: {st.requests} reqs / {st.slices} slices "
+          f"({st.mid_admits} mid-gen admits)  {st.tokens} tokens delivered "
           f"(+{st.tokens_dropped} truncated)  NFE={st.nfe}  "
           f"tokens/NFE={st.tokens_per_nfe:.2f}  tokens/s={st.tokens_per_s:.1f}")
     print(f"per-request: queue {np.mean(q)*1e3:.1f}ms avg / "
@@ -95,19 +93,17 @@ def main() -> None:
     if st.blocks_drafted:
         print(f"drafting: {st.blocks_drafted} drafted "
               f"{st.blocks_accepted} accepted "
-              f"({st.draft_accept_rate:.0%}) over {st.draft_batches} "
-              f"batches, ~{st.nfe_saved} forwards saved")
+              f"({st.draft_accept_rate:.0%}) planned at "
+              f"{st.draft_batches} boundaries")
     if st.prefix_hits or st.prefix_misses:
         print(f"prefix cache: {st.prefix_hits} hits {st.prefix_misses} "
               f"misses ({st.prefix_hit_rate:.0%}), "
               f"{st.prefill_tokens_saved} prompt tokens saved, "
               f"{st.prefix_inserts} inserts {st.prefix_evictions} "
               f"evictions, prefill NFE={st.prefill_nfe}")
-    if st.slices:
-        ttfb = [r.ttfb_s for r in responses]
-        print(f"sliced: {st.slices} slices, {st.mid_admits} mid-gen "
-              f"admits, ttfb {np.mean(ttfb)*1e3:.1f}ms avg / "
-              f"{np.max(ttfb)*1e3:.1f}ms max")
+    ttfb = [r.ttfb_s for r in responses]
+    print(f"ttfb {np.mean(ttfb)*1e3:.1f}ms avg / "
+          f"{np.max(ttfb)*1e3:.1f}ms max")
 
 
 if __name__ == "__main__":

@@ -230,7 +230,8 @@ class DecodeConfig:
     #   bf16 — weights stream in their stored dtype (the bit-identity
     #          oracle; the name covers f32-stored params too)
     #   int8 — the decode program expects params quantized ONCE at load
-    #          by models.quantize.quantize_decode_params: QKV/O, MLP and
+    #          by models.quantize.quantize_decode_params (the serving
+    #          scheduler does so at construction): QKV/O, MLP and
     #          lm-head tiles stream as symmetric per-output-channel int8
     #          and dequantize in-register before each contraction (half
     #          the weight HBM bytes of bf16; NOT bit-identical — the
@@ -255,20 +256,17 @@ class DecodeConfig:
 class EngineConfig:
     """Serving-engine / scheduler knobs (SERVING.md).
 
-    The scheduler decodes fixed-shape ``[batch_size, prompt_len]`` batches
-    through ONE compiled program; everything per-request (threshold table,
-    liveness, EOS exit) is a runtime argument.
+    The scheduler keeps a fixed-shape ``[batch_size, prompt_len]`` decode
+    carry and advances it one compiled slice at a time; between slices
+    it retires finished rows and admits queued requests into the freed
+    slots. Everything per-request (threshold table, liveness, block
+    cursor, EOS exit) is carry state, not program shape. The attention
+    path and the weight dtype are ``DecodeConfig``'s.
     """
 
     batch_size: int = 4
     prompt_len: int = 64
     cache_mode: str = "prefix"    # prefix | dual | none (decoder variants)
-    attn_impl: str = ""           # "" -> DecodeConfig.attn_impl
-    # "" -> DecodeConfig.weight_dtype; "int8" makes the scheduler run
-    # models.quantize.quantize_decode_params ONCE at construction and
-    # serve every decode/prefill forward from the int8 tiles
-    # (EngineStats.weight_bytes_streamed tracks the streamed footprint)
-    weight_dtype: str = ""
     # retire rows at the first completed block containing EOS; dead slots
     # and retired rows stop forcing denoising steps
     eos_early_exit: bool = True
@@ -292,21 +290,19 @@ class EngineConfig:
     # bit-identical to a spec_decode-free engine.
     spec_decode: bool = False
     draft_max_steps: int = 1
-    # step-sliced decode loop (SERVING.md "Async admission"): 0 keeps the
-    # monolithic one-program-per-batch runtime (admission at batch
-    # boundaries only); N >= 1 decodes N blocks per compiled slice and
-    # returns to the host between slices, where EOS rows retire (pages
-    # reclaimed immediately) and queued requests are admitted into freed
-    # slots MID-GENERATION with their own block cursor, threshold table,
-    # and (spec_decode) re-planned draft mask.
-    slice_len: int = 0
+    # blocks decoded per compiled slice (SERVING.md "Async admission"),
+    # >= 1: the scheduler returns to the host after every slice, where
+    # EOS rows retire (pages reclaimed immediately) and queued requests
+    # are admitted into freed slots MID-GENERATION with their own block
+    # cursor, threshold table, and (spec_decode) draft mask
+    slice_len: int = 1
     # radix-tree prefix cache (SERVING.md "Radix prefix cache"): page-
     # aligned multi-tenant prefix reuse. Admission walks a radix tree of
     # immutable prefix pages for the longest match on the row's
     # ``shared_prefix + Request.prefix`` stream, share()s the matched
     # pages and prefills only the novel remainder; retirement promotes
     # the row's now-immutable prompt pages back into the tree. Requires
-    # the paged layout and the step-sliced loop (slice_len >= 1).
+    # the paged layout.
     # ``shared_prefix`` stops being a statically prefilled run and
     # becomes the pre-seeded first tree node instead.
     prefix_cache: bool = False
@@ -324,10 +320,10 @@ class EngineConfig:
     # pool must divide evenly); model_parallel runs tensor-parallel
     # decode through repro.sharding.rules' "serve" specs (dims that
     # don't divide the axis replicate — the divisibility fallback).
-    # 1/1 keeps the single-device runtime bit-exactly. Sharded serving
-    # runs the step-sliced loop (slice_len >= 1): slice boundaries are
-    # the host-side exchange points, and only int32 metadata (retired
-    # slots, freed/shared page ids, calibration ingests) crosses them.
+    # 1/1 keeps the single-device runtime bit-exactly. Slice boundaries
+    # are the host-side exchange points, and only int32 metadata
+    # (retired slots, freed/shared page ids, calibration ingests)
+    # crosses them.
     data_parallel: int = 1
     model_parallel: int = 1
     # observability (SERVING.md "Observability") — all off by default;

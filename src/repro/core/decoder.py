@@ -122,8 +122,8 @@ def make_generate_fn(cfg: ModelConfig, dcfg: DecodeConfig, *,
     broadcast over the batch at trace time. Either way it stays a runtime
     argument: one compiled program serves every policy and task mix.
 
-    ``live`` marks rows that should decode. Dead rows (scheduler pad
-    slots) never trigger the argmax fallback, never keep the step loop
+    ``live`` marks rows that should decode. Dead rows (pad rows of a
+    batch) never trigger the argmax fallback, never keep the step loop
     alive, and have their masks flushed in one ride-along step — an
     all-dead block costs zero forwards. ``eos_id`` (pass ``None`` to
     disable) retires a row once a *completed* block of its response

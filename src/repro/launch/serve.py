@@ -94,18 +94,17 @@ def main() -> None:
     ap.add_argument("--draft-max-steps", type=int, default=1,
                     help="draft blocks predicted to clear in <= this many "
                          "steps (spec decode)")
-    ap.add_argument("--slice-len", type=int, default=0,
-                    help="step-sliced decode loop: decode N blocks per "
-                         "compiled slice and admit queued requests into "
-                         "freed slots MID-generation (0 = monolithic "
-                         "batch-boundary admission, SERVING.md 'Async "
+    ap.add_argument("--slice-len", type=int, default=1,
+                    help="blocks decoded per compiled slice; queued "
+                         "requests admit into freed slots at every slice "
+                         "boundary, MID-generation (SERVING.md 'Async "
                          "admission')")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="radix-tree prefix cache: reuse page-aligned "
                          "prompt-prefix KV across requests and tenants, "
                          "prefilling only each row's novel remainder "
-                         "(needs --cache-layout paged and --slice-len "
-                         ">= 1; SERVING.md 'Radix prefix cache')")
+                         "(needs --cache-layout paged; SERVING.md 'Radix "
+                         "prefix cache')")
     ap.add_argument("--prefix-cache-pages", type=int, default=0,
                     help="page budget the tree may pin (0 = bounded by "
                          "the pool; LRU-evicted under pressure)")
@@ -115,8 +114,8 @@ def main() -> None:
     ap.add_argument("--data-parallel", type=int, default=1,
                     help="shard the slot pool (and paged page pool) over "
                          "this many devices on the mesh's 'data' axis — "
-                         "needs --slice-len >= 1 and batch divisible "
-                         "(SERVING.md 'Sharded serving')")
+                         "needs batch divisible (SERVING.md 'Sharded "
+                         "serving')")
     ap.add_argument("--model-parallel", type=int, default=1,
                     help="tensor-parallel decode over the mesh's 'model' "
                          "axis via the 'serve' weight specs (dims that "
@@ -193,8 +192,8 @@ def main() -> None:
     if st.blocks_drafted:
         print(f"# drafting: {st.blocks_drafted} drafted "
               f"{st.blocks_accepted} accepted "
-              f"({st.draft_accept_rate:.0%}) over {st.draft_batches} "
-              f"batches, ~{st.nfe_saved} forwards saved")
+              f"({st.draft_accept_rate:.0%}) planned at "
+              f"{st.draft_batches} boundaries")
     if st.prefix_hits or st.prefix_misses or st.prefix_inserts:
         print(f"# prefix cache: {st.prefix_hits} hits "
               f"{st.prefix_misses} misses "
@@ -203,13 +202,11 @@ def main() -> None:
               f"({st.prefill_tokens_saved} prompt tokens), "
               f"{st.prefix_inserts} inserts {st.prefix_evictions} "
               f"evictions, prefill NFE={st.prefill_nfe}")
-    if st.slices:
-        q = [r.queue_s for r in out]
-        ttfb = [r.ttfb_s for r in out]
-        print(f"# sliced: {st.slices} slices, {st.mid_admits} "
-              f"mid-generation admits, queue p95 "
-              f"{np.percentile(q, 95) * 1e3:.1f}ms, ttfb p95 "
-              f"{np.percentile(ttfb, 95) * 1e3:.1f}ms")
+    q = [r.queue_s for r in out]
+    ttfb = [r.ttfb_s for r in out]
+    print(f"# {st.slices} slices, {st.mid_admits} mid-generation admits, "
+          f"queue p95 {np.percentile(q, 95) * 1e3:.1f}ms, ttfb p95 "
+          f"{np.percentile(ttfb, 95) * 1e3:.1f}ms")
     obs = engine.obs
     if args.drift and obs.drift is not None:
         for task, row in sorted(obs.drift.snapshot().items()):

@@ -1,8 +1,9 @@
 """Live confidence-drift telemetry (the OSDT staleness question).
 
 OSDT calibrates once per task and then trusts the stored signature
-forever — but the τ-sweep in ``experiments/bench_results.csv`` (draft
-acceptance 0.81 → 0.00) shows what a stale signature costs. The
+forever — but a signature that no longer matches the traffic drafts
+blocks verification then rejects (draft acceptance fell 0.81 → 0.00
+over a τ-scale sweep on the CPU bench model). The
 ``DriftMonitor`` closes the measurement gap: every retired row's
 recorded confidence trajectory (``result_profile`` — the SAME recording
 calibration uses) is compared against the task's stored

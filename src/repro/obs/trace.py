@@ -28,11 +28,11 @@ schema that Perfetto / ``chrome://tracing`` load directly):
     lifecycle phases that overlap arbitrarily: ``request`` (submit →
     response) and ``queued`` (submit/requeue → admit).
   * **instants** (``ph`` i) — point events: evictions, promotions,
-    calibration ingests, failures.
+    failures.
   * **counters** (``ph`` C) — time series (pages in use, rows live).
 
 ``validate_trace`` checks structural integrity (schema + balanced span
-trees) and is shared by the tests and the observability benchmark.
+trees); the tests check every exported trace with it.
 """
 from __future__ import annotations
 

@@ -8,25 +8,25 @@ table — the paper's observation O2 says the confidence signature is a
 are the normal case: each slot's table is gathered per row at runtime.
 
 ``submit()`` is the synchronous compatibility surface: enqueue, drain, and
-return responses in uid order. Callers that want batch-granularity control
-(admit/step/retire, per-batch stats) should drive the scheduler directly.
+return responses in uid order. Callers that want boundary-granularity
+control (``Scheduler.slice_step``: admit, one slice, retire) should drive
+the scheduler directly.
 
 Throughput accounting (``EngineStats``): NFE (model forwards — the
 hardware-independent driver), *delivered* tokens (post-EOS truncation; a
 request that stops early is not credited ``max_new_tokens``), and
-per-request wall = its own queue wait + the decode wall it was actually
-decoded in. Under the paged KV layout (``DecodeConfig.cache_layout=
+per-request wall = its own queue wait + the walls of the slices it was
+actually decoded in. Under the paged KV layout (``DecodeConfig.cache_layout=
 "paged"``, SERVING.md "Paged KV") the stats additionally surface
 page-pool occupancy: ``page_capacity``, ``pages_peak`` / ``page_util``,
 ``pages_shared``, ``pages_freed``.
 
-With ``EngineConfig.slice_len >= 1`` the scheduler runs the STEP-SLICED
-decode loop (SERVING.md "Async admission"): requests admit into freed
-slots mid-generation, EOS retirement reclaims pages at slice
-boundaries, and the latency split is slice-granular — ``Response.
-ttfb_s`` (submit → first decoded block) plus ``queue_s``/``decode_s``
-measured at the boundaries the row actually crossed, instead of
-charging every member the whole batch's wall.
+The scheduler runs the STEP-SLICED decode loop (SERVING.md "Async
+admission"): requests admit into freed slots mid-generation, EOS
+retirement reclaims pages at slice boundaries, and the latency split is
+slice-granular — ``Response.ttfb_s`` (submit → first decoded block) plus
+``queue_s``/``decode_s`` measured at the boundaries the row actually
+crossed.
 
 With ``EngineConfig.data_parallel`` / ``model_parallel`` > 1 the
 scheduler runs SPMD over a ``("data", "model")`` device mesh
@@ -51,28 +51,15 @@ __all__ = ["DiffusionEngine", "EngineStats", "Request", "Response",
 
 class DiffusionEngine:
     def __init__(self, params, cfg: ModelConfig, dcfg: DecodeConfig, *,
-                 batch_size: int = 4, prompt_len: int = 64,
-                 use_cache: bool = True, mask_id: int = tok.MASK_ID,
-                 eos_id: int = tok.EOS_ID, attn_impl: str = "",
                  ecfg: Optional[EngineConfig] = None,
-                 store: Optional[CalibrationStore] = None):
-        """``ecfg`` carries the scheduler knobs (cache mode, EOS early
-        exit, calibration persistence — see ``EngineConfig``); when absent
-        one is assembled from the legacy keyword args (batch_size /
-        prompt_len / use_cache / attn_impl), which must stay at their
-        defaults when ``ecfg`` is given — mixing the two would silently
-        drop the legacy values. ``attn_impl`` forces the block-step
-        attention path (auto | dense | flash | kernel — KERNELS.md); pass
-        "kernel" when serving on TPU."""
-        if ecfg is None:
-            ecfg = EngineConfig(batch_size=batch_size,
-                                prompt_len=prompt_len,
-                                cache_mode="prefix" if use_cache else "none",
-                                attn_impl=attn_impl)
-        else:
-            assert (batch_size, prompt_len, use_cache, attn_impl) == \
-                (4, 64, True, ""), \
-                "pass serving knobs via EngineConfig when ecfg is given"
+                 store: Optional[CalibrationStore] = None,
+                 mask_id: int = tok.MASK_ID, eos_id: int = tok.EOS_ID):
+        """``dcfg`` carries the decode program's choices (attention path,
+        cache layout, weight dtype — pass ``attn_impl="kernel"`` when
+        serving on TPU, KERNELS.md); ``ecfg`` the scheduler knobs (batch
+        geometry, cache mode, EOS early exit, calibration persistence —
+        see ``EngineConfig``; defaults when absent)."""
+        ecfg = ecfg if ecfg is not None else EngineConfig()
         self.params = params
         self.cfg = cfg
         self.dcfg = dcfg

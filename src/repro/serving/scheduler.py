@@ -1,27 +1,32 @@
 """Slot-based continuous-batching scheduler for diffusion decoding.
 
 The decode program is compiled ONCE per engine (fixed ``[batch_size,
-prompt_len]`` shapes); everything that varies per request rides as runtime
-arguments — the per-slot threshold table ``[B, nb, steps_cap]`` gathered
-from the :class:`~repro.core.osdt.CalibrationStore`, the per-slot ``live``
-mask, and the EOS id. That is what lets a *mixed-task* request stream share
-one executable: OSDT's table is a task-level artifact, and here every row
-of a batch may belong to a different task.
+prompt_len]`` carry shapes); everything that varies per request rides as
+runtime state in the decode carry — the per-slot threshold table
+``[B, nb, steps_cap]`` gathered from the
+:class:`~repro.core.osdt.CalibrationStore`, the per-slot ``live`` mask,
+the per-slot block cursor, and the EOS id. That is what lets a
+*mixed-task* request stream share one executable: OSDT's table is a
+task-level artifact, and here every row of a batch may belong to a
+different task.
 
-Lifecycle (SERVING.md):
+Lifecycle (SERVING.md), one ``slice_step()`` per slice boundary:
 
-  QUEUED --admit--> ACTIVE --decode--> RETIRED (response emitted)
-                 \\-> slots with no request are admitted DEAD: mask-only
-                     prompt rows with ``live=False`` that cost ~zero
-                     denoising steps (the decoder's step loop and
-                     commit/refresh forwards are live-row-aware).
+  QUEUED --admit--> ACTIVE --slice x N--> RETIRED (response emitted)
 
-Batch filling no longer pins calibration to slot 0: the decoder records
-the confidence profile of EVERY live row, so each *uncalibrated* task's
-first admitted request — whatever slot it lands in — becomes that task's
-one-shot calibration (paper Algorithm 1), and several new tasks calibrate
-inside one mixed batch. Extra requests of a not-yet-calibrated task ride
-along on the static table.
+At each boundary queued requests are admitted into FREE slots — also
+while other rows are mid-generation, each with its own block cursor —
+and prefilled by one batched admission program over the admitted rows;
+then ONE compiled slice program decodes ``slice_len`` blocks of every
+live row; rows whose cursor ran out or that EOS-retired are retired and
+their pages reclaimed immediately. Free slots ride along with
+``live=False`` and cost no denoising steps.
+
+Each uncalibrated task's first admitted request — whatever slot it lands
+in — becomes that task's one-shot calibration (paper Algorithm 1): its
+row's confidence recording is ingested at retirement, and several new
+tasks may calibrate at once. Extra requests of a not-yet-calibrated task
+ride along on the static table.
 
 With ``DecodeConfig.cache_layout == "paged"`` the scheduler is the PAGE
 OWNER (SERVING.md "Paged KV"): it holds the device page pool and a host
@@ -30,7 +35,7 @@ request off the shared system-prompt parent (``PageAllocator.fork``:
 refcount-map the shared pages read-only, allocate private pages only for
 the logical range the row writes), retirement releases the fork, and a
 request is admissible as soon as enough *pages* — not a whole dense
-slot — are free. Dead slots map no pages at all.
+slot — are free. Free slots map no pages at all.
 ``EngineConfig.shared_prefix`` is prefilled ONCE into refcounted pages at
 engine construction; every slot's page table then maps those pages
 read-only (copy-on-write boundaries are page-aligned, so decode writes
@@ -48,14 +53,12 @@ LRU over tree-only nodes evicts under page pressure BEFORE admission
 load-shedding.
 
 With ``EngineConfig.spec_decode`` the scheduler also owns the DRAFT
-lifecycle (SERVING.md "Speculative drafting"): the decode program is the
+lifecycle (SERVING.md "Speculative drafting"): the slice program is the
 ``variant="draft"`` executable, a :class:`~repro.spec.drafter.Drafter`
-turns each calibrated task's stored profile into the per-row
-``draft_mask`` runtime argument (admission gates on pages exactly as
-before — a draft fork is only admitted when its pages are available),
-accepted blocks' pages merge back into the row's committed KV for the
-rest of the batch, rejected blocks decode through the stepped loop, and
-``EngineStats`` gains the acceptance-rate / NFE-saved counters.
+turns each calibrated task's stored profile into the ``draft_mask`` of
+the rows admitted at a boundary, accepted blocks' pages merge back into
+the row's committed KV, rejected blocks decode through the stepped loop,
+and ``EngineStats`` gains the acceptance counters.
 """
 from __future__ import annotations
 
@@ -74,8 +77,7 @@ from repro.config.base import DecodeConfig, EngineConfig, ModelConfig
 from repro.core.calibrate import CalibrationProfile
 from repro.core.decoder import (DecodeCarry, admit_carry_rows, bucket_rows,
                                 init_decode_carry, make_admit_fn,
-                                make_generate_fn, make_slice_fn,
-                                result_profile, retire_carry_rows)
+                                make_slice_fn, retire_carry_rows)
 from repro.core.osdt import CalibrationStore
 from repro.data import tokenizer as tok
 from repro.launch.mesh import make_serving_mesh
@@ -87,8 +89,6 @@ from repro.models.quantize import (WEIGHT_DTYPES, decode_weight_bytes,
 from repro.obs import Observability
 from repro.obs.metrics import MetricsRegistry
 from repro.spec.drafter import Drafter
-
-DEAD_TASK = "__dead__"  # pseudo-task of pad slots (resolves to the static table)
 
 
 @dataclass
@@ -118,15 +118,13 @@ class Response:
     tokens_dropped: int = 0  # generated but cut at EOS / never unmasked
     blocks_drafted: int = 0   # spec decode: blocks drafted for this row
     blocks_accepted: int = 0  # ... and how many survived verification
-    # submit -> the row's FIRST decoded block is available. Sliced decode
-    # measures it at the first slice boundary the row participated in;
-    # the batch-granular runtime can only observe the batch end, so there
-    # it equals wall_s (stats glossary). Always ``block_s[0]``.
+    # submit -> the row's FIRST decoded block is available, measured at
+    # the first slice boundary the row participated in. Always
+    # ``block_s[0]``.
     ttfb_s: float = 0.0
     # submit -> each decoded block, as the host observes it: the end of
     # the wait for the slice that completed the block (blocks completed
-    # by one slice share its time). The batch runtime observes only the
-    # batch end, so it holds that one entry.
+    # by one slice share its time)
     block_s: Tuple[float, ...] = ()
     # radix prefix cache (0 with prefix_cache off): tree pages this
     # row's admission reused, and the prompt tokens whose prefill those
@@ -148,13 +146,12 @@ class RequestState:
 
 @dataclass
 class Slot:
-    """One row of the decode batch. ``state``: free | active | dead.
+    """One row of the decode batch. ``state``: free | active.
     ``pages``: private pool pages this slot's request owns (paged layout);
-    freed — and shared-prefix references dropped — at retirement.
-    Sliced decode additionally accumulates the slot's per-request
-    latency split (``decode_s`` over the slices it was live in,
-    ``block_t``: the host time each block completed) and remembers which
-    task it is calibrating, if any."""
+    freed — and shared-prefix references dropped — at retirement. The
+    slot accumulates its request's latency split (``decode_s`` over the
+    slices it was live in, ``block_t``: the host time each block
+    completed) and remembers which task it is calibrating, if any."""
     index: int
     state: str = "free"
     rs: Optional[RequestState] = None
@@ -171,11 +168,11 @@ class Slot:
     prefix_len: int = 0
     prefix_hit_pages: int = 0
 
-    def admit(self, rs: Optional[RequestState],
+    def admit(self, rs: RequestState,
               pages: Optional[List[int]] = None) -> None:
         self.rs = rs
         self.pages = pages
-        self.state = "active" if rs is not None else "dead"
+        self.state = "active"
         self.decode_s = 0.0
         self.block_t = []
         self.calib_task = ""
@@ -183,8 +180,7 @@ class Slot:
         self.prefix_pages = None
         self.prefix_len = 0
         self.prefix_hit_pages = 0
-        if rs is not None:
-            rs.slot = self.index
+        rs.slot = self.index
 
     def retire(self) -> None:
         self.rs = None
@@ -203,11 +199,9 @@ _STATS_FIELDS: Dict[str, tuple] = {
     "requests": (int, "requests admitted"),
     "tokens": (int, "delivered tokens (post-EOS truncation)"),
     "tokens_dropped": (int, "generated-but-truncated tokens"),
-    "nfe": (int, "model forwards across all batches"),
-    "wall_s": (float, "sum of batch decode walls"),
+    "nfe": (int, "model forwards, prefills included"),
+    "wall_s": (float, "sum of slice dispatch walls"),
     "queue_s": (float, "sum of per-request queue waits"),
-    "batches": (int, "monolithic decode batches"),
-    "dead_slots": (int, "pad rows admitted dead"),
     "seq_steps": (int, "sum of per-row live denoising steps"),
     # nfe x the resident decode footprint — int8 engines stream ~1/4
     # the f32 bytes per forward
@@ -220,12 +214,7 @@ _STATS_FIELDS: Dict[str, tuple] = {
     # speculative drafting (all 0 with spec_decode off)
     "blocks_drafted": (int, "row-blocks flagged by the signature"),
     "blocks_accepted": (int, "drafted blocks surviving verification"),
-    "draft_batches": (int, "batches running the draft+verify forwards"),
-    # estimate: one per batch-block whose step loop never ran while some
-    # row was still live to reach it, minus the 2 draft forwards per
-    # batch; blocks past every row's EOS don't count
-    "nfe_saved": (int, "forwards saved vs stepping (lower bound)"),
-    # step-sliced decode (all 0 with slice_len == 0)
+    "draft_batches": (int, "boundaries that handed the slice a plan"),
     "slices": (int, "compiled slice dispatches"),
     # the async-admission payoff: admitted while cursor > 0 rows present
     "mid_admits": (int, "requests admitted mid-generation"),
@@ -375,11 +364,12 @@ def _seed_prefill_batched_prog(cfg: ModelConfig, max_len: int, ps: int,
 
 
 class Scheduler:
-    """Request queue + slot pool + one compiled decode program.
+    """Request queue + slot pool + one compiled slice program.
 
-    ``step()`` admits up to ``batch_size`` queued requests into slots,
-    decodes one batch, retires every slot, and returns the responses.
-    ``run()`` drains the queue. Unfilled slots are admitted DEAD.
+    ``slice_step()`` runs one slice boundary: it admits queued requests
+    into free slots, dispatches one ``slice_len``-block slice over the
+    carry, retires finished rows, and returns their responses. ``run()``
+    drains the queue by ``slice_step()`` alone.
     """
 
     def __init__(self, params, cfg: ModelConfig, dcfg: DecodeConfig, *,
@@ -391,13 +381,17 @@ class Scheduler:
         self.cfg = cfg
         self.dcfg = dcfg
         self.ecfg = ecfg if ecfg is not None else EngineConfig()
+        self.slice_len = int(self.ecfg.slice_len)
+        if self.slice_len < 1:
+            raise ValueError(
+                f"EngineConfig.slice_len must be >= 1 (blocks per "
+                f"compiled slice), got {self.slice_len}")
         mode = self.ecfg.resolved_cache_mode()
         # weight streaming dtype: quantize ONCE at load (before the page
         # pool's shared prefill — every forward thereafter streams the
         # int8 tiles), and price each forward's weight traffic for the
         # ``weight_bytes_streamed`` stat
-        self.weight_dtype = self.ecfg.weight_dtype or dcfg.weight_dtype \
-            or "bf16"
+        self.weight_dtype = dcfg.weight_dtype or "bf16"
         assert self.weight_dtype in WEIGHT_DTYPES, self.weight_dtype
         if self.weight_dtype == "int8" and not is_quantized(self.params):
             self.params = quantize_decode_params(params, cfg)
@@ -432,12 +426,8 @@ class Scheduler:
         self.paged = dcfg.cache_layout == "paged" and mode != "none"
         self.prefix_cache = bool(self.ecfg.prefix_cache)
         if self.prefix_cache:
-            # the radix tree shares PAGES and admits through per-row
-            # composed prefills at slice boundaries — both are paged /
-            # step-sliced machinery
+            # the radix tree shares PAGES between rows
             assert self.paged, "prefix_cache needs the paged KV layout"
-            assert self.ecfg.slice_len >= 1, \
-                "prefix_cache admits through the step-sliced loop"
         self.prefix_tree: Optional[RadixPrefixCache] = None
         self._prefix_memo: Dict[str, Tuple[List[int], int]] = {}
         # uid -> tree pages matched BEFORE this boundary's batched
@@ -460,15 +450,11 @@ class Scheduler:
             assert self.ecfg.batch_size % self.dp == 0, \
                 f"batch_size {self.ecfg.batch_size} must divide into " \
                 f"data={self.dp} slot shards"
-            assert self.ecfg.slice_len >= 1, \
-                "sharded serving runs the step-sliced loop (slice " \
-                "boundaries are the host-side metadata exchange points)"
             assert not self.prefix_cache or self.dp == 1, \
                 "radix prefix cache is single-shard (tree pages live " \
                 "on one data shard); use model_parallel only"
             pallas = [name for name, on in (
-                ("attn_impl=kernel",
-                 (self.ecfg.attn_impl or dcfg.attn_impl) == "kernel"),
+                ("attn_impl=kernel", dcfg.attn_impl == "kernel"),
                 ("step_fusion=fused", dcfg.step_fusion == "fused"),
                 ("weight_dtype=int8", self.weight_dtype == "int8")) if on]
             if pallas and jax.default_backend() == "tpu":
@@ -509,35 +495,26 @@ class Scheduler:
         self.drafter = Drafter(self.store, dcfg,
                                max_steps=self.ecfg.draft_max_steps) \
             if self.spec else None
-        self._gen = make_generate_fn(
-            cfg, dcfg, cache_mode=mode, attn_impl=self.ecfg.attn_impl,
-            cache_layout="paged" if self.paged else "dense",
-            shared_prefix_len=self.shared_len if self.paged else 0,
-            variant="draft" if self.spec else "step",
-            weight_dtype=self.weight_dtype)
 
         # step-sliced decode loop (SERVING.md "Async admission")
-        self.slice_len = int(self.ecfg.slice_len)
         self._carry = None
         self._nfe_seen = 0
         self._calibrating: Dict[str, int] = {}  # task -> calibration slot
-        if self.slice_len:
-            kw = dict(cache_mode=mode, attn_impl=self.ecfg.attn_impl,
-                      cache_layout="paged" if self.paged else "dense",
-                      shared_prefix_len=self.shared_len if self.paged
-                      else 0)
-            self._slice_fn = make_slice_fn(
-                cfg, dcfg, slice_len=self.slice_len,
-                variant="draft" if self.spec else "step",
-                weight_dtype=self.weight_dtype, **kw)
-            self._admit_fn = make_admit_fn(cfg, dcfg, **kw) \
-                if mode != "none" else None
+        kw = dict(cache_mode=mode,
+                  cache_layout="paged" if self.paged else "dense",
+                  shared_prefix_len=self.shared_len if self.paged else 0)
+        self._slice_fn = make_slice_fn(
+            cfg, dcfg, slice_len=self.slice_len,
+            variant="draft" if self.spec else "step",
+            weight_dtype=self.weight_dtype, **kw)
+        self._admit_fn = make_admit_fn(cfg, dcfg, **kw) \
+            if mode != "none" else None
 
     @property
     def carry(self) -> Optional[DecodeCarry]:
-        """The live step-sliced decode carry between slices (``None``
-        when idle or not sliced). Read it between ``slice_step`` calls
-        only: the next slice may donate its buffers."""
+        """The live decode carry between slices (``None`` when idle).
+        Read it between ``slice_step`` calls only: the next slice may
+        donate its buffers."""
         return self._carry
 
     def _count_nfe(self, n: int) -> None:
@@ -643,249 +620,6 @@ class Scheduler:
     def pending(self) -> int:
         return len(self.queue)
 
-    # -- batch formation ------------------------------------------------
-    def _fill(self) -> Tuple[List[RequestState], Dict[str, int]]:
-        """Pop admissible requests (FIFO).
-
-        Returns (picked, calib_rows): ``picked[i]`` lands in slot ``i``;
-        ``calib_rows`` maps each not-yet-calibrated task to the row whose
-        recorded profile will calibrate it (its first admitted request) —
-        every row records, so several new tasks calibrate per batch.
-
-        Paged layout: admission stops once the page pool cannot fit
-        another request's private pages — the pool, not the slot count,
-        is the capacity, so a partially free pool admits partial batches
-        instead of waiting for a whole dense slot's worth of HBM.
-        """
-        B = self.ecfg.batch_size
-        if self.paged and self.private_per_slot:
-            B = min(B, self.allocator.available // self.private_per_slot)
-        picked: List[RequestState] = []
-        calib_rows: Dict[str, int] = {}
-        while self.queue and len(picked) < B:
-            rs = self.queue.popleft()
-            t = rs.req.task
-            if not self.store.calibrated(t) and t not in calib_rows:
-                calib_rows[t] = len(picked)
-            picked.append(rs)
-        return picked, calib_rows
-
-    # -- decode ---------------------------------------------------------
-    def step(self) -> List[Response]:
-        picked, calib_rows = self._fill()
-        if not picked:
-            return []
-        P = self.ecfg.prompt_len
-        tr = self.obs.tracer
-        now = time.perf_counter()
-        for slot, rs in zip(self.slots, picked):
-            rs.t_admit = now
-            pages = None
-            if self.paged:
-                # admit = COW-fork off the shared-prefix parent: a
-                # read-only reference on the shared pages plus private
-                # pages for the logical range this row actually writes
-                # (_fill guaranteed availability)
-                _, pages = self.allocator.fork(
-                    self._shared_for(slot), self.private_per_slot,
-                    self.shard_of_slot(slot.index))
-            slot.admit(rs, pages)
-            if tr:
-                tr.aend("queued", rs.req.uid, t=now)
-                tr.begin("serve", tid=self.obs.slot_track(slot.index),
-                         t=now, uid=rs.req.uid, task=rs.req.task)
-            self.seen_tasks[rs.req.task] = \
-                self.seen_tasks.get(rs.req.task, 0) + 1
-        for slot in self.slots[len(picked):]:
-            slot.admit(None)  # explicit dead slot: zero pages
-
-        # the slot pool is the source of truth for the batch's runtime
-        # arguments: prompt rows, liveness, per-slot table gather, and
-        # (paged) the page tables
-        rows, tasks = [], []
-        n_shared = self.shared_len // self.dcfg.page_size if self.paged \
-            else 0
-        page_tables = np.full((len(self.slots), self.n_log), -1, np.int32) \
-            if self.paged else None
-        for slot in self.slots:
-            if slot.state == "active":
-                rows.append(self._prompt_row(slot.rs))
-                tasks.append(slot.rs.req.task)
-                if self.paged:
-                    page_tables[slot.index, :n_shared] = \
-                        self._shared_for(slot)
-                    page_tables[slot.index, n_shared:] = slot.pages
-            else:  # dead slot: mask-only prompt row, live=False
-                rows.append([self.mask_id] * P)
-                tasks.append(DEAD_TASK)
-        prompt = np.asarray(rows, np.int32)
-        live = np.asarray([s.state == "active" for s in self.slots])
-        n_dead = int((~live).sum())
-        tables = self.store.tables_for(tasks)
-        draft_mask = None
-        if self.spec:
-            # draft plan: each row's calibrated signature flags its easy
-            # blocks (uncalibrated tasks — including the row currently
-            # calibrating one — and dead slots draft nothing)
-            draft_mask = self.drafter.mask_for(tasks)
-        if self.paged:
-            self.stats.pages_peak = max(self.stats.pages_peak,
-                                        self.allocator.in_use)
-
-        served: set = set()   # slots whose serve span closed (trace)
-        try:
-            t0 = time.perf_counter()
-            with tr.span("sched.batch", live=len(picked), dead=n_dead,
-                         draft_blocks=int(draft_mask.sum())
-                         if draft_mask is not None else 0):
-                args = (self.params, jnp.asarray(prompt),
-                        jnp.asarray(tables), self._mask_arr,
-                        jnp.asarray(live),
-                        self.eos_id if self.ecfg.eos_early_exit else None)
-                kwargs = {}
-                if self.paged:
-                    args += (self._pool_k, self._pool_v,
-                             jnp.asarray(page_tables))
-                if draft_mask is not None:
-                    kwargs["draft_mask"] = jnp.asarray(draft_mask)
-                res = self._gen(*args, **kwargs)
-                tokens = np.asarray(res.tokens)  # blocks until ready
-                t_end = time.perf_counter()
-            decode_s = t_end - t0
-            self._h_dispatch.observe(decode_s, kind="batch")
-
-            for task, row in calib_rows.items():
-                # each new task calibrates from its own row's recording
-                # and step counts (not the batch-max, which ride-along
-                # rows of other tasks determine)
-                self.store.ingest(task, result_profile(res, row=row))
-                if tr:
-                    tr.instant("calibrate", task=task, row=row)
-                if self.drafter is not None:
-                    self.drafter.invalidate(task)
-            if calib_rows and self.ecfg.store_path:
-                self.store.save(self.ecfg.store_path)
-
-            seq_steps = np.asarray(res.seq_steps)
-            drafted = np.asarray(res.blocks_drafted)
-            accepted = np.asarray(res.blocks_accepted)
-            drift = self.obs.drift
-            if drift is not None:
-                thr = np.asarray(res.thr_steps)
-                msum = np.asarray(res.margin_sum)
-                mn = np.asarray(res.margin_n)
-                # one batch conversion, not one per served row
-                conf_rec = np.asarray(res.conf)
-                val_rec = np.asarray(res.conf_valid)
-            out: List[Response] = []
-            for slot in self.slots:
-                if slot.rs is None:
-                    continue
-                j, rs = slot.index, slot.rs
-                row = tokens[j].tolist()
-                if self.eos_id in row:
-                    row = row[:row.index(self.eos_id)]
-                row = [t for t in row if t != self.mask_id]
-                queue_s = rs.t_admit - rs.t_submit
-                steps = int(seq_steps[j].sum())
-                out.append(Response(
-                    rs.req.uid, rs.req.task, tok.decode(row),
-                    nfe=steps, wall_s=queue_s + decode_s, queue_s=queue_s,
-                    decode_s=decode_s, tokens_out=len(row),
-                    tokens_dropped=tokens.shape[1] - len(row),
-                    blocks_drafted=int(drafted[j]),
-                    blocks_accepted=int(accepted[j]),
-                    # batch granularity: the first block is only
-                    # observable when the whole batch returns
-                    ttfb_s=queue_s + decode_s,
-                    block_s=(queue_s + decode_s,), token_ids=tuple(row)))
-                self.stats.tokens += len(row)
-                self.stats.tokens_dropped += tokens.shape[1] - len(row)
-                self.stats.queue_s += queue_s
-                self.stats.ttfb_s += queue_s + decode_s
-                self.stats.seq_steps += steps
-                self._h_queue.observe(queue_s)
-                if drift is not None:
-                    drift.observe(rs.req.task,
-                                  CalibrationProfile(conf=conf_rec[j],
-                                                     valid=val_rec[j],
-                                                     steps=seq_steps[j]),
-                                  thr_steps=thr[j], seq_steps=seq_steps[j],
-                                  margin_sum=msum[j], margin_n=mn[j])
-                if tr:
-                    tr.end("serve", tid=self.obs.slot_track(j), t=t_end,
-                           tokens=len(row), nfe=steps)
-                    tr.aend("request", rs.req.uid)
-                    served.add(j)
-            if draft_mask is not None and int(drafted.sum()) > 0:
-                self.stats.blocks_drafted += int(drafted.sum())
-                self.stats.blocks_accepted += int(accepted.sum())
-                self.stats.draft_batches += 1
-                # lower-bound estimate of forwards saved: a block whose
-                # step loop ran zero iterations while some row was still
-                # live to reach it (its accepted draft is the only way a
-                # live row can carry no masks) would have cost >= 1
-                # stepped forward; blocks past every row's EOS
-                # retirement cost zero either way and must not count.
-                # The batch paid 2 extra forwards (draft + verify).
-                nb = self.dcfg.num_blocks
-                bs = self.dcfg.block_size
-                reach = np.zeros((nb,), bool)
-                for j in np.flatnonzero(live):
-                    row = tokens[j].tolist()
-                    last = (row.index(self.eos_id) // bs) \
-                        if (self.ecfg.eos_early_exit
-                            and self.eos_id in row) else nb - 1
-                    reach[: last + 1] = True
-                skipped = int(((np.asarray(res.steps_per_block) == 0)
-                               & reach).sum())
-                self.stats.nfe_saved += skipped - 2
-            self.stats.requests += len(picked)
-            self._count_nfe(int(res.nfe))
-            self.stats.prefill_nfe += 1  # the batch's fused prefill
-            self.stats.wall_s += decode_s
-            self.stats.batches += 1
-            self.stats.dead_slots += n_dead
-        except BaseException:
-            # a failed batch must not swallow its requests: put them
-            # back at the head of the queue (FIFO order preserved) so a
-            # retried run() can still serve every uid
-            if tr:
-                tr.instant("batch_failed", tid=0)
-                for slot in self.slots:
-                    if slot.rs is None:
-                        continue
-                    if slot.index in served:
-                        # its response was already emitted when the
-                        # failure hit; the requeue re-serves it, so
-                        # re-open the lifecycle span for balance
-                        tr.abegin("request", slot.rs.req.uid,
-                                  task=slot.rs.req.task)
-                    else:
-                        tr.end("serve",
-                               tid=self.obs.slot_track(slot.index),
-                               requeued=True)
-            for rs in reversed(picked):
-                self.queue.appendleft(rs)
-                if tr:
-                    tr.abegin("queued", rs.req.uid)
-            raise
-        finally:
-            # retire = reclaim, even when decode raises: a failed batch
-            # must not leak its pages (a leak shrinks the pool until
-            # _fill can admit nothing and run() livelocks)
-            for slot in self.slots:
-                if self.paged and slot.pages is not None:
-                    # release the fork: private pages (merged-in accepted
-                    # drafts included) return to the free list; the
-                    # shared-prefix reference is dropped (the scheduler's
-                    # own permanent reference keeps those pages)
-                    self.allocator.free(slot.pages)
-                    self.allocator.free(self._shared_for(slot))
-                    self.stats.pages_freed += len(slot.pages)
-                slot.retire()
-        return out
-
     # -- step-sliced decode (SERVING.md "Async admission") --------------
     def _start_carry(self) -> None:
         """Build a fresh all-free carry. Paged: the pool arrays move INTO
@@ -932,8 +666,7 @@ class Scheduler:
             # in the rest (pads in the middle). Engines WITHOUT the
             # radix cache build the identical row for a prefix-carrying
             # request and prefill it whole — that is what keeps the
-            # paged/dense and sliced/monolithic oracle comparisons
-            # token-identical.
+            # cache-on/off and paged/dense comparisons token-identical.
             return self._row_tokens(rs.req)
         ids = tok.encode(rs.req.prompt, bos=True)
         ids = ids[-(P - self.shared_len):]
@@ -1486,13 +1219,16 @@ class Scheduler:
         return their responses. A no-op (returning ``[]``) when nothing
         is queued or active. Each phase is a ``sched.*`` span
         (SERVING.md "Observability")."""
-        assert self.slice_len, "slice_step() needs EngineConfig.slice_len"
         tr = self.obs.tracer
         with tr.span("sched.boundary", slice=int(self.stats.slices)) as bd:
             with tr.span("sched.admit") as sp:
                 admitted = self._admit_sliced()
                 sp.set_metadata(rows=len(admitted))
-            self._admit_prefill(admitted)
+            try:
+                self._admit_prefill(admitted)
+            except BaseException:
+                self._requeue_active("admit_failed")
+                raise
             active = [s for s in self.slots if s.state == "active"]
             bd.set_metadata(live=len(active), admitted=len(admitted))
             if not active:
@@ -1534,42 +1270,7 @@ class Scheduler:
                 t_end = time.perf_counter()
                 nfe_now = int(np.asarray(self._carry.nfe))
         except BaseException:
-            # a failed slice must not swallow in-flight requests or leak
-            # their pages: requeue FIFO (by submit time) and reclaim.
-            # The retried admission re-counts the request and may
-            # re-claim its calibration row, so back out both here.
-            if tr:
-                tr.instant("slice_failed", tid=0)
-            for slot in sorted(active, key=lambda s: s.rs.t_submit,
-                               reverse=True):
-                if tr:
-                    tr.end("serve", tid=self.obs.slot_track(slot.index),
-                           requeued=True)
-                    tr.abegin("queued", slot.rs.req.uid)
-                self.queue.appendleft(slot.rs)
-                self.stats.requests -= 1
-                if slot.was_mid:
-                    self.stats.mid_admits -= 1
-                if slot.calib_task:
-                    self._calibrating.pop(slot.calib_task, None)
-                if self.prefix_cache:
-                    # re-admission re-counts the lookup (possibly with a
-                    # deeper match — seeds survive the failure)
-                    if slot.prefix_hit_pages:
-                        self.stats.prefix_hits -= 1
-                    elif slot.prefix_len:
-                        self.stats.prefix_misses -= 1
-                    self.stats.prefix_hit_pages -= slot.prefix_hit_pages
-                    self.stats.prefill_tokens_saved -= \
-                        slot.prefix_hit_pages * self.dcfg.page_size
-                if self.paged and slot.pages is not None:
-                    self.allocator.free(slot.pages)
-                    if self.prefix_cache:
-                        self.allocator.free(slot.prefix_pages or [])
-                    else:
-                        self.allocator.free(self._shared_for(slot))
-                slot.retire()
-            self._teardown_carry()
+            self._requeue_active("slice_failed")
             raise
         wall = t_end - t0
         self.stats.wall_s += wall
@@ -1593,20 +1294,55 @@ class Scheduler:
             self._teardown_carry()
         return out
 
+    def _requeue_active(self, event: str) -> None:
+        """Back out every active row after a boundary program raised (the
+        admission prefill or the slice): the carry holding their state is
+        torn down, so none of them may be swallowed or leak its pages.
+        Requeue FIFO (by submit time) and reclaim. The retried admission
+        re-counts the request and may re-claim its calibration row, so
+        back out both here."""
+        tr = self.obs.tracer
+        if tr:
+            tr.instant(event, tid=0)
+        active = [s for s in self.slots if s.state == "active"]
+        for slot in sorted(active, key=lambda s: s.rs.t_submit,
+                           reverse=True):
+            if tr:
+                tr.end("serve", tid=self.obs.slot_track(slot.index),
+                       requeued=True)
+                tr.abegin("queued", slot.rs.req.uid)
+            self.queue.appendleft(slot.rs)
+            self.stats.requests -= 1
+            if slot.was_mid:
+                self.stats.mid_admits -= 1
+            if slot.calib_task:
+                self._calibrating.pop(slot.calib_task, None)
+            if self.prefix_cache:
+                # re-admission re-counts the lookup (possibly with a
+                # deeper match — seeds survive the failure)
+                if slot.prefix_hit_pages:
+                    self.stats.prefix_hits -= 1
+                elif slot.prefix_len:
+                    self.stats.prefix_misses -= 1
+                self.stats.prefix_hit_pages -= slot.prefix_hit_pages
+                self.stats.prefill_tokens_saved -= \
+                    slot.prefix_hit_pages * self.dcfg.page_size
+            if self.paged and slot.pages is not None:
+                self.allocator.free(slot.pages)
+                if self.prefix_cache:
+                    self.allocator.free(slot.prefix_pages or [])
+                else:
+                    self.allocator.free(self._shared_for(slot))
+            slot.retire()
+        self._teardown_carry()
+
     def run(self) -> List[Response]:
+        """Drain the queue: slice boundaries until nothing is queued or
+        active, or nothing queued is admissible (pool too small)."""
         out: List[Response] = []
-        if self.slice_len:
-            while self.queue or \
-                    any(s.state == "active" for s in self.slots):
-                got = self.slice_step()
-                out.extend(got)
-                if not got and not any(s.state == "active"
-                                       for s in self.slots):
-                    break  # nothing admissible (pool too small)
-            return out
-        while self.queue:
-            got = self.step()
-            if not got:  # nothing admissible (should not happen)
-                break
+        while self.queue or any(s.state == "active" for s in self.slots):
+            got = self.slice_step()
             out.extend(got)
+            if not got and not any(s.state == "active" for s in self.slots):
+                break
         return out

@@ -14,8 +14,8 @@ The contracts enforced here:
 * **Off = free** — a tracing+drift engine delivers byte-identical
   text and identical token/NFE accounting to the default engine.
 * **Trace integrity** — one balanced span tree per submitted request,
-  across mid-generation admission AND failed-slice/failed-batch
-  requeues.
+  across mid-generation admission AND the requeues of a failed slice or
+  admission prefill.
 * **Profiler spans** — every slice boundary's ``sched.*`` phases reach
   an active ``jax.profiler`` session, nested and in order, whether or
   not the ring is on; the ring, when on, holds the same spans.
@@ -80,7 +80,7 @@ def calibrated_store(small_model):
 
 
 def _sched(cfg, params, store, **ecfg_kw):
-    kw = dict(batch_size=2, prompt_len=PROMPT_LEN, slice_len=1)
+    kw = dict(batch_size=2, prompt_len=PROMPT_LEN)
     kw.update(ecfg_kw)
     dcfg_kw = kw.pop("dcfg_kw", {})
     dcfg = dataclasses.replace(DCFG, **dcfg_kw) if dcfg_kw else DCFG
@@ -293,7 +293,7 @@ def test_engine_drift_telemetry_end_to_end(small_model, calibrated_store):
 # off = free (bit-identity)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("slice_len", [0, 1])
+@pytest.mark.parametrize("slice_len", [1, 2])
 def test_obs_off_output_identity(small_model, calibrated_store, slice_len):
     """The default engine and a tracing+drift engine deliver identical
     text and identical token/NFE/step accounting — telemetry rides the
@@ -313,8 +313,8 @@ def test_obs_off_output_identity(small_model, calibrated_store, slice_len):
     for uid in ref:
         assert got[uid].text == ref[uid].text, uid
         assert got[uid].nfe == ref[uid].nfe
-    for f in ("requests", "tokens", "nfe", "seq_steps", "batches",
-              "slices", "mid_admits", "pages_freed", "prefill_nfe"):
+    for f in ("requests", "tokens", "nfe", "seq_steps", "slices",
+              "mid_admits", "pages_freed", "prefill_nfe", "prefill_rows"):
         assert getattr(on.stats, f) == getattr(off.stats, f), f
 
 
@@ -360,26 +360,32 @@ def test_trace_covers_request_lifecycle(small_model, calibrated_store):
     assert any(e["args"].get("mid") for e in serves)
 
 
-def test_trace_balanced_across_failed_slice(small_model, calibrated_store):
-    """An injected slice failure requeues its rows: their serve spans
-    close (requeued=True), queued spans reopen, and the retried run
-    still exports a balanced, schema-valid trace."""
+@pytest.mark.parametrize("phase,span", [("slice", "sched.slice"),
+                                        ("admit", "sched.admit_prefill")])
+def test_trace_balanced_across_failed_slice(small_model, calibrated_store,
+                                            phase, span):
+    """An injected failure of a boundary program — the slice, or the
+    admission prefill — requeues its rows: their serve spans close
+    (requeued=True), queued spans reopen, and the retried run still
+    exports a balanced, schema-valid trace."""
     cfg, params = small_model
     s = _sched(cfg, params, calibrated_store, trace=True,
                dcfg_kw=dict(cache_layout="paged"))
-    real = s._slice_fn
+    name = f"_{phase}_fn"
+    real = getattr(s, name)
     calls = {"n": 0}
 
     def flaky(*a, **kw):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise RuntimeError("injected slice failure")
+            raise RuntimeError(f"injected {phase} failure")
         return real(*a, **kw)
 
-    s._slice_fn = flaky
+    setattr(s, name, flaky)
     s.submit(_requests("alpha", 2))
     with pytest.raises(RuntimeError):
         s.slice_step()
+    assert s.pending() == 2            # both rows back in the queue
     out = s.run()
     assert sorted(r.uid for r in out) == [0, 1]
     doc = s.obs.tracer.export()
@@ -389,43 +395,15 @@ def test_trace_balanced_across_failed_slice(small_model, calibrated_store):
         assert bal.get(("request", uid, "request"), 0) == 0
         assert bal.get(("request", uid, "queued"), 0) == 0
     names = [e["name"] for e in doc["traceEvents"]]
-    assert "slice_failed" in names
-    # the failed slice's own span closes as an error
-    assert any(e["name"] == "sched.slice" and e["ph"] == "E"
+    assert f"{phase}_failed" in names
+    # the failed program's own span closes as an error
+    assert any(e["name"] == span and e["ph"] == "E"
                and (e.get("args") or {}).get("error")
                for e in doc["traceEvents"])
-    # the failed slice's serve spans carry the requeue marker
+    # the failed boundary's serve spans carry the requeue marker
     assert any(e["name"] == "serve" and e["ph"] == "E"
                and (e.get("args") or {}).get("requeued")
                for e in doc["traceEvents"])
-
-
-def test_trace_balanced_across_failed_batch(small_model, calibrated_store):
-    cfg, params = small_model
-    s = _sched(cfg, params, calibrated_store, trace=True, slice_len=0)
-    real = s._gen
-    calls = {"n": 0}
-
-    def flaky(*a, **kw):
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise RuntimeError("injected batch failure")
-        return real(*a, **kw)
-
-    s._gen = flaky
-    s.submit(_requests("alpha", 2))
-    with pytest.raises(RuntimeError):
-        s.step()
-    out = s.run()
-    assert sorted(r.uid for r in out) == [0, 1]
-    doc = s.obs.tracer.export()
-    validate_trace(doc)
-    bal = _async_balance(s.obs.tracer)
-    for uid in (0, 1):
-        assert bal.get(("request", uid, "request"), 0) == 0
-        assert bal.get(("request", uid, "queued"), 0) == 0
-    names = [e["name"] for e in doc["traceEvents"]]
-    assert "batch_failed" in names and "sched.batch" in names
 
 
 def test_trace_save_roundtrip(tmp_path, small_model, calibrated_store):
@@ -564,11 +542,11 @@ def test_profiler_records_seed_and_plan(tmp_path, small_model,
         assert any(b[0] <= e[0] and e[1] <= b[1] for b in bounds)
 
 
-@pytest.mark.parametrize("slice_len", [0, 1])
+@pytest.mark.parametrize("slice_len", [1, 2])
 def test_response_block_times(small_model, calibrated_store, slice_len):
-    """Sliced decode stamps every block: ``num_blocks`` strictly
-    increasing times from submit, the first of them the ttfb. The batch
-    runtime observes only the batch end."""
+    """Sliced decode stamps every block: ``num_blocks`` times from
+    submit, the first of them the ttfb, rising from one slice to the
+    next; the blocks one slice completed share its time."""
     cfg, params = small_model
     s = _sched(cfg, params, calibrated_store, slice_len=slice_len,
                dcfg_kw=dict(cache_layout="paged"))
@@ -577,11 +555,11 @@ def test_response_block_times(small_model, calibrated_store, slice_len):
     assert len(out) == 3
     for r in out:
         assert r.ttfb_s > 0 and r.block_s[0] == r.ttfb_s
-        if slice_len:
-            assert len(r.block_s) == DCFG.num_blocks
-            assert all(b > a for a, b in zip(r.block_s, r.block_s[1:]))
-            # the slices the row decoded in lie between its admission
-            # and its last block
-            assert r.block_s[-1] >= r.queue_s + r.decode_s
-        else:
-            assert r.block_s == (r.wall_s,)
+        assert len(r.block_s) == DCFG.num_blocks
+        per_slice = r.block_s[::slice_len]
+        assert all(b > a for a, b in zip(per_slice, per_slice[1:]))
+        for i in range(0, DCFG.num_blocks, slice_len):
+            assert set(r.block_s[i:i + slice_len]) == {r.block_s[i]}
+        # the slices the row decoded in lie between its admission and
+        # its last block
+        assert r.block_s[-1] >= r.queue_s + r.decode_s

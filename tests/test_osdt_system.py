@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.config.base import DecodeConfig
+from repro.config.base import DecodeConfig, EngineConfig
 from repro.config.registry import get_config
 from repro.core.osdt import OSDTSession
 from repro.core.signature import cosine_matrix, mean_offdiag_cosine
@@ -61,7 +61,8 @@ def test_engine_batched_serving(small_model):
     cfg, params = small_model
     dcfg = DecodeConfig(max_new_tokens=8, block_size=4, policy="osdt",
                         mode="block", metric="q1", cap=0.9, slack=0.2)
-    eng = DiffusionEngine(params, cfg, dcfg, batch_size=2, prompt_len=16,
+    eng = DiffusionEngine(params, cfg, dcfg,
+                          ecfg=EngineConfig(batch_size=2, prompt_len=16),
                           mask_id=tok.MASK_ID)
     reqs = [Request(i, "gsm8k-syn", f"Q: what is {i}+1?\nA:")
             for i in range(3)]

@@ -539,9 +539,12 @@ def test_shared_prefix_pages_are_refcounted_and_cow(small_model):
     assert (np.abs(before_k).sum() > 0)  # the one-time prefill wrote them
 
     sch.submit([Request(0, "a", "q0?"), Request(1, "b", "q1?")])
-    # during the batch each active slot holds a reference
+    # while the rows decode each active slot holds a reference
     base = sch.allocator
-    sch.step()
+    sch.slice_step()
+    for p in sch._shared_pages:
+        assert base.refcount(p) == 1 + 2
+    sch.run()
     # decode never wrote the shared pages (COW contract)
     after_k = np.asarray(sch._pool_k)[:, sch._shared_pages]
     np.testing.assert_array_equal(before_k, after_k)
@@ -585,40 +588,22 @@ def test_shared_prefix_aligns_when_prompt_len_is_odd(small_model):
 
 
 @pytest.mark.paged
-def test_failed_batch_requeues_and_reclaims(small_model):
-    """A decode exception must neither leak the batch's pages (livelock)
-    nor swallow its requests: they go back to the queue head."""
-    cfg, params = small_model
-    sch = _shared_scheduler(cfg, params)
-    n_shared = len(sch._shared_pages)
-    sch.submit([Request(i, "t", f"question {i}?") for i in range(2)])
-
-    real_gen = sch._gen
-    sch._gen = lambda *a, **k: (_ for _ in ()).throw(RuntimeError("boom"))
-    with pytest.raises(RuntimeError):
-        sch.step()
-    assert sch.allocator.in_use == n_shared   # pages reclaimed
-    assert sch.pending() == 2                 # requests restored (FIFO)
-    sch._gen = real_gen
-    out = sch.run()                           # retry serves every uid
-    assert sorted(r.uid for r in out) == [0, 1]
-
-
-@pytest.mark.paged
 def test_page_scarcity_limits_admission(small_model):
     """A pool sized below batch_size * pages-per-request admits partial
-    batches — requests wait for PAGES, not whole dense slots — and the
-    queue still drains completely."""
+    batches — requests wait for PAGES, not free slots — and the queue
+    still drains completely."""
     cfg, params = small_model
     probe = _shared_scheduler(cfg, params)
     n_shared = len(probe._shared_pages)
     per = probe.private_per_slot
     sch = _shared_scheduler(cfg, params, num_pages=n_shared + per)
     sch.submit([Request(i, "t", f"question {i}?") for i in range(3)])
-    first = sch.step()
-    assert len(first) == 1          # pages for exactly one request
+    first = sch.slice_step()
+    # pages for exactly one request, though a second slot is free
+    assert sum(s.state == "active" for s in sch.slots) == 1
+    assert sch.pending() == 2
     rest = sch.run()
-    assert len(rest) == 2
+    assert sorted(r.uid for r in first + rest) == [0, 1, 2]
     assert sch.allocator.in_use == n_shared
 
 
@@ -663,7 +648,7 @@ def test_page_conservation_across_prefix_lifecycle(small_model):
     tree-held, with shared/tree pages counted once however many rows
     map them."""
     cfg, params = small_model
-    ecfg = EngineConfig(batch_size=2, prompt_len=PROMPT_LEN, slice_len=1,
+    ecfg = EngineConfig(batch_size=2, prompt_len=PROMPT_LEN,
                         prefix_cache=True, num_pages=12)
     sch = Scheduler(params, cfg, DCFG_PAGED, ecfg=ecfg)
     reqs = [Request(i, "t", f"question number {i}?") for i in range(5)]
@@ -694,24 +679,28 @@ def test_page_conservation_across_prefix_lifecycle(small_model):
 
 
 @pytest.mark.paged
-def test_failed_slice_exact_stats_backout(small_model):
-    """The failed-slice requeue must back the admission ledger out
-    EXACTLY: afterwards the stats equal the pre-submit snapshot except
-    the fields the (real) admission prefill moved — ``nfe`` /
-    ``weight_bytes_streamed`` / ``prefill_nfe`` / ``prefill_rows`` — and
-    ``pages_peak``, a high-water mark that is never unwound."""
+@pytest.mark.parametrize("phase", ["slice", "admit"])
+def test_failed_slice_exact_stats_backout(small_model, phase):
+    """A raising boundary program — the slice, or the admission prefill
+    before it — must back the admission ledger out EXACTLY: afterwards
+    the stats equal the pre-submit snapshot except the fields a (real)
+    admission prefill moved — ``nfe`` / ``weight_bytes_streamed`` /
+    ``prefill_nfe`` / ``prefill_rows`` — and ``pages_peak``, a
+    high-water mark that is never unwound."""
     cfg, params = small_model
-    ecfg = EngineConfig(batch_size=2, prompt_len=32, slice_len=1,
+    ecfg = EngineConfig(batch_size=2, prompt_len=32,
                         shared_prefix="SYSTEM: be terse. ")
     sch = Scheduler(params, cfg, DCFG_PAGED, ecfg=ecfg)
     n_shared = len(sch._shared_pages)
     before = sch.stats.as_dict()
     sch.submit([Request(i, "t", f"question {i}?") for i in range(2)])
-    real = sch._slice_fn
-    sch._slice_fn = lambda *a, **k: (_ for _ in ()).throw(
-        RuntimeError("boom"))
+    name = f"_{phase}_fn"
+    real = getattr(sch, name)
+    setattr(sch, name, lambda *a, **k: (_ for _ in ()).throw(
+        RuntimeError("boom")))
     with pytest.raises(RuntimeError):
         sch.slice_step()
+    assert sch.carry is None and all(s.state == "free" for s in sch.slots)
     after = sch.stats.as_dict()
     moved = {"pages_peak", "nfe", "weight_bytes_streamed", "prefill_nfe",
              "prefill_rows"}
@@ -720,7 +709,7 @@ def test_failed_slice_exact_stats_backout(small_model):
     assert after["pages_peak"] >= before["pages_peak"]
     assert sch.allocator.in_use == n_shared  # full page reclaim
     assert sch.pending() == 2
-    sch._slice_fn = real
+    setattr(sch, name, real)
     out = sch.run()                          # retry serves every uid
     assert sorted(r.uid for r in out) == [0, 1]
     assert sch.allocator.in_use == n_shared

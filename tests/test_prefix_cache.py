@@ -13,8 +13,8 @@ Contracts enforced here:
   reproduces the same texts: seeding is a pure function of the prefix
   stream, so cache state never changes outputs.
 * **Full-miss degradation** — prefix-free requests through a
-  prefix_cache engine are token-identical to the cache-off sliced
-  runtime and the monolithic paged oracle.
+  prefix_cache engine are token-identical to the cache-off engine and
+  the monolithic paged oracle (``make_generate_fn``).
 * **Eviction under pressure** — LRU reclaims tree-only nodes before
   load-shedding and the allocator ledger stays balanced (the evict-time
   assert guarantees no live row ever loses a mapped page).
@@ -51,10 +51,9 @@ def small_model():
 
 
 def _engine(cfg, params, *, prefix_cache=True, store=None, batch=2,
-            num_pages=0, spec=False, slice_len=1, shared="",
-            watermark=0.0):
+            num_pages=0, spec=False, shared="", watermark=0.0):
     ecfg = EngineConfig(batch_size=batch, prompt_len=PROMPT_LEN,
-                        slice_len=slice_len, num_pages=num_pages,
+                        num_pages=num_pages,
                         shared_prefix=shared, spec_decode=spec,
                         prefix_cache=prefix_cache,
                         prefix_cache_watermark=watermark)
@@ -182,18 +181,20 @@ def test_cold_engine_reproduces_warm_texts(small_model):
     assert _texts(s2.run()) == _texts(first)
 
 
-def test_full_miss_matches_cache_off_and_monolithic(small_model):
+def test_full_miss_matches_cache_off_and_monolithic(small_model,
+                                                   generate_reference):
     cfg, params = small_model
     reqs = lambda: [Request(0, "t", "what is 2+2?"),
                     Request(1, "t", "what is 3+3?")]
     store = _calibrated_store(cfg, params, reqs())
     runs = []
-    for kw in (dict(prefix_cache=True),
-               dict(prefix_cache=False),
-               dict(prefix_cache=False, slice_len=0)):  # monolithic
+    for kw in (dict(prefix_cache=True), dict(prefix_cache=False)):
         s = _engine(cfg, params, store=store, **kw)
         s.submit(reqs())
         runs.append(_texts(s.run()))
+    want, _ = generate_reference(
+        _engine(cfg, params, store=store, prefix_cache=False), reqs())
+    runs.append([want[uid][0] for uid in sorted(want)])  # monolithic
     assert runs[0] == runs[1] == runs[2]
     # and the prefix engine's resubmission (now a promoted full hit)
     # still reproduces the miss texts exactly

@@ -1,6 +1,6 @@
 """Continuous-batching scheduler: mixed-task identity, per-row lifecycle
-(EOS early-exit, dead slots), calibration-store persistence, and the
-engine's repaired stats accounting (SERVING.md)."""
+(EOS early-exit, dead rows), calibration-store persistence, and the
+engine's stats accounting (SERVING.md)."""
 import dataclasses
 
 import jax
@@ -20,6 +20,7 @@ DCFG = DecodeConfig(max_new_tokens=16, block_size=4, policy="osdt",
                     mode="block", metric="q1", cap=0.9, slack=0.1,
                     threshold=0.9)
 PROMPT_LEN = 16
+NB = DCFG.num_blocks
 
 
 @pytest.fixture(scope="module")
@@ -53,9 +54,10 @@ def calibrated_store(small_model):
 
 
 def _engine(cfg, params, store, cache_mode="prefix", attn_impl="auto"):
+    dcfg = dataclasses.replace(DCFG, attn_impl=attn_impl)
     ecfg = EngineConfig(batch_size=4, prompt_len=PROMPT_LEN,
-                        cache_mode=cache_mode, attn_impl=attn_impl)
-    return DiffusionEngine(params, cfg, DCFG, ecfg=ecfg, store=store)
+                        cache_mode=cache_mode)
+    return DiffusionEngine(params, cfg, dcfg, ecfg=ecfg, store=store)
 
 
 # ---------------------------------------------------------------------------
@@ -70,21 +72,25 @@ def _engine(cfg, params, store, cache_mode="prefix", attn_impl="auto"):
 def test_mixed_task_identity(small_model, calibrated_store, cache_mode,
                              attn_impl):
     """One batch mixing tasks alpha/beta must produce byte-identical
-    responses to per-task batches (dead-slot padded, same batch shape =>
-    same compiled program => bitwise-identical row math)."""
+    responses to per-task batches (free slots ride along dead, same carry
+    shape => same compiled slice program => bitwise-identical row
+    math)."""
     cfg, params = small_model
     alpha, beta = _requests("alpha", 2, 0), _requests("beta", 2, 10)
     mixed = _engine(cfg, params, calibrated_store, cache_mode, attn_impl)
     got = {r.uid: r for r in mixed.submit([alpha[0], beta[0], alpha[1],
                                            beta[1]])}
-    assert mixed.stats.batches == 1  # genuinely one mixed batch
+    # genuinely one mixed batch: all four admitted at the first boundary
+    # and decoded side by side
+    assert mixed.stats.mid_admits == 0 and mixed.stats.slices <= NB
 
     for reqs in (alpha, beta):
         iso = _engine(cfg, params, calibrated_store, cache_mode, attn_impl)
         for r in iso.submit(list(reqs)):
             assert r.text == got[r.uid].text, (cache_mode, attn_impl, r.uid)
             assert r.tokens_out == got[r.uid].tokens_out
-        assert iso.stats.dead_slots == 2  # explicit dead-slot padding
+        # two rows of a batch of four: the other two slots stayed free
+        assert iso.stats.requests == 2 and iso.stats.slices <= NB
 
 
 # ---------------------------------------------------------------------------
@@ -133,17 +139,21 @@ def test_dead_rows_cost_no_steps_and_no_interference(small_model):
 
 def test_scheduler_calibrates_several_new_tasks_per_batch(small_model):
     """Parallel calibration: every row records a profile, so two
-    uncalibrated tasks admitted into ONE mixed batch both calibrate —
-    each from its own first request's row, not the batch-max counts."""
+    uncalibrated tasks admitted at ONE boundary both calibrate — each
+    from its own first request's row, not the batch-max counts — while
+    the second t1 request rides along on the static table."""
     cfg, params = small_model
     ecfg = EngineConfig(batch_size=4, prompt_len=PROMPT_LEN)
     sched = Scheduler(params, cfg, DCFG, ecfg=ecfg)
     sched.submit(_requests("t1", 1, 0) + _requests("t2", 1, 1)
                  + _requests("t1", 1, 2))
-    out1 = sched.step()
+    out1 = sched.slice_step()
+    assert sched._calibrating == {"t1": 0, "t2": 1}
+    assert sched.pending() == 0
+    out1 += sched.run()
     assert sorted(r.uid for r in out1) == [0, 1, 2]
     assert sched.store.calibrated("t1") and sched.store.calibrated("t2")
-    assert sched.stats.batches == 1 and sched.pending() == 0
+    assert sched._calibrating == {} and sched.stats.mid_admits == 0
 
 
 def test_parallel_calibration_matches_isolated(small_model):
@@ -154,24 +164,26 @@ def test_parallel_calibration_matches_isolated(small_model):
     ecfg = EngineConfig(batch_size=4, prompt_len=PROMPT_LEN)
     mixed = Scheduler(params, cfg, DCFG, ecfg=ecfg)
     mixed.submit([_requests("a", 1, 0)[0], _requests("b", 1, 1)[0]])
-    mixed.step()
+    mixed.run()
     for task in ("a", "b"):
         iso = Scheduler(params, cfg, DCFG, ecfg=ecfg)
         iso.submit(_requests(task, 1, 0))
-        iso.step()
+        iso.run()
         np.testing.assert_array_equal(iso.store.tables[task],
                                       mixed.store.tables[task])
 
 
 def test_engine_stats_accounting(small_model, calibrated_store):
     """Delivered tokens are post-EOS-truncation counts and per-request
-    wall is its queue wait + its own batch's decode wall (not the whole
+    wall is its queue wait + the slices it was decoded in (not the whole
     submit wall for every member)."""
     cfg, params = small_model
     eng = _engine(cfg, params, calibrated_store)
-    out = eng.submit(_requests("alpha", 6))  # 2 batches of 4 (2 dead slots)
+    out = eng.submit(_requests("alpha", 6))  # 4 admitted, then 2 more
     st = eng.stats
-    assert st.requests == 6 and st.batches == 2 and st.dead_slots == 2
+    # two admission prefills: a bucket of 4 rows, then one of 2
+    assert st.requests == 6 and st.prefill_nfe == 2
+    assert st.prefill_rows == 6
     assert st.tokens == sum(r.tokens_out for r in out)
     assert st.tokens + st.tokens_dropped == 6 * DCFG.max_new_tokens
     for r in out:
@@ -183,30 +195,16 @@ def test_engine_stats_accounting(small_model, calibrated_store):
         assert r.nfe <= DCFG.num_blocks * DCFG.steps_cap
 
 
-def test_failed_batch_conserves_stats_ledger(small_model, calibrated_store):
-    """Monolithic ``step()`` mutates EngineStats only on success: after
-    an injected decode failure the ledger must equal its pre-step
-    snapshot EXACTLY (dense layout — no page watermark to move and no
-    admission prefill), the requests must be back at the queue head,
-    and a retry serves every uid with the usual accounting."""
+@pytest.mark.parametrize("slice_len", [0, -1])
+def test_scheduler_refuses_slice_len_below_one(small_model, slice_len):
+    """The sliced loop is the one runtime: a slice of fewer than one
+    block is a configuration error, refused when the scheduler is
+    built."""
     cfg, params = small_model
-    ecfg = EngineConfig(batch_size=4, prompt_len=PROMPT_LEN)
-    sch = Scheduler(params, cfg, DCFG, ecfg=ecfg, store=calibrated_store)
-    sch.submit(_requests("alpha", 3))
-    before = sch.stats.as_dict()
-    real_gen = sch._gen
-    sch._gen = lambda *a, **k: (_ for _ in ()).throw(RuntimeError("boom"))
-    with pytest.raises(RuntimeError):
-        sch.step()
-    assert sch.stats.as_dict() == before     # conservation: exact
-    assert sch.pending() == 3                # nothing swallowed
-    assert all(s.state == "free" for s in sch.slots)
-    sch._gen = real_gen
-    out = sch.run()
-    assert sorted(r.uid for r in out) == [0, 1, 2]
-    st = sch.stats
-    assert st.requests == 3 and st.batches == 1 and st.dead_slots == 1
-    assert st.tokens == sum(r.tokens_out for r in out)
+    with pytest.raises(ValueError, match="slice_len"):
+        Scheduler(params, cfg, DCFG,
+                  ecfg=EngineConfig(batch_size=2, prompt_len=PROMPT_LEN,
+                                    slice_len=slice_len))
 
 
 # ---------------------------------------------------------------------------

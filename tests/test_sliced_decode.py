@@ -258,7 +258,7 @@ def calibrated_store(small_model):
 
 
 def _sched(cfg, params, store, **ecfg_kw):
-    kw = dict(batch_size=2, prompt_len=PROMPT_LEN, slice_len=1)
+    kw = dict(batch_size=2, prompt_len=PROMPT_LEN)
     kw.update(ecfg_kw)
     dcfg_kw = kw.pop("dcfg_kw", {})
     dcfg = dataclasses.replace(DCFG, **dcfg_kw) if dcfg_kw else DCFG
@@ -273,23 +273,22 @@ def _drain(s):
     return out
 
 
-def test_sliced_matches_batch_boundary(small_model, calibrated_store):
-    """The sliced runtime delivers the same responses as the monolithic
-    batch runtime for the same admitted set (pre-calibrated tables)."""
+def test_sliced_matches_batch_boundary(small_model, calibrated_store,
+                                       generate_reference):
+    """The scheduler delivers what the monolithic oracle decodes for the
+    same admitted set as one batch (pre-calibrated tables): texts,
+    per-request NFE and the forward count."""
     cfg, params = small_model
     reqs = _requests("alpha", 2) + _requests("beta", 2, 10)
-    ref = _sched(cfg, params, calibrated_store, batch_size=4, slice_len=0)
-    ref.submit(list(reqs))
-    got_ref = {r.uid: r for r in ref.run()}
-    sl = _sched(cfg, params, calibrated_store, batch_size=4, slice_len=1)
+    sl = _sched(cfg, params, calibrated_store, batch_size=4)
+    want, res = generate_reference(sl, reqs)
     sl.submit(list(reqs))
     got = {r.uid: r for r in sl.run()}
-    assert got.keys() == got_ref.keys()
+    assert got.keys() == want.keys()
     for uid, r in got.items():
-        assert r.text == got_ref[uid].text, uid
-        assert r.nfe == got_ref[uid].nfe
-    assert sl.stats.tokens == ref.stats.tokens
-    assert sl.stats.nfe == ref.stats.nfe
+        assert (r.text, r.nfe) == want[uid][:2], uid
+    assert sl.stats.tokens == sum(len(r.token_ids) for r in got.values())
+    assert sl.stats.nfe == int(res.nfe)
     assert sl.stats.slices >= NB
 
 
@@ -368,67 +367,69 @@ def test_sliced_latency_accounting(small_model, calibrated_store):
         sum(r.ttfb_s for r in got.values()))
 
 
-def test_sliced_calibration_matches_batch(small_model):
-    """An uncalibrated task's first request calibrates identically under
-    the sliced runtime (same recording rows, ingested at retirement)."""
+def test_sliced_calibration_matches_batch(small_model, generate_reference):
+    """An uncalibrated task's first request calibrates to the table the
+    monolithic oracle's recording of the same row gives (same recording
+    rows, ingested at retirement)."""
     cfg, params = small_model
-    a = Scheduler(params, cfg, DCFG,
-                  ecfg=EngineConfig(batch_size=2, prompt_len=PROMPT_LEN))
-    a.submit(_requests("gamma", 1))
-    a.run()
     b = Scheduler(params, cfg, DCFG,
-                  ecfg=EngineConfig(batch_size=2, prompt_len=PROMPT_LEN,
-                                    slice_len=1))
+                  ecfg=EngineConfig(batch_size=2, prompt_len=PROMPT_LEN))
+    _, res = generate_reference(b, _requests("gamma", 1))
+    ref = CalibrationStore(DCFG)
+    ref.ingest("gamma", result_profile(res, row=0))
     b.submit(_requests("gamma", 1))
     b.run()
     assert b.store.calibrated("gamma")
-    np.testing.assert_array_equal(a.store.tables["gamma"],
+    np.testing.assert_array_equal(ref.tables["gamma"],
                                   b.store.tables["gamma"])
 
 
 def test_sliced_spec_matches_monolithic_spec(small_model,
-                                             calibrated_store):
-    """spec_decode engines: sliced vs batch-boundary runtimes deliver the
-    same texts and draft the same blocks (plan handed over once, at the
-    row's admission slice)."""
+                                             calibrated_store,
+                                             generate_reference):
+    """spec_decode engines: the scheduler delivers the texts of the
+    monolithic draft-variant oracle and drafts the same blocks (plan
+    handed over once, at the row's admission slice)."""
     cfg, params = small_model
     reqs = _requests("alpha", 2) + _requests("beta", 2, 10)
-    kw = dict(batch_size=4, spec_decode=True, eos_early_exit=False,
-              dcfg_kw=dict(cache_layout="paged"))
-    ref = _sched(cfg, params, calibrated_store, slice_len=0, **kw)
-    ref.submit(list(reqs))
-    got_ref = {r.uid: r for r in ref.run()}
-    sl = _sched(cfg, params, calibrated_store, slice_len=2, **kw)
+    sl = _sched(cfg, params, calibrated_store, batch_size=4,
+                spec_decode=True, eos_early_exit=False, slice_len=2,
+                dcfg_kw=dict(cache_layout="paged"))
+    want, res = generate_reference(sl, reqs)
     sl.submit(list(reqs))
     got = {r.uid: r for r in sl.run()}
-    for uid in got_ref:
-        assert got[uid].text == got_ref[uid].text, uid
-        assert got[uid].blocks_drafted == got_ref[uid].blocks_drafted
-        assert got[uid].blocks_accepted == got_ref[uid].blocks_accepted
-    assert sl.stats.blocks_drafted == ref.stats.blocks_drafted
-    assert sl.stats.blocks_accepted == ref.stats.blocks_accepted
+    assert got.keys() == want.keys()
+    for uid, (text, nfe, drafted, accepted) in want.items():
+        assert got[uid].text == text, uid
+        assert got[uid].nfe == nfe
+        assert (got[uid].blocks_drafted, got[uid].blocks_accepted) == \
+            (drafted, accepted)
+    assert sl.stats.blocks_drafted == int(np.asarray(res.blocks_drafted).sum())
+    assert sl.stats.blocks_accepted == \
+        int(np.asarray(res.blocks_accepted).sum())
 
 
 def test_sliced_shared_prefix_matches_batch(small_model,
-                                            calibrated_store):
+                                            calibrated_store,
+                                            generate_reference):
     """Paged + shared system prompt: the sliced admission program encodes
-    only the per-row remainder against the shared pages (same responses
-    as the batch-boundary engine, mid-generation admission included)."""
+    only the per-row remainder against the shared pages (the responses
+    the monolithic oracle decodes for both rows as one batch,
+    mid-generation admission included)."""
     cfg, params = small_model
-    kw = dict(dcfg_kw=dict(cache_layout="paged"),
-              shared_prefix="answer briefly answer briefly ")
-    ref = _sched(cfg, params, calibrated_store, slice_len=0, **kw)
-    assert ref.shared_len > 0  # the prefix actually occupies pages
-    ref.submit(_requests("alpha", 1) + _requests("beta", 1, 10))
-    got_ref = {r.uid: r for r in ref.run()}
-    sl = _sched(cfg, params, calibrated_store, slice_len=1, **kw)
+    sl = _sched(cfg, params, calibrated_store,
+                dcfg_kw=dict(cache_layout="paged"),
+                shared_prefix="answer briefly answer briefly ")
+    assert sl.shared_len > 0  # the prefix actually occupies pages
+    want, _ = generate_reference(
+        sl, _requests("alpha", 1) + _requests("beta", 1, 10))
     sl.submit(_requests("alpha", 1))
     out = list(sl.slice_step())
     sl.submit(_requests("beta", 1, 10))   # admits against shared pages
     out += _drain(sl)
     got = {r.uid: r for r in out}
-    for uid in got_ref:
-        assert got[uid].text == got_ref[uid].text, uid
+    for uid, (text, nfe, _, _) in want.items():
+        assert (got[uid].text, got[uid].nfe) == (text, nfe), uid
     assert sl.stats.mid_admits == 1
     assert sl.allocator.in_use == len(sl._shared_pages)
 
@@ -446,30 +447,33 @@ def test_drafter_plan_remaining_masks_done_blocks(small_model,
     np.testing.assert_array_equal(plan[2][2:], full[2:])
 
 
-def test_failed_slice_requeues_and_retries_cleanly(small_model):
-    """A slice that raises must not swallow requests, leak pages,
-    double-count stats, or pin the task's calibration claim — a retried
-    run() serves every uid and still calibrates the task."""
+@pytest.mark.parametrize("phase", ["slice", "admit"])
+def test_failed_slice_requeues_and_retries_cleanly(small_model, phase):
+    """A slice — or the admission prefill before it — that raises must
+    not swallow requests, leak pages, double-count stats, or pin the
+    task's calibration claim — a retried run() serves every uid and
+    still calibrates the task."""
     cfg, params = small_model
     s = Scheduler(params, cfg,
                   dataclasses.replace(DCFG, cache_layout="paged"),
-                  ecfg=EngineConfig(batch_size=2, prompt_len=PROMPT_LEN,
-                                    slice_len=1))
-    real = s._slice_fn
+                  ecfg=EngineConfig(batch_size=2, prompt_len=PROMPT_LEN))
+    name = f"_{phase}_fn"
+    real = getattr(s, name)
     calls = {"n": 0}
 
     def flaky(*a, **kw):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise RuntimeError("injected slice failure")
+            raise RuntimeError(f"injected {phase} failure")
         return real(*a, **kw)
 
-    s._slice_fn = flaky
+    setattr(s, name, flaky)
     s.submit(_requests("delta", 2))
     with pytest.raises(RuntimeError):
         s.slice_step()
     assert s.allocator.in_use == len(s._shared_pages)  # no page leak
     assert s.pending() == 2 and s.stats.requests == 0
+    assert s.carry is None and all(sl.state == "free" for sl in s.slots)
     assert "delta" not in s._calibrating  # claim released for the retry
     out = s.run()
     assert sorted(r.uid for r in out) == [0, 1]

@@ -293,8 +293,7 @@ def test_engine_rejected_drafts_match_plain_engine(small_model):
                           store=impossible_store())
     # the signature would never flag a block under tau=2.0; force the
     # plan so the REJECT path is what's exercised
-    eng.scheduler.drafter.mask_for = \
-        lambda tasks: np.ones((len(tasks), NB), bool)
+    eng.scheduler.drafter.row_mask = lambda task: np.ones((NB,), bool)
     out_s = eng.submit(list(reqs))
 
     for p, s in zip(out_p, out_s):
@@ -304,8 +303,10 @@ def test_engine_rejected_drafts_match_plain_engine(small_model):
     st = eng.stats
     assert st.blocks_drafted == 3 * NB and st.blocks_accepted == 0
     assert st.draft_accept_rate == 0.0
-    assert st.nfe == plain.stats.nfe + 2  # one drafted batch
-    assert st.nfe_saved == -2             # honest: drafting cost 2
+    # all three rows admitted at one boundary: one plan, whose draft and
+    # verify forwards are the only extra cost
+    assert st.draft_batches == 1
+    assert st.nfe == plain.stats.nfe + 2
 
 
 @pytest.mark.spec
@@ -323,7 +324,7 @@ def test_engine_draft_lifecycle_and_stats(small_model):
                 Request(2, "easy", "q2?")])
     out = {r.uid: r for r in sch.run()}
     assert out[0].blocks_drafted == NB and out[2].blocks_drafted == NB
-    assert out[1].blocks_drafted == 0      # was calibrating this batch
+    assert out[1].blocks_drafted == 0      # was calibrating its task
     st = sch.stats
     assert st.blocks_drafted == 2 * NB
     assert 0 <= st.blocks_accepted <= st.blocks_drafted
@@ -333,7 +334,7 @@ def test_engine_draft_lifecycle_and_stats(small_model):
     assert sch.allocator.in_use == st.pages_shared  # forks released
     # the now-calibrated task drafts on its next request
     sch.submit([Request(3, "new", "q3?")])
-    (r3,) = sch.step()
+    (r3,) = sch.run()
     assert r3.blocks_drafted >= 0  # plan derived from its own signature
 
 
